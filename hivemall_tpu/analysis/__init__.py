@@ -1,6 +1,6 @@
 """graftcheck — a JAX/TPU-aware static analysis pass for this codebase.
 
-Five bench rounds in a row (BENCH_r01-r05, VERDICT.md) lost throughput to
+The first five bench rounds in a row lost throughput to
 *silent* Python-side hazards — retracing, implicit device->host syncs,
 accidental float64 promotion — never to kernel bugs. graftcheck is the gate:
 an AST analyzer purpose-built for the repo's JAX idioms, runnable as

@@ -11,7 +11,7 @@ import re
 
 # --- G002: modules whose loops are per-step hot paths -----------------------
 # The per-step loops of these modules drive every benchmark; an implicit
-# device->host sync there serializes dispatch (BENCH_r01-r05 regressions).
+# device->host sync there serializes dispatch (the rounds 1-5 regressions).
 HOT_LOOP_MODULES = (
     "hivemall_tpu/core/engine.py",
     "hivemall_tpu/parallel/sharded_train.py",

@@ -1,6 +1,6 @@
 """G031 unbounded-retry: a retry loop with no attempt cap or no backoff.
 
-The bench.py TPU-probe pathology, generalized: a ``while True:`` loop
+The retry-until-it-answers pathology, generalized: a ``while True:`` loop
 whose except handler neither raises, breaks, nor returns retries
 *forever* — a persistent failure (bad artifact, dead endpoint) becomes
 a 100%-CPU busy spin that also hammers the failing dependency. And a

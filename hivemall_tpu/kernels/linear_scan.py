@@ -4,8 +4,9 @@ Executes the SAME Rule definitions as core/engine.py (perceptron ... AdaGradRDA,
 all regressors) but with every model table VMEM-resident and the block's rows
 replayed sequentially in ONE kernel — the reference's per-row semantics
 (ref: BinaryOnlineClassifierUDTF.java:111-247) without an HBM round trip per
-row. Usable when the model fits on-chip (dims * (2 + n_slots) * 4B within
-~12MB of VMEM).
+row. Usable when the model fits on-chip (`vmem_resident_reason`: every table
+twice within VMEM_TABLE_BUDGET_BYTES); larger models are refused before the
+kernel is traced.
 
 Hardware layout (lowers on real TPU Mosaic — scalar VMEM stores do not):
 - model tables are reshaped to [D/128, 128]; a feature id becomes
@@ -22,7 +23,9 @@ The rule's `update(ctx, hyper)` is traced *inside* the kernel. Validated
 against the engine's scan mode in interpret mode (tests/test_pallas_kernels.py)
 and compiled on a real v5e chip (scripts/pallas_tpu_check.py).
 
-Opt-in: `fit_linear(..., options="-pallas")` routes scan-mode training here.
+Opt-in: `fit_linear(..., options="-pallas")` routes scan-mode training here
+on a TPU and refuses elsewhere; interpret mode is a Python argument that only
+tests pass.
 """
 
 from __future__ import annotations
@@ -173,6 +176,34 @@ def _make_kernel(rule: Rule, hyper: dict, K: int, D: int, chunk: int,
     return kernel
 
 
+# VMEM the resident tables may claim: the v5e's capacity as Mosaic reports it
+# ("Used 256.00M of 128.00M vmem"). The kernel holds every table twice, as a
+# single-buffered whole-array input window and an output window. Measured on
+# the v5e (jax 0.9.0, libtpu 0.0.34, PR 21): perceptron at 2^24 dims and AROW
+# at 2^23 (both exactly 128 MiB) compile and match the engine scan;
+# perceptron at 2^25 and AROW at 2^24 (256 MiB) are refused by the compiler.
+VMEM_TABLE_BUDGET_BYTES = 128 << 20
+
+
+def vmem_resident_reason(rule: Rule, dims: int):
+    """Why a `dims`-wide model of this rule cannot run VMEM-resident, or
+    None when it fits: 2 (in + out window) x tables (w [+ cov] + slots) x
+    padded dims x 4 B against VMEM_TABLE_BUDGET_BYTES. Checked before the
+    kernel is traced, so an oversized model is refused in words instead of
+    dying inside Mosaic."""
+    n_tables = 1 + (1 if rule.use_covariance else 0) + len(rule.slot_names)
+    d_pad = (dims + LANES - 1) // LANES * LANES
+    need = 2 * n_tables * d_pad * 4
+    if need <= VMEM_TABLE_BUDGET_BYTES:
+        return None
+    max_dims = VMEM_TABLE_BUDGET_BYTES // (2 * n_tables * 4)
+    return (f"{rule.name} at {dims} dims needs {need >> 20} MiB of VMEM "
+            f"({n_tables} f32 table(s), each held as an input and an output "
+            f"window) and the chip has {VMEM_TABLE_BUDGET_BYTES >> 20} MiB — "
+            f"at most {max_dims} dims fit; use the default scan backend or "
+            f"-mini_batch")
+
+
 def _table_2d(flat: jnp.ndarray, d_pad: int) -> jnp.ndarray:
     d = flat.shape[0]
     if d_pad != d:
@@ -209,6 +240,9 @@ def pallas_scan_raw(rule: Rule, hyper: dict, state: LinearState,
     labels = jnp.asarray(labels, jnp.float32)
     B, K = indices.shape
     D = state.weights.shape[0]
+    reason = vmem_resident_reason(rule, D)
+    if reason is not None:
+        raise ValueError(f"pallas scan kernel refused: {reason}")
     d_pad = (D + LANES - 1) // LANES * LANES
     n_rows = d_pad // LANES
     chunk = _pick_chunk(B, K)

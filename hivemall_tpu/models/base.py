@@ -282,8 +282,14 @@ def fit_linear(
     initial_weights: Optional[np.ndarray] = None,
     initial_covars: Optional[np.ndarray] = None,
     default_dims: int = DEFAULT_NUM_FEATURES,
+    pallas_interpret: bool = False,
 ) -> TrainedLinearModel:
-    """The generic fit loop used by every classifier/regressor `train_*`."""
+    """The generic fit loop used by every classifier/regressor `train_*`.
+
+    `pallas_interpret` runs the `-pallas` kernel in the Pallas interpreter
+    instead of compiling it — for tests that check the kernel's semantics
+    off-chip. It is a Python argument on purpose, never an option string:
+    a user's `-pallas` either compiles for the TPU or is refused."""
     dims = cl.get_int("dims") or default_dims
     mini_batch = cl.get_int("mini_batch", 1)
     iters = cl.get_int("iters", 1)
@@ -365,8 +371,15 @@ def fit_linear(
     elif cl.has("pallas") and mode == "scan":
         from ..kernels.linear_scan import make_pallas_scan_step
 
-        interpret = jax.devices()[0].platform != "tpu"
-        step = make_pallas_scan_step(rule, hyper, interpret=interpret)
+        platform = jax.devices()[0].platform
+        if platform != "tpu" and not pallas_interpret:
+            raise ValueError(
+                f"-pallas compiles a TPU (Mosaic) kernel and jax is on "
+                f"{platform!r}; drop the flag — the default scan backend "
+                f"has the same per-row semantics")
+        # dims that cannot be VMEM-resident are refused by the kernel's own
+        # guard when the first block is traced (vmem_resident_reason)
+        step = make_pallas_scan_step(rule, hyper, interpret=pallas_interpret)
     else:
         backend = "mxu" if (cl.has("mxu_scatter") and mode == "minibatch") \
             else "xla"

@@ -323,7 +323,7 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
             # sorted-window MXU path (ops/mxu_scatter.py): the packed
             # [D, kp] table is gathered ONCE for the whole block and the
             # update columns ride one windowed scatter — V traffic is the
-            # whole FM step cost on v5e (PERF.md FM bisection), and the
+            # whole FM step cost on v5e (docs/perf_history.md FM bisection), and the
             # scalar engine charges ~20ms/block for it
             from ..ops import mxu_scatter as mxu
 

@@ -538,9 +538,9 @@ def grow_forest(
       `hist_budget_bytes`; chunk shapes are padded to fixed sizes so the
       set of compiled kernels stays O(log max_frontier).
     - "auto" (default): per_tree unless `row_shard` is set. Measured on
-      both platforms (scripts/bench_forest.py, PERF.md round 5): the
+      both platforms (scripts/bench_forest.py, docs/perf_history.md round 5): the
       batched padding waste exceeds its dispatch savings — batched runs
-      0.62x the per-tree loop on relay-attached v5e and 0.35x on CPU — so
+      0.62x the per-tree loop on v5e (one r4 session) and 0.35x on CPU — so
       the loop is the default wherever it is legal. Row-sharded growth
       keeps the batched kernels: its per-level psum'd histogram
       (_sharded_hist_fn) is the data-parallel path's whole point and

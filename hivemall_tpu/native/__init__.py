@@ -2,22 +2,37 @@
 
 The C++ library accelerates the host-side input pipeline: bulk murmur3 feature
 hashing and padded-CSR block packing (the [native-equiv] substrate pieces from
-SURVEY.md §2.17). Python/numpy fallbacks are used automatically when the .so
-hasn't been built (scripts/build_native.sh)."""
+SURVEY.md §2.17). The .so is a build product, not a tracked file: the first
+load compiles it from native/hivemall_native.cpp through
+scripts/build_native.sh when it is missing or its build stamp names another
+CPU or source (the plain build is -march=native, so a library copied from
+another host is a SIGILL waiting in hm_pack_block). Python/numpy fallbacks
+are used when no compiler is available."""
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
+import shutil
+import subprocess
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-_LIB_PATH = os.path.join(os.path.dirname(__file__), "libhivemall_native.so")
+_PKG_DIR = os.path.dirname(os.path.abspath(__file__))
+_REPO_ROOT = os.path.dirname(os.path.dirname(_PKG_DIR))
+_LIB_PATH = os.path.join(_PKG_DIR, "libhivemall_native.so")
+_SRC_PATH = os.path.join(_REPO_ROOT, "native", "hivemall_native.cpp")
+_BUILD_SCRIPT = os.path.join(_REPO_ROOT, "scripts", "build_native.sh")
 _lib: Optional[ctypes.CDLL] = None
 
 
 _load_error: Optional[str] = None
+# why this process (re)built the library at first use, or None when the
+# library on disk was already this host's build — reported by build_info()
+_built_because: Optional[str] = None
 
 # HIVEMALL_TPU_NATIVE_SANITIZE selects a sanitizer-instrumented .so variant
 # built by `scripts/build_native.sh --sanitize=...` (suffixed so the
@@ -54,6 +69,95 @@ def _so_path() -> Optional[str]:
     return base + suffix + ext
 
 
+def _host_cpu_id() -> str:
+    """Machine + sha256 of the kernel's ISA-flags line — the same derivation
+    as scripts/build_native.sh::stamp_content's ``cpu:`` line (keep the two
+    identical). Two hosts with the same id accept the same -march=native
+    instruction set."""
+    line = b""
+    try:
+        with open("/proc/cpuinfo", "rb") as fh:
+            for raw in fh:
+                if raw.startswith((b"flags", b"Features")):
+                    line = raw
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()} {hashlib.sha256(line).hexdigest()}"
+
+
+def _read_stamp() -> dict:
+    """The plain library's build stamp as {key: value}; {} when absent."""
+    try:
+        with open(_LIB_PATH + ".stamp", encoding="utf-8") as fh:
+            return dict(ln.rstrip("\n").split(": ", 1) for ln in fh
+                        if ": " in ln)
+    except OSError:
+        return {}
+
+
+def _stale_reason() -> Optional[str]:
+    """Why the plain .so on disk must not be loaded as it stands, or None
+    when its stamp says it was built on this CPU from the current source.
+    Compiler/flag drift is scripts/build_native.sh --if-stale's job
+    (scripts/test.sh runs it); the loader checks only what makes a load
+    unsafe (foreign CPU) or wrong (other source)."""
+    if not os.path.exists(_LIB_PATH):
+        return "library not built yet"
+    stamp = _read_stamp()
+    if not stamp:
+        return "library has no build stamp"
+    host_cpu = _host_cpu_id()
+    if stamp.get("cpu") != host_cpu:
+        return (f"library was built for another CPU ({stamp.get('cpu')}; "
+                f"this host is {host_cpu})")
+    if os.path.exists(_SRC_PATH):
+        with open(_SRC_PATH, "rb") as fh:
+            digest = hashlib.sha256(fh.read()).hexdigest()
+        if stamp.get("source") != digest:
+            return "library predates native/hivemall_native.cpp"
+    return None
+
+
+def _build(reason: str) -> bool:
+    """Build the plain library for this host (first use). False, with
+    ``_load_error`` naming why, when there is no compiler/build script or
+    the build fails."""
+    global _load_error, _built_because
+    import warnings
+
+    have_toolchain = shutil.which("g++") and os.path.exists(_BUILD_SCRIPT) \
+        and os.path.exists(_SRC_PATH)
+    if have_toolchain:
+        proc = subprocess.run(["bash", _BUILD_SCRIPT], cwd=_REPO_ROOT,
+                              capture_output=True, text=True)
+        if proc.returncode == 0:
+            _built_because = reason
+            return True
+        detail = f"scripts/build_native.sh failed: {proc.stderr.strip()[-300:]}"
+    else:
+        detail = "no g++ / build script to rebuild it"
+    _load_error = f"{reason}; {detail}"
+    if have_toolchain or os.path.exists(_LIB_PATH):
+        # loud unless this is the quiet pure-Python deployment (nothing to
+        # load and nothing to build with)
+        warnings.warn(f"hivemall_tpu.native: {_LIB_PATH} not loaded "
+                      f"({_load_error}); using Python fallbacks")
+    return False
+
+
+def build_info() -> dict:
+    """Which library this process uses and how it came to be — the
+    provenance chip_smoke.py reports: path, whether it loaded, why it was
+    (re)built at first use (None: the stamp already matched this host),
+    and the stamp (compiler, flags, cpu, source hash)."""
+    loaded = _load() is not None
+    return {"path": _so_path(), "loaded": loaded,
+            "built_at_first_use": _built_because,
+            "load_error": _load_error, "stamp": _read_stamp(),
+            "host_cpu": _host_cpu_id()}
+
+
 def _load() -> Optional[ctypes.CDLL]:
     global _lib, _load_error
     if _lib is not None:
@@ -61,7 +165,13 @@ def _load() -> Optional[ctypes.CDLL]:
     if _load_error is not None:
         return None
     path = _so_path()
-    if path is None or not os.path.exists(path):
+    if path is None:
+        return None
+    if path == _LIB_PATH:
+        stale = _stale_reason()
+        if stale is not None and not _build(stale):
+            return None
+    elif not os.path.exists(path):
         return None
     try:
         lib = ctypes.CDLL(path)
@@ -205,10 +315,10 @@ def available() -> bool:
 
 
 def load_error() -> Optional[str]:
-    """The recorded load failure for a PRESENT-but-unloadable .so (toolchain
-    mismatch — the PR 11 GLIBCXX pathology), or None. Callers that refuse or
-    fall back on unavailability report this so the mismatch is named, never
-    swallowed (scripts/build_native.sh --if-stale rebuilds it away)."""
+    """Why the library is not in use (unbuildable, foreign-CPU build with no
+    compiler, toolchain mismatch at load — the PR 11 GLIBCXX pathology), or
+    None. Callers that refuse or fall back on unavailability report this so
+    the cause is named, never swallowed."""
     _load()
     return _load_error
 

@@ -143,7 +143,7 @@ _NOUNS = [
     "神社", "寺", "城", "美術館", "博物館", "動物園", "水族館",
     "映画館", "劇場", "空席", "入口", "出口", "受付", "窓口",
     "切手", "封筒", "葉書", "小包", "郵便", "郵便局",
-    # blind2 fold (after its 0.9773 first-pass was recorded — PERF.md):
+    # blind2 fold (after its 0.9773 first-pass was recorded — docs/perf_history.md):
     # 口座 and 毎週 were two of the three actual misses (the third,
     # について, is filed with the 連語 particles); 毎年/毎月/温泉 are
     # opportunistic siblings added in the same pass, NOT blind misses
@@ -484,7 +484,7 @@ def build_lexicon() -> Dict[str, List[Tuple[str, int]]]:
         if all(p != N for p, _ in lex[w]):
             lex[w].append((N, 60))
     for w in ext.KANJI_SUFFIXES:
-        # Pricing (blind3/blind4 post-record fixes, PERF.md round 5; the
+        # Pricing (blind3/blind4 post-record fixes, docs/perf_history.md round 5; the
         # kanji unknown model is (1100, 500) -> runs price 1600/2100/2600):
         # a suffix must lose to the 2-kanji unknown price when its host is
         # ALSO unknown — at 540 the tier shredded unseen compounds (減税 ->

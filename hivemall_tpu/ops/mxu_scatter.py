@@ -1,7 +1,7 @@
 """Sorted-window MXU gather/scatter: random model-table access as matmuls.
 
 The engine's single-chip floor is XLA's scalar gather/scatter engine: the
-verified v5e cost model (PERF.md, diag micros) puts one 524288-id gather at
+verified v5e cost model (docs/perf_history.md, diag micros) puts one 524288-id gather at
 ~13 ms (~38M ids/s) and one scatter-add at ~7 ms (~70M updates/s) — both
 latency-bound serial loops ~20x off the HBM roofline, and together they ARE
 the AROW/FM step time (reference hot loop being beaten:

@@ -29,6 +29,9 @@ def make_mesh(n_devices: Optional[int] = None, axis_name: str = WORKER_AXIS,
     if devices is None:
         devices = jax.devices()
     if n_devices is not None:
+        if len(devices) < n_devices:
+            raise ValueError(
+                f"need {n_devices} devices, have {len(devices)}")
         devices = devices[:n_devices]
     return Mesh(np.asarray(devices), (axis_name,))
 

@@ -127,19 +127,28 @@ def merge_slot_arrays(slots: dict, touched_all: np.ndarray, kinds: dict,
 
 def replicate_state(one, n_replicas: int, mesh: Mesh, specs=None,
                     axis: str = WORKER_AXIS):
-    """Broadcast a single-model pytree to a leading [n_replicas] axis and
-    place it on the mesh. Default placement: replica axis sharded over
-    `axis`, everything else replicated; pass `specs` (a pytree of
-    PartitionSpec with the leading replica dim included) to additionally
-    stripe trailing dims. One copy of the broadcast-then-place init shared by
-    every replicated trainer."""
-    stacked = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_replicas,) + x.shape), one)
+    """Give a single-model pytree a leading [n_replicas] axis, placed on the
+    mesh. Default placement: replica axis sharded over `axis`, everything
+    else replicated; pass `specs` (a pytree of PartitionSpec with the
+    leading replica dim included) to additionally stripe trailing dims. One
+    copy of the replicate-and-place init shared by every replicated trainer.
+
+    Each device receives exactly its shard, cut from a zero-copy host
+    broadcast of the one replica: the stacked [n_replicas, ...] tables never
+    exist on any single device (broadcasting on the default device first
+    put every replica's tables on chip 0 before the placement moved them)."""
     if specs is None:
         specs = jax.tree.map(
-            lambda x: P(*((axis,) + (None,) * (x.ndim - 1))), stacked)
-    return jax.tree.map(
-        lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), stacked, specs)
+            lambda x: P(*((axis,) + (None,) * np.ndim(x))), one)
+
+    def place(x, spec):
+        host = np.asarray(x)
+        shape = (n_replicas,) + host.shape
+        stacked = np.broadcast_to(host[None], shape)  # a view, not a copy
+        return jax.make_array_from_callback(
+            shape, NamedSharding(mesh, spec), lambda index: stacked[index])
+
+    return jax.tree.map(place, one, specs)
 
 
 def split_replica_blocks(n_replicas: int, *arrays):

@@ -1,27 +1,13 @@
-"""Version-portable JAX API surface for the distributed paths.
+"""The one sanctioned spelling of the shard_map API for the distributed paths.
 
-The shard_map API moved twice across the jax versions this repo must run
-on:
-
-- jax <= 0.4.x ships ``jax.experimental.shard_map.shard_map`` with a
-  ``check_rep=`` replication checker (no vma system, no ``jax.lax.pcast``);
-- newer jax promotes it to ``jax.shard_map`` with ``check_vma=`` (the
-  varying-manual-axes checker) and adds ``jax.lax.pcast`` to re-tag
-  device-invariant values as mesh-varying.
-
-Every trainer imports ``shard_map`` / ``pcast`` from here instead of
-touching either spelling directly; graftcheck rule G009 enforces that (and
-its autofix performs the rewrite). This module is the only file allowed to
-reference the raw APIs — it is excluded from G009 by path.
-
-Legacy note: on the 0.4.x path ``check_vma`` is accepted but the legacy
-``check_rep`` checker is kept OFF regardless of its value. The legacy
-rewrite rules predate the vma system (scan-carry re-tagging needs pcast,
-which does not exist there, so this module's ``pcast`` is the identity) —
-running the old checker against code written for vma semantics produces
-spurious failures, not safety. The real vma check still runs wherever a
-newer jax is installed, and graftcheck's static G007/G010 rules cover the
-collective-safety classes on every version.
+Every trainer imports ``shard_map`` / ``pcast`` / ``named_mesh`` from here
+instead of touching ``jax.shard_map`` / ``jax.lax.pcast`` directly;
+graftcheck rule G009 enforces that (and its autofix performs the rewrite).
+This module is the only file allowed to reference the raw APIs — it is
+excluded from G009 by path. It targets the installed jax only
+(``jax.shard_map`` with the ``check_vma=`` varying-manual-axes checker,
+``jax.lax.pcast`` to re-tag device-invariant values as mesh-varying); when
+the API moves again, this is the one file that changes.
 """
 
 from __future__ import annotations
@@ -30,7 +16,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 
-__all__ = ["shard_map", "pcast", "named_mesh", "HAS_NATIVE_SHARD_MAP"]
+__all__ = ["shard_map", "pcast", "named_mesh"]
 
 
 def named_mesh(axis_sizes: Sequence[int],
@@ -42,8 +28,7 @@ def named_mesh(axis_sizes: Sequence[int],
     This is the one sanctioned mesh-construction spelling for the serving
     placements (serving/placement.py) and the G008 analyzer resolves its
     axis names (default ``("batch", "model")`` — the serving convention).
-    Newer jax ships ``jax.make_mesh``, which may REORDER devices for ICI
-    locality; that reordering is a perf nicety training can afford but
+    ``jax.make_mesh`` may REORDER devices for ICI locality; that reordering is a perf nicety training can afford but
     serving cannot take by default — stripe ownership must be a pure
     function of device index so (a) the process-wide sharded-jit cache can
     key on the device list and (b) a re-deploy on the same host places
@@ -66,72 +51,18 @@ def named_mesh(axis_sizes: Sequence[int],
 
     return Mesh(grid, tuple(axis_names))
 
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
 
-if HAS_NATIVE_SHARD_MAP:
+def shard_map(f: Optional[Callable] = None, *, mesh, in_specs, out_specs,
+              check_vma: bool = True, **kwargs):
+    """``jax.shard_map``, usable directly or decorator-style
+    (``shard_map(mesh=..., ...)(fn)``)."""
+    if f is None:
+        return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs,
+                                   check_vma=check_vma, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma,
+                         **kwargs)
 
-    def shard_map(f: Optional[Callable] = None, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True, **kwargs):
-        """``jax.shard_map`` with a version-stable keyword surface."""
-        if f is None:  # decorator-style: shard_map(mesh=..., ...)(fn)
-            return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs,
-                                       check_vma=check_vma, **kwargs)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
 
-    if hasattr(jax.lax, "pcast"):
-        pcast = jax.lax.pcast
-    else:  # vma jax without pcast spelling: pvary covers the to="varying" use
-
-        def pcast(x, axis_name, *, to: str = "varying"):
-            if to != "varying":
-                raise NotImplementedError(
-                    f"pcast(to={to!r}) has no equivalent on jax "
-                    f"{jax.__version__}")
-            return jax.lax.pvary(x, axis_name)
-
-else:
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    # Modern jax defaults jax_threefry_partitionable=True: random bits are a
-    # pure function of (key, flat index), so a padded [D_pad] table's prefix
-    # equals the unpadded [D] one. Legacy jax defaults False, where bits
-    # depend on the TOTAL array size — padded-sharded init then silently
-    # diverges from single-device init past the threefry half-split point
-    # and every sharded-vs-reference parity guarantee breaks. Align the
-    # semantics with the modern default on the legacy path.
-    #
-    # This is a process-global flip at import time, so on legacy jax the
-    # raw jax.random stream for a given key changes once this module (or
-    # anything under hivemall_tpu.parallel / models.trees.grow) is first
-    # imported. The deliberate trade-off: hivemall_tpu/__init__.py must
-    # stay jax-free (the stdlib-only analyzer imports through it), so the
-    # flip cannot be hoisted there; import this module first if external
-    # code needs the aligned stream from the start of the process.
-    try:
-        jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # graftcheck: disable=G029 (flag probe: very old jax lacks it)
-        pass
-
-    def shard_map(f: Optional[Callable] = None, *, mesh, in_specs, out_specs,
-                  check_vma: bool = True, **kwargs):
-        """Legacy ``jax.experimental.shard_map`` adapter.
-
-        ``check_vma`` is accepted for source compatibility; the legacy
-        ``check_rep`` checker stays off (see module docstring).
-        """
-        del check_vma
-        if f is None:
-            return lambda g: shard_map(g, mesh=mesh, in_specs=in_specs,
-                                       out_specs=out_specs, **kwargs)
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False,
-                                 **kwargs)
-
-    def pcast(x, axis_name, *, to: str = "varying"):
-        """No vma system on legacy jax: values carry no varying/invariant
-        tag, so the re-tag is the identity."""
-        del axis_name, to
-        return x
+pcast = jax.lax.pcast

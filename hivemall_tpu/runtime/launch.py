@@ -75,6 +75,12 @@ def main(argv=None) -> int:
     joined = init_cluster(coordinator, args.num_procs, args.proc_id)
     import jax
 
+    from hivemall_tpu.runtime.compile_cache import enable_compile_cache
+
+    # after the join: the helper initializes the backend, which must not
+    # happen before jax.distributed.initialize
+    enable_compile_cache()
+
     print(f"[launch] distributed={'joined' if joined else 'single-process'} "
           f"process={jax.process_index()}/{jax.process_count()} "
           f"local_devices={len(jax.local_devices())} "

@@ -251,15 +251,20 @@ def _jit_cache_size(fn) -> int:
         return 0
 
 
-# jax 0.4.x logs every XLA compile at DEBUG as "Compiling <fn> with global
-# shapes and types [ShapedArray(...)]. Argument mapping: ...". The capture
-# anchors on the sentence structure, NOT a bracket match — shapes like
-# float32[4] contain `]`, so a lazy `\[.*?\]` truncates mid-list.
+# jax logs every XLA compile at DEBUG as "Compiling jit(<fn>) with global
+# shapes and types (ShapedArray(...),). Argument mapping: ...": the module
+# name is the function name wrapped in the API that compiled it (jit, pmap).
+# The capture anchors on the sentence structure, NOT a bracket match —
+# shapes like float32[4] contain `]`, so a lazy `\[.*?\]` truncates
+# mid-list.
 _COMPILE_LOG_RE = re.compile(
-    r"Compiling (\S+) with global shapes and types (.*?)\. Argument mapping")
+    r"Compiling \w+\((\S+)\) with global shapes and types (.*?)\. "
+    r"Argument mapping")
 
-# the module that owns the "Compiling ..." log line; if a future jax moves
-# it, attribution degrades to empty (counters are unaffected)
+# the module that owns the "Compiling ..." log line; if a jax upgrade moves
+# or rewords it, attribution goes empty (counters are unaffected) and
+# tests/test_graftcheck.py's retrace-attribution tests fail — they are the
+# alarm for this parse
 _COMPILE_LOGGER = "jax._src.interpreters.pxla"
 
 # fn name -> shape signature of its LAST compile, process-wide: lets a later

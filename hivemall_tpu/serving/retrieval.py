@@ -273,10 +273,16 @@ def _build_sh_block_step(mesh, stripe: int, bk: int, k_pad: int,
         return tv, jnp.take_along_axis(cand, pos, axis=1)
 
     m = MODEL_AXIS
+    # check_vma=False: the merged carry IS replicated (every device runs
+    # the same top_k over the same all_gather result), but the checker
+    # types all_gather's output as varying over the model axis and the
+    # public API has no invariant-typed gather, so P() out_specs cannot be
+    # proven. Parity with the single-device engine is pinned by
+    # tests/test_serving_retrieval.py.
     fn = shard_map(local, mesh=mesh,
                    in_specs=(P(m), P(m), P(m), P(m), P(), P(), P(), P(),
                              P(), P()),
-                   out_specs=(P(), P()))
+                   out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)
 
 

@@ -59,6 +59,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from ..runtime import metrics_http
+from ..runtime.compile_cache import enable_compile_cache
 from ..runtime.metrics import REGISTRY
 from ..runtime.tracing import TRACER
 from .admission import (PRIORITY_NAMES, DeadlineExpired, priority_class,
@@ -162,6 +163,10 @@ class ModelRegistry:
                  express_high: bool = True,
                  degraded_depth_fraction: float = 0.75,
                  score_cache_bytes: Optional[int] = None) -> None:
+        # every deploy compiles a bucket ladder (42 programs per engine by
+        # default): let a restarted server find them in the persistent
+        # compilation cache instead of recompiling
+        enable_compile_cache()
         self._entries: Dict[str, ModelEntry] = {}
         # hot-row score caches, one per model NAME, shared across that
         # name's versions (the version is in every key, so a hot-swap

@@ -132,4 +132,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from hivemall_tpu.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     main()

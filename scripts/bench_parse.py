@@ -1,6 +1,6 @@
 """Host-staging benchmark: the native bulk feature parser vs the Python
 parser over a CTR-shaped token batch (mixed int ids / "id:value" pairs /
-hashed string names). Rerunnable source of PERF.md's parser row.
+hashed string names). Rerunnable source of docs/perf_history.md's parser row.
 
 Run: python scripts/bench_parse.py [n_rows] [width]
 """

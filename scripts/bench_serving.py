@@ -2273,6 +2273,36 @@ def run_http_mode(args, source, rows, tag) -> int:
     return 0
 
 
+def _reexec_with_mode_flags(args) -> None:
+    """Some modes need XLA flags that only take effect before jax
+    initializes; re-exec once with them set (modes do not compose — main()
+    refuses any pair — so at most one branch applies).
+
+    - --topk / --sharded need a mesh: CPU runs force 8 host devices the way
+      the test suite does (tests/conftest.py); real accelerator runs keep
+      their native device set.
+    - --quantize pins the CPU backend to single-threaded ops —
+      serving-shaped XLA threading: production servers give each request
+      one core (request-level parallelism) instead of letting every
+      dispatch fan out over the whole intra-op pool, and it is under that
+      per-core regime that table bytes, not the scheduler, price a request.
+      Operators override by setting XLA_FLAGS themselves."""
+    import os
+
+    flags = os.environ.get("XLA_FLAGS", "")
+    extra = ""
+    if args.topk or args.sharded:
+        if os.environ.get("JAX_PLATFORMS", "") == "cpu" \
+                and "xla_force_host_platform_device_count" not in flags:
+            extra = " --xla_force_host_platform_device_count=8"
+    elif args.quantize and "intra_op_parallelism_threads" not in flags:
+        extra = (" --xla_cpu_multi_thread_eigen=false "
+                 "intra_op_parallelism_threads=1")
+    if extra:
+        os.environ["XLA_FLAGS"] = (flags + extra).strip()
+        os.execv(sys.executable, [sys.executable] + sys.argv)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--artifact", help="serve this artifact dir instead of "
@@ -2516,6 +2546,13 @@ def main() -> int:
         if getattr(args, name) is None:
             setattr(args, name, small if args.smoke else full)
 
+    _reexec_with_mode_flags(args)
+    # only now may jax initialize: the re-exec above must replace a process
+    # that has not touched the device
+    from hivemall_tpu.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     if args.topk:
         if args.artifact or args.http or args.quantize or args.sharded \
                 or args.skew or args.overload:
@@ -2523,17 +2560,6 @@ def main() -> int:
                              "catalog; it does not compose with "
                              "--artifact, --http, --quantize, --sharded, "
                              "--skew or --overload")
-        import os
-
-        # the sharded-catalog parity segment needs a mesh: CPU runs force
-        # 8 host devices BEFORE jax initializes (re-exec, the --sharded
-        # pattern); real accelerator runs keep their native device set
-        flags = os.environ.get("XLA_FLAGS", "")
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu" \
-                and "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8").strip()
-            os.execv(sys.executable, [sys.executable] + sys.argv)
         return run_topk_mode(args)
 
     if args.slo:
@@ -2569,16 +2595,6 @@ def main() -> int:
                              "--quantize or --topk")
         import os
 
-        # CPU runs simulate a mesh the same way the test suite does
-        # (tests/conftest.py): force 8 host devices BEFORE jax initializes
-        # (re-exec, the --quantize pattern). Real accelerator runs keep
-        # their native device set.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if os.environ.get("JAX_PLATFORMS", "") == "cpu" \
-                and "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=8").strip()
-            os.execv(sys.executable, [sys.executable] + sys.argv)
         if not args.concurrency:  # 0 from sizing: drivers match cores
             args.concurrency = min(8, os.cpu_count() or 2)
         return run_sharded_mode(args)
@@ -2590,19 +2606,6 @@ def main() -> int:
                              "--artifact, --http or --topk")
         import os
 
-        # serving-shaped XLA threading: production servers give each
-        # request one core (request-level parallelism) instead of letting
-        # every dispatch fan out over the whole intra-op pool — and it is
-        # under that per-core regime that table bytes, not the scheduler,
-        # price a request. Re-exec once with the CPU backend pinned to
-        # single-threaded ops before jax initializes; operators override
-        # by setting XLA_FLAGS themselves.
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "intra_op_parallelism_threads" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_cpu_multi_thread_eigen=false "
-                "intra_op_parallelism_threads=1").strip()
-            os.execv(sys.executable, [sys.executable] + sys.argv)
         if not args.concurrency:  # 0 from sizing: drivers match cores
             args.concurrency = min(8, os.cpu_count() or 2)
         return run_quantize_mode(args)

@@ -12,11 +12,15 @@
 # LD_PRELOAD="$(g++ -print-file-name=libasan.so) $(g++ -print-file-name=libubsan.so)".
 #
 # --if-stale: rebuild only when the .so is missing, its build stamp (compiler
-# version + flags + source sha256) mismatches, or — plain variant only — it
-# is unloadable on THIS host (the PR 11 GLIBCXX-mismatch pathology) or
-# predates the newest required symbol. The stamp is what makes flag changes
-# count as staleness: before it, `--if-stale` only compared mtimes, so a
-# stray -O0 or sanitizer build of the same source looked "fresh" forever.
+# version + flags + target CPU + source sha256) mismatches, or — plain
+# variant only — it is unloadable on THIS host (the PR 11 GLIBCXX-mismatch
+# pathology) or predates the newest required symbol. The stamp is what makes
+# flag changes count as staleness: before it, `--if-stale` only compared
+# mtimes, so a stray -O0 or sanitizer build of the same source looked
+# "fresh" forever. The cpu line is what makes a copied tree safe: the plain
+# build is -march=native, and a library built on an AVX-512 host dies with
+# SIGILL inside hm_pack_block on a host without it (seen on the TPU VM,
+# PR 21) — hivemall_tpu.native checks the same line before it loads.
 # Exits 0 WITHOUT building when no C++ compiler is present —
 # hivemall_tpu.native then reports unavailability loudly (warnings +
 # load_error()) and the native bench gates skip with the reason in-artifact.
@@ -63,10 +67,14 @@ esac
 STAMP="$SO.stamp"
 
 stamp_content() {
-  # compiler identity + exact flags + source hash: any drift in any of the
-  # three means the binary on disk is not the binary these inputs produce
+  # compiler identity + exact flags + target CPU + source hash: any drift in
+  # any of the four means the binary on disk is not the binary these inputs
+  # produce HERE. The cpu id (machine + sha256 of the kernel's ISA-flags
+  # line) is recomputed by hivemall_tpu/native/__init__.py::_host_cpu_id —
+  # keep the two derivations identical.
   echo "compiler: $(g++ --version 2>/dev/null | head -n 1)"
   echo "flags: $FLAGS -fPIC -shared -std=c++17"
+  echo "cpu: $(uname -m) $(grep -m1 -E '^(flags|Features)' /proc/cpuinfo 2>/dev/null | sha256sum | cut -d' ' -f1)"
   echo "source: $(sha256sum "$SRC" | cut -d' ' -f1)"
 }
 
@@ -106,8 +114,13 @@ fi
 
 mkdir -p hivemall_tpu/native
 # shellcheck disable=SC2086  # FLAGS is a deliberate word-split flag list
+# compile beside the target and rename over it: concurrent first-use builds
+# (several processes importing hivemall_tpu.native at once) each publish a
+# complete library, never a half-written one
 g++ $FLAGS -fPIC -shared -std=c++17 \
     "$SRC" \
-    -o "$SO"
-stamp_content > "$STAMP"
+    -o "$SO.tmp.$$"
+mv -f "$SO.tmp.$$" "$SO"
+stamp_content > "$STAMP.tmp.$$"
+mv -f "$STAMP.tmp.$$" "$STAMP"
 echo "built $SO (stamp: $STAMP)"

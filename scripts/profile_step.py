@@ -1,7 +1,7 @@
 """Microbenchmark the AROW minibatch step's components on the current device.
 
 Times (a) full step, (b) gather+math only, (c) each scatter variant, to find
-where the ~10ms/step goes (PERF.md optimization plan step 1).
+where the ~10ms/step goes (docs/perf_history.md optimization plan step 1).
 
 Compile time and steady-state step time are reported SEPARATELY: the first
 call is timed under `recompile_guard` (runtime/metrics.py), which counts jit

@@ -1,5 +1,5 @@
 """Score the built-in tokenize_ja lattice analyzer against the gold
-segmentation fixture; prints one JSON line (the number PERF.md cites).
+segmentation fixture; prints one JSON line (the number docs/perf_history.md cites).
 
 Run: python scripts/score_tokenizer_gold.py
 """

@@ -174,9 +174,18 @@ def test_batch_b1_equals_minibatch_b1(rule, hyper, binary):
     bstep = make_batch_train_step(rule, hyper, batch_size=1, donate=False)
     s_b, _ = bstep(_state(rule, d), idx, val, y,
                    stage_block_plans(idx, 1, d))
+    # derive_w rules rebuild w from slot sums the batch backend forms as
+    # cumsum[end] - cumsum[start] (ops/scatter.staged_segment_totals): a
+    # 65.09 squared-gradient lane behind a 66,940 chunk prefix is rounded
+    # to the prefix's ulp (2^-8 -> 4.4e-5 relative in G, 2.3e-5 in w ~
+    # G^-1/2). Sequential numpy f32 cumsum reproduces the batch value bit
+    # for bit and the reference slots give w to 2e-8 in float64, so this
+    # is the documented reduction-order difference, not a divergence; the
+    # bound is eps_f32 * prefix / segment (6e-5 here), hence 1e-4.
+    w_rtol = 1e-4 if rule.derive_w is not None else 2e-5
     np.testing.assert_allclose(np.asarray(s_b.weights),
                                np.asarray(s_ref.weights),
-                               rtol=2e-5, atol=1e-6)
+                               rtol=w_rtol, atol=1e-6)
     if rule.use_covariance:
         np.testing.assert_allclose(np.asarray(s_b.covars),
                                    np.asarray(s_ref.covars),

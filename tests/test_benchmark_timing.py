@@ -1,8 +1,7 @@
-"""runtime/benchmark.py — the un-fakeable bench timing loop (round 4).
+"""runtime/benchmark.py — the value-verified bench timing loop (round 4).
 
-Motivated by a measured relay artifact: block_until_ready acknowledging
-buffers whose producing execution had not finished, letting async timing
-loops report enqueue rate (PERF.md round-4 note). These tests pin the
+Motivated by a measured artifact: a "dispatch N, block once" loop reporting
+the enqueue rate instead of the execution rate. These tests pin the
 helper's contract: budget-bounded, chunk auto-ranging, and the step-counter
 verification that catches dropped executions.
 """

@@ -32,6 +32,14 @@ def test_eight_devices_available():
     assert len(jax.devices()) == 8
 
 
+def test_make_mesh_refuses_more_devices_than_exist():
+    """A mesh smaller than the one asked for would train on fewer workers
+    without anyone noticing — make_mesh raises like make_mesh_2d does."""
+    with pytest.raises(ValueError, match="need 9 devices, have 8"):
+        make_mesh(9)
+    assert make_mesh(4).devices.size == 4
+
+
 def test_mix_average_trains_across_replicas():
     dims, n_dev = 64, 8
     mesh = make_mesh(n_dev)

@@ -284,6 +284,9 @@ def test_unloadable_so_is_reported_not_swallowed(tmp_path, monkeypatch):
     # redirect the loader to a (nonexistent) .asan.so and skip the warning
     monkeypatch.setenv("HIVEMALL_TPU_NATIVE_SANITIZE", "")
     monkeypatch.setattr(nat, "_LIB_PATH", str(bad))
+    # this host's stamp: the case under test is a library that passes the
+    # provenance check and then fails in CDLL
+    monkeypatch.setattr(nat, "_stale_reason", lambda: None)
     monkeypatch.setattr(nat, "_lib", None)
     monkeypatch.setattr(nat, "_load_error", None)
     with pytest.warns(UserWarning, match="failed to load"):

@@ -38,9 +38,10 @@ def test_if_stale_is_idempotent_and_stamped():
         assert os.path.isfile(STAMP), "build must leave a stamp"
         with open(STAMP, encoding="utf-8") as fh:
             stamp = fh.read()
-        # compiler identity + flags + source hash: the three staleness axes
+        # compiler identity + flags + target CPU + source hash: the four
+        # staleness axes
         assert "compiler:" in stamp and "flags:" in stamp \
-            and "source:" in stamp
+            and "cpu:" in stamp and "source:" in stamp
     else:
         assert "no g++" in second.stdout + second.stderr
 
@@ -67,6 +68,68 @@ def test_flag_drift_in_stamp_forces_rebuild():
         if os.path.isfile(SO) and open(STAMP).read() != good:
             with open(STAMP, "w", encoding="utf-8") as fh:
                 fh.write(good)
+
+
+def _foreign_cpu_stamp():
+    """Rewrite the stamp's cpu line as another host's; returns the true
+    stamp text for restoring."""
+    _build("--if-stale")
+    with open(STAMP, encoding="utf-8") as fh:
+        good = fh.read()
+    with open(STAMP, "w", encoding="utf-8") as fh:
+        fh.write(good.replace("cpu: ", "cpu: elsewhere-"))
+    return good
+
+
+def _fresh_loader(monkeypatch):
+    monkeypatch.setenv("HIVEMALL_TPU_NATIVE_SANITIZE", "")
+    monkeypatch.setattr(nat, "_lib", None)
+    monkeypatch.setattr(nat, "_load_error", None)
+    monkeypatch.setattr(nat, "_built_because", None)
+
+
+@pytest.mark.skipif(shutil.which("g++") is None,
+                    reason="no g++: foreign-CPU rebuild not exercisable")
+def test_foreign_cpu_build_is_rebuilt_not_loaded(monkeypatch):
+    """A library stamped by another CPU (the tree was copied between hosts;
+    -march=native makes it a SIGILL hazard — it killed hm_pack_block on the
+    TPU VM) must be rebuilt by --if-stale AND by the loader's first use;
+    the loader's stamp derivation must agree with the script's."""
+    good = _foreign_cpu_stamp()
+    try:
+        proc = _build("--if-stale")
+        assert proc.returncode == 0, proc.stdout + proc.stderr
+        assert "built" in proc.stdout, proc.stdout + proc.stderr
+        with open(STAMP, encoding="utf-8") as fh:
+            assert fh.read() == good
+        assert nat._stale_reason() is None  # python id == script id
+
+        _foreign_cpu_stamp()
+        _fresh_loader(monkeypatch)
+        assert "another CPU" in nat._stale_reason()
+        assert nat._load() is not None
+        info = nat.build_info()
+        assert "another CPU" in info["built_at_first_use"]
+        assert info["stamp"]["cpu"] == info["host_cpu"]
+    finally:
+        _build("--if-stale")
+
+
+def test_foreign_cpu_build_without_compiler_is_refused(monkeypatch):
+    """No compiler to rebuild with: the foreign-CPU library stays on disk
+    but is NOT loaded, and the cause is named."""
+    if not os.path.isfile(SO):
+        pytest.skip("no library on disk and none buildable")
+    good = _foreign_cpu_stamp()
+    try:
+        _fresh_loader(monkeypatch)
+        monkeypatch.setattr(nat.shutil, "which", lambda _name: None)
+        with pytest.warns(UserWarning, match="another CPU"):
+            assert nat._load() is None
+        assert "no g++" in nat.load_error()
+    finally:
+        with open(STAMP, "w", encoding="utf-8") as fh:
+            fh.write(good)
 
 
 def test_unknown_sanitize_mode_is_a_hard_error():

@@ -117,6 +117,32 @@ def test_fit_linear_pallas_option():
     val = [rng.randn(d).astype(np.float32) for _ in range(n)]
     y = np.array([np.sign(v @ w) for v in val])
     m_ref = train_arow((idx, val), y, "-dims 32")
-    m_pal = train_arow((idx, val), y, "-dims 32 -pallas")
+    # interpret mode is asked for explicitly, in Python: the option string
+    # alone never selects it (see the refusal test below)
+    m_pal = train_arow((idx, val), y, "-dims 32 -pallas",
+                       pallas_interpret=True)
     np.testing.assert_allclose(np.asarray(m_pal.state.weights),
                                np.asarray(m_ref.state.weights), rtol=1e-5, atol=1e-6)
+
+
+def test_fit_linear_pallas_refusals():
+    """`-pallas` off-TPU raises instead of silently interpreting, and dims
+    whose tables cannot be VMEM-resident are refused with the arithmetic —
+    before anything reaches the compiler."""
+    from hivemall_tpu.kernels.linear_scan import (VMEM_TABLE_BUDGET_BYTES,
+                                                  vmem_resident_reason)
+    from hivemall_tpu.models.classifier import ADAGRAD_RDA, AROW, train_arow
+
+    idx = [np.arange(4, dtype=np.int64)] * 8
+    val = [np.ones(4, np.float32)] * 8
+    y = np.ones(8)
+    with pytest.raises(ValueError, match="jax is on 'cpu'"):
+        train_arow((idx, val), y, "-dims 32 -pallas")
+    with pytest.raises(ValueError, match="refused.*MiB of VMEM"):
+        train_arow((idx, val), y, "-dims 16777216 -pallas",
+                   pallas_interpret=True)
+    # the bound is computed from the table count: w+cov fit where
+    # w+2 slots of the same width do not
+    fits_two = VMEM_TABLE_BUDGET_BYTES // (2 * 2 * 4)
+    assert vmem_resident_reason(AROW, fits_two) is None
+    assert "3 f32 table" in vmem_resident_reason(ADAGRAD_RDA, fits_two)

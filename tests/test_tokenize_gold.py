@@ -10,7 +10,7 @@ Honesty note: the bundled lexicon was GROWN against this fixture
 (dev-set methodology, VERDICT r3 next #4), so the measured score is an
 upper bound on open-domain accuracy; the gate at F1 >= 0.9 is a
 regression floor for lexicon/lattice/native-kernel changes, and
-scripts/score_tokenizer_gold.py reports the current number for PERF.md."""
+scripts/score_tokenizer_gold.py reports the current number for docs/perf_history.md."""
 
 import os
 
@@ -52,7 +52,7 @@ def test_normal_mode_f1_gate(gold):
 
 def test_heldout_f1_gate():
     """Second fixture, measured BLIND first (F1 0.872 before the vocabulary
-    it exposed was added — the number PERF.md records as the open-domain
+    it exposed was added — the number docs/perf_history.md records as the open-domain
     estimate); after growth it joins the regression floor."""
     heldout = load_gold(HELDOUT_PATH)
     assert len(heldout) >= 30
@@ -63,7 +63,7 @@ def test_heldout_f1_gate():
 
 def test_blind2_f1_gate():
     """Round-4b third fixture, measured BLIND first against the grown
-    (3043-surface) lexicon: first-pass span F1 0.9773 — the number PERF.md
+    (3043-surface) lexicon: first-pass span F1 0.9773 — the number docs/perf_history.md
     records as the open-domain estimate for this lexicon generation (up
     from 0.872 for the previous one). After its three OOV misses (口座,
     毎週, について) were folded it joins the regression floor."""
@@ -94,7 +94,7 @@ def test_round5_blind_f1_gates(fixture, first_pass):
       2-kanji unknown run (雪/崩, 法/案).
     - blind5 first-pass 0.9522 — after the kanji unknown retune
       ((900,900) -> (1100,500)); >= 0.95, the round-5 OOV-domain accuracy
-      claim recorded in PERF.md. Each first-pass number was measured BEFORE
+      claim recorded in docs/perf_history.md. Each first-pass number was measured BEFORE
       any fix responding to that fixture; folds happened only after.
 
     blind6 (0.9310 first-pass, composed after the wave 2-5 vocabulary
