@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_PACK_TABLES,
+                               SCOPE_REDUCE, SCOPE_RULE, SCOPE_TOUCHED)
 from .state import LinearState
 
 
@@ -238,34 +240,43 @@ def make_train_fn(
             tf = (t + 1).astype(jnp.float32)
             if rule.pre_row is not None:
                 gl = rule.pre_row(gl, y)
-            ctx, sidx = build_ctx((weights, covars, slots), idx, val, y, tf, gl)
-            out = rule.update(ctx, hyper)
-            # rule math runs in f32; bf16 tables (the SpaceEfficientDenseModel
-            # analog) take the delta cast to their storage dtype
-            weights = weights.at[sidx].add(
-                out.dw.astype(weights.dtype), mode="drop")
-            if use_cov and out.dcov is not None:
-                covars = covars.at[sidx].add(
-                    out.dcov.astype(covars.dtype), mode="drop")
-            new_slots = dict(slots)
-            for k, d in out.dslots.items():
-                new_slots[k] = slots[k].at[sidx].add(
-                    d.astype(slots[k].dtype), mode="drop")
-            if rule.derive_w is not None:
-                # lane-wise slot values after this row's delta
-                sl_new = {k: ctx.slots[k] + out.dslots.get(k, 0.0) for k in slots}
-                w_new = rule.derive_w(sl_new, tf, hyper)
-                w_new = jnp.where(out.updated, w_new, ctx.w)
-                weights = weights.at[sidx].set(
-                    w_new.astype(weights.dtype), mode="drop")
-            upd = out.updated.astype(jnp.int8)
-            touched = touched.at[sidx].max(jnp.broadcast_to(upd, sidx.shape), mode="drop")
+            with jax.named_scope(SCOPE_GATHER):
+                ctx, sidx = build_ctx((weights, covars, slots), idx, val, y,
+                                      tf, gl)
+            with jax.named_scope(SCOPE_RULE):
+                out = rule.update(ctx, hyper)
+            with jax.named_scope(SCOPE_APPLY):
+                # rule math runs in f32; bf16 tables (the
+                # SpaceEfficientDenseModel analog) take the delta cast to
+                # their storage dtype
+                weights = weights.at[sidx].add(
+                    out.dw.astype(weights.dtype), mode="drop")
+                if use_cov and out.dcov is not None:
+                    covars = covars.at[sidx].add(
+                        out.dcov.astype(covars.dtype), mode="drop")
+                new_slots = dict(slots)
+                for k, d in out.dslots.items():
+                    new_slots[k] = slots[k].at[sidx].add(
+                        d.astype(slots[k].dtype), mode="drop")
+                if rule.derive_w is not None:
+                    # lane-wise slot values after this row's delta
+                    sl_new = {k: ctx.slots[k] + out.dslots.get(k, 0.0)
+                              for k in slots}
+                    w_new = rule.derive_w(sl_new, tf, hyper)
+                    w_new = jnp.where(out.updated, w_new, ctx.w)
+                    weights = weights.at[sidx].set(
+                        w_new.astype(weights.dtype), mode="drop")
+            with jax.named_scope(SCOPE_TOUCHED):
+                upd = out.updated.astype(jnp.int8)
+                touched = touched.at[sidx].max(
+                    jnp.broadcast_to(upd, sidx.shape), mode="drop")
             if track_deltas:
-                new_slots[DELTA_SLOT] = slots[DELTA_SLOT].at[sidx].add(
-                    jnp.broadcast_to(
-                        out.updated.astype(slots[DELTA_SLOT].dtype),
-                        sidx.shape),
-                    mode="drop")
+                with jax.named_scope(SCOPE_APPLY):
+                    new_slots[DELTA_SLOT] = slots[DELTA_SLOT].at[sidx].add(
+                        jnp.broadcast_to(
+                            out.updated.astype(slots[DELTA_SLOT].dtype),
+                            sidx.shape),
+                        mode="drop")
             return (weights, covars, new_slots, touched, t + 1, gl), out.loss
 
         carry0 = (state.weights, state.covars, state.slots, state.touched, state.step,
@@ -290,17 +301,22 @@ def make_train_fn(
         # pack w+cov once per block so every row's two scalar gathers become
         # one pair-row gather (see _row_ctx; the [D,2] stack is one ~0.1ms
         # full-table pass vs ~13ms saved per 512k-update block on v5e)
-        packed = (jnp.stack([state.weights, state.covars], axis=-1)
-                  if use_cov else None)
+        with jax.named_scope(SCOPE_PACK_TABLES):
+            packed = (jnp.stack([state.weights, state.covars], axis=-1)
+                      if use_cov else None)
 
         def per_row(idx, val, y, tf):
-            ctx, sidx = build_ctx((state.weights, state.covars, state.slots),
-                                  idx, val, y, tf, gl, packed)
-            return rule.update(ctx, hyper), sidx
+            with jax.named_scope(SCOPE_GATHER):
+                ctx, sidx = build_ctx(
+                    (state.weights, state.covars, state.slots), idx, val, y,
+                    tf, gl, packed)
+            with jax.named_scope(SCOPE_RULE):
+                return rule.update(ctx, hyper), sidx
 
         outs, sidx = jax.vmap(per_row)(indices, values, labels, ts)
-        upd = outs.updated.astype(jnp.float32)  # [B]
-        lane_upd = upd[:, None] * jnp.ones_like(values)  # [B, K]
+        with jax.named_scope(SCOPE_RULE):
+            upd = outs.updated.astype(jnp.float32)  # [B]
+            lane_upd = upd[:, None] * jnp.ones_like(values)  # [B, K]
 
         weights, covars, slots = state.weights, state.covars, state.slots
         if mini_batch_average:
@@ -310,58 +326,75 @@ def make_train_fn(
             # table write (the SpaceEfficientDenseModel analog stores
             # compact, never accumulates compact).
             acc = jnp.promote_types(weights.dtype, jnp.float32)
-            counts = jnp.zeros(weights.shape, acc).at[sidx].add(
-                lane_upd, mode="drop")
-            denom = jnp.maximum(counts, 1.0)
-            dw_sum = jnp.zeros(weights.shape, acc).at[sidx].add(
-                outs.dw.astype(acc), mode="drop")
-            weights = (weights.astype(acc) + dw_sum / denom) \
-                .astype(weights.dtype)
+            # scopes follow the statements' order: moving one would change
+            # the traced program, and with it the compile cache's key
+            with jax.named_scope(SCOPE_REDUCE):
+                counts = jnp.zeros(weights.shape, acc).at[sidx].add(
+                    lane_upd, mode="drop")
+            with jax.named_scope(SCOPE_APPLY):
+                denom = jnp.maximum(counts, 1.0)
+            with jax.named_scope(SCOPE_REDUCE):
+                dw_sum = jnp.zeros(weights.shape, acc).at[sidx].add(
+                    outs.dw.astype(acc), mode="drop")
+            with jax.named_scope(SCOPE_APPLY):
+                weights = (weights.astype(acc) + dw_sum / denom) \
+                    .astype(weights.dtype)
             if use_cov and outs.dcov is not None:
-                dc_sum = jnp.zeros(covars.shape, acc).at[sidx].add(
-                    outs.dcov.astype(acc), mode="drop")
-                covars = (covars.astype(acc) + dc_sum / denom) \
-                    .astype(covars.dtype)
+                with jax.named_scope(SCOPE_REDUCE):
+                    dc_sum = jnp.zeros(covars.shape, acc).at[sidx].add(
+                        outs.dcov.astype(acc), mode="drop")
+                with jax.named_scope(SCOPE_APPLY):
+                    covars = (covars.astype(acc) + dc_sum / denom) \
+                        .astype(covars.dtype)
         else:
-            weights = weights.at[sidx].add(
-                outs.dw.astype(weights.dtype), mode="drop")
-            if use_cov and outs.dcov is not None:
-                covars = covars.at[sidx].add(
-                    outs.dcov.astype(covars.dtype), mode="drop")
+            with jax.named_scope(SCOPE_APPLY):
+                weights = weights.at[sidx].add(
+                    outs.dw.astype(weights.dtype), mode="drop")
+                if use_cov and outs.dcov is not None:
+                    covars = covars.at[sidx].add(
+                        outs.dcov.astype(covars.dtype), mode="drop")
         new_slots = dict(slots)
-        for k in rule.slot_names:
-            if k in outs.dslots:
-                new_slots[k] = slots[k].at[sidx].add(
-                    outs.dslots[k].astype(slots[k].dtype), mode="drop")
-        if rule.derive_w is not None:
-            # Dual-averaging weights are a pure function of the *updated*
-            # accumulators — gather-after-scatter makes duplicate features
-            # across the batch deterministic.
-            tf_end = (t0 + b).astype(jnp.float32)
-            sl_g = {k: _gather(new_slots[k], sidx) for k in new_slots}
-            w_new = rule.derive_w(sl_g, tf_end, hyper)  # [B, K]
-            keep = _gather(weights, sidx)
-            w_new = jnp.where(lane_upd > 0, w_new, keep)
-            weights = weights.at[sidx].set(
-                w_new.astype(weights.dtype), mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            for k in rule.slot_names:
+                if k in outs.dslots:
+                    new_slots[k] = slots[k].at[sidx].add(
+                        outs.dslots[k].astype(slots[k].dtype), mode="drop")
+            if rule.derive_w is not None:
+                # Dual-averaging weights are a pure function of the
+                # *updated* accumulators — gather-after-scatter makes
+                # duplicate features across the batch deterministic.
+                tf_end = (t0 + b).astype(jnp.float32)
+                sl_g = {k: _gather(new_slots[k], sidx) for k in new_slots}
+                w_new = rule.derive_w(sl_g, tf_end, hyper)  # [B, K]
+                keep = _gather(weights, sidx)
+                w_new = jnp.where(lane_upd > 0, w_new, keep)
+                weights = weights.at[sidx].set(
+                    w_new.astype(weights.dtype), mode="drop")
         if mini_batch_average:
             # `counts` is exactly this block's per-feature lane_upd scatter,
             # so touched and the MIX delta clock derive from it with cheap
             # full-table elementwise ops instead of two more scalar
             # scatters (~7ms each per 512k-update block on v5e).
-            touched = jnp.maximum(state.touched, (counts > 0).astype(jnp.int8))
+            with jax.named_scope(SCOPE_TOUCHED):
+                touched = jnp.maximum(state.touched,
+                                      (counts > 0).astype(jnp.int8))
             if track_deltas:
-                delta_tab = new_slots.get(DELTA_SLOT, state.slots[DELTA_SLOT])
-                new_slots[DELTA_SLOT] = delta_tab + counts.astype(
-                    delta_tab.dtype)
+                with jax.named_scope(SCOPE_APPLY):
+                    delta_tab = new_slots.get(DELTA_SLOT,
+                                              state.slots[DELTA_SLOT])
+                    new_slots[DELTA_SLOT] = delta_tab + counts.astype(
+                        delta_tab.dtype)
         else:
-            touched = state.touched.at[sidx].max(
-                lane_upd.astype(jnp.int8), mode="drop"
-            )
+            with jax.named_scope(SCOPE_TOUCHED):
+                touched = state.touched.at[sidx].max(
+                    lane_upd.astype(jnp.int8), mode="drop"
+                )
             if track_deltas:
-                delta_tab = new_slots.get(DELTA_SLOT, state.slots[DELTA_SLOT])
-                new_slots[DELTA_SLOT] = delta_tab.at[sidx].add(
-                    lane_upd.astype(delta_tab.dtype), mode="drop")
+                with jax.named_scope(SCOPE_APPLY):
+                    delta_tab = new_slots.get(DELTA_SLOT,
+                                              state.slots[DELTA_SLOT])
+                    new_slots[DELTA_SLOT] = delta_tab.at[sidx].add(
+                        lane_upd.astype(delta_tab.dtype), mode="drop")
         new_state = state.replace(
             weights=weights,
             covars=covars,
@@ -370,7 +403,9 @@ def make_train_fn(
             step=t0 + b,
             globals=gl,
         )
-        return new_state, jnp.sum(outs.loss)
+        with jax.named_scope(SCOPE_RULE):
+            loss_sum = jnp.sum(outs.loss)
+        return new_state, loss_sum
 
     def minibatch_step_mxu(state: LinearState, indices, values, labels):
         """minibatch_step with every random table access routed through

@@ -20,6 +20,10 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from ..runtime.metrics import REGISTRY
+from ..runtime.tracing import (SPAN_EMIT, SPAN_EMIT_D2H, SPAN_EMIT_SELECT,
+                               TRACER)
+
 
 @struct.dataclass
 class LinearState:
@@ -79,16 +83,36 @@ def init_linear_state(
     )
 
 
+def table_to_host(table, name: str) -> np.ndarray:
+    """One whole table copied to the host under an `emit.d2h` span; the
+    bytes go to the `emit.d2h_bytes` counter and to the open
+    `emit.model_rows` span's sum."""
+    with TRACER.span(SPAN_EMIT_D2H, args={"table": name}) as sp:
+        out = np.asarray(table)
+        sp.set(bytes=out.nbytes)
+    REGISTRY.counter("emit", "d2h_bytes").increment(out.nbytes)
+    return out
+
+
 def model_rows(state: LinearState, filter_zero: bool = False):
     """Dump the model as (feature, weight[, covar]) arrays over touched
     entries — the close() model emission (ref: BinaryOnlineClassifierUDTF.java:254-291).
     """
-    touched = np.asarray(state.touched) != 0
-    if filter_zero:
-        touched &= np.asarray(state.weights) != 0.0
-    feats = np.nonzero(touched)[0].astype(np.int64)
-    weights = np.asarray(state.weights)[feats]
-    if state.covars is not None:
-        covars = np.asarray(state.covars)[feats]
-        return feats, weights, covars
-    return feats, weights
+    with TRACER.span(SPAN_EMIT, args={
+            "table_dtype": str(state.weights.dtype)}) as emit:
+        touched = table_to_host(state.touched, "touched")
+        weights = table_to_host(state.weights, "weights")
+        covars = table_to_host(state.covars, "covars") \
+            if state.covars is not None else None
+        tables = [t for t in (touched, weights, covars) if t is not None]
+        with TRACER.span(SPAN_EMIT_SELECT) as select:
+            keep = touched != 0
+            if filter_zero:
+                keep &= weights != 0.0
+            feats = np.nonzero(keep)[0].astype(np.int64)
+            out = (feats,) + tuple(t[feats] for t in tables[1:])
+            select.set(rows_out=len(feats))
+        emit.set(rows_out=len(feats),
+                 d2h_bytes=sum(t.nbytes for t in tables))
+    REGISTRY.counter("emit", "rows").increment(len(feats))
+    return out

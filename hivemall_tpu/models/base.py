@@ -30,6 +30,10 @@ from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
 from ..core.engine import Rule, make_predict, make_train_step
 from ..core.state import LinearState, init_linear_state, model_rows
 from ..ops.convergence import ConversionState
+from ..runtime.metrics import REGISTRY, _jit_cache_size
+from ..runtime.tracing import (SPAN_CALL, SPAN_COMPILED_STEP, SPAN_DATA_PREP,
+                               SPAN_EPOCH, SPAN_INIT_STATE, SPAN_STAGE,
+                               SPAN_SYNC, TRACER)
 from ..utils.feature import parse_features_batch
 from ..utils.options import CommandLine, Options
 
@@ -92,12 +96,73 @@ ArrayRows = Tuple[List[np.ndarray], List[np.ndarray]]
 FeatureRows = Union[Sequence[Sequence[str]], ArrayRows]
 
 
+def _is_array_rows(features: FeatureRows) -> bool:
+    return isinstance(features, tuple) and len(features) == 2
+
+
 def _stage_rows(features: FeatureRows, dims: int) -> ArrayRows:
-    if isinstance(features, tuple) and len(features) == 2:
+    if _is_array_rows(features):
         idx_rows = [np.asarray(r, dtype=np.int64) % dims for r in features[0]]
         val_rows = [np.asarray(v, dtype=np.float32) for v in features[1]]
         return idx_rows, val_rows
     return parse_features_batch(features, dims)
+
+
+def stage_training_rows(features: FeatureRows, dims: int):
+    """(idx_rows, val_rows, block width) of a training call's rows, under a
+    `train.stage` span (text rows open `train.parse` inside it)."""
+    with TRACER.span(SPAN_STAGE, args={
+            "form": "arrays" if _is_array_rows(features) else "text"}) as sp:
+        idx_rows, val_rows = _stage_rows(features, dims)
+        lens = [len(r) for r in idx_rows]
+        sp.set(rows=len(lens), nnz=sum(lens))
+    return idx_rows, val_rows, pad_to_bucket(max(lens, default=1))
+
+
+def init_state_spanned(init, *args, **kw):
+    """`init(*args, **kw)` under a `train.init_state` span that carries the
+    new state's bytes."""
+    with TRACER.span(SPAN_INIT_STATE) as sp:
+        state = init(*args, **kw)
+        sp.set(state_bytes=sum(x.nbytes
+                               for x in jax.tree_util.tree_leaves(state)))
+    return state
+
+
+def prepared_blocks(idx_rows, val_rows, labels, dims, block_size, width,
+                    extra=None):
+    """The arrays of each training step, `(indices, values, labels) +
+    extra(block)`, each packed under a `train.data_prep` span that carries
+    the bytes handed to the step (`h2d_bytes`, also the `train.h2d_bytes`
+    counter)."""
+    blocks = iter_blocks(idx_rows, val_rows, labels, dims, block_size, width)
+    h2d_counter = REGISTRY.counter("train", "h2d_bytes")
+    for _ in range(-(-len(idx_rows) // block_size)):
+        with TRACER.span(SPAN_DATA_PREP) as sp:
+            block = next(blocks)
+            arrays = (block.indices, block.values, block.labels)
+            if extra is not None:
+                arrays += extra(block)
+            h2d = sum(a.nbytes for a in arrays)
+            sp.set(rows=block.batch_size, width=block.width, h2d_bytes=h2d)
+        h2d_counter.increment(h2d)
+        yield arrays
+
+
+def dispatch_step(step, step_no: int, *args):
+    """One call of the jitted `step` under a `train.compiled_step` span.
+    `compiled` is true where the jit's cache grew across the call: the span
+    then holds the trace, the lowering and the compile (or the persistent
+    cache's read), and says so by a `jit_recompile` instant."""
+    with TRACER.span(SPAN_COMPILED_STEP, args={"step": step_no}) as sp:
+        before = _jit_cache_size(step)
+        out = step(*args)
+        grew = _jit_cache_size(step) - before
+        sp.set(compiled=grew > 0)
+        if grew > 0:
+            sp.event("jit_recompile", guard=SPAN_CALL, compiles=grew)
+            REGISTRY.counter("train", "jit_compiles").increment(grew)
+    return out
 
 
 @dataclass
@@ -180,8 +245,6 @@ def _fit_native_scan(rule, hyper, cl, dims, idx_rows, val_rows, labels,
         raise RuntimeError("-native_scan requires the native library "
                            "(bash scripts/build_native.sh)")
 
-    from ..runtime.metrics import REGISTRY
-
     iters = cl.get_int("iters", 1)
     n = len(idx_rows)
     conv = ConversionState(not cl.has("disable_cv"),
@@ -236,9 +299,6 @@ def _fit_native_batch(rule, hyper, cl, dims, idx_rows, val_rows, labels,
     from ..core.native_batch import (init_native_tables,
                                      make_native_batch_step,
                                      native_tables_to_state)
-    from ..ops.convergence import ConversionState
-    from ..runtime.metrics import REGISTRY
-
     step = make_native_batch_step(rule, hyper)
     tables = init_native_tables(dims, rule.use_covariance,
                                 initial_weights, initial_covars)
@@ -289,7 +349,19 @@ def fit_linear(
     `pallas_interpret` runs the `-pallas` kernel in the Pallas interpreter
     instead of compiling it — for tests that check the kernel's semantics
     off-chip. It is a Python argument on purpose, never an option string:
-    a user's `-pallas` either compiles for the TPU or is refused."""
+    a user's `-pallas` either compiles for the TPU or is refused.
+
+    The whole call is one `train.call` span (docs/observability.md): option
+    reads and backend refusals are its self time, everything else a child."""
+    with TRACER.span(SPAN_CALL, args={"entry": rule.name}) as call:
+        return _fit_linear(call, rule, hyper, cl, features, labels,
+                           label_map, initial_weights, initial_covars,
+                           default_dims, pallas_interpret)
+
+
+def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
+                initial_weights, initial_covars, default_dims,
+                pallas_interpret) -> TrainedLinearModel:
     dims = cl.get_int("dims") or default_dims
     mini_batch = cl.get_int("mini_batch", 1)
     iters = cl.get_int("iters", 1)
@@ -304,11 +376,10 @@ def fit_linear(
         feats0, w0, c0 = load_model_rows(cl.get("loadmodel"))
         initial_weights, initial_covars = dense_from_rows(dims, feats0, w0, c0)
 
-    idx_rows, val_rows = _stage_rows(features, dims)
+    idx_rows, val_rows, width = stage_training_rows(features, dims)
     n = len(idx_rows)
     if n == 0:
         raise ValueError("no training rows")
-    width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
 
     batch_b = cl.get_int("batch", 0) if cl.has("batch") else 0
     mode = "minibatch" if mini_batch > 1 else "scan"
@@ -333,6 +404,7 @@ def fit_linear(
                          "-batch B (docs/execution_backends.md)")
     if mode == "minibatch":
         block_size = mini_batch
+    call.set(dims=dims, rows=n, mini_batch=mini_batch, mode=mode)
     if mode == "batch":
         # a staged block must hold whole minibatches: round the block up
         # to a multiple of B (only the dataset's final partial block
@@ -393,7 +465,8 @@ def fit_linear(
     dtype = jnp.float32
     if dims > (1 << 24) and not cl.has("disable_halffloat"):
         dtype = jnp.bfloat16
-    state = init_linear_state(
+    state = init_state_spanned(
+        init_linear_state,
         dims,
         use_covariance=rule.use_covariance,
         slot_names=rule.slot_names,
@@ -402,53 +475,56 @@ def fit_linear(
         initial_weights=initial_weights,
         initial_covars=initial_covars,
     )
+    call.set(table_dtype=str(state.weights.dtype))
 
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     # progress counters, the Hadoop Reporter/Counter analog
     # (ref: UDTFWithOptions.java:59-88, FM iteration counter :529-543)
-    from ..runtime.metrics import REGISTRY
-
     iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
     row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
     # -batch: plans are a pure function of each block's indices, so they
     # are staged on the host once and replayed every epoch (cleared when
     # -shuffle re-deals the rows)
     plan_cache: list = []
+    step_no = 0
     for it in range(max(1, iters)):
-        if cl.has("shuffle") and it > 0:
-            idx_rows, val_rows, labels = shuffle_rows(
-                idx_rows, val_rows, labels, cl.get_int("seed", 31) + it
-            )
-            plan_cache = []
-        # losses stay on device through the epoch — a float() per block
-        # would sync the dispatch stream every step; the convergence check
-        # only needs the epoch total, fetched in ONE batched device_get at
-        # the epoch boundary (graftcheck G002)
-        epoch_losses = []
-        for bi, block in enumerate(
-                iter_blocks(idx_rows, val_rows, labels, dims, block_size,
-                            width)):
-            if mode == "batch":
-                from ..core.batch_update import stage_block_plans
+        with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
+            if cl.has("shuffle") and it > 0:
+                idx_rows, val_rows, labels = shuffle_rows(
+                    idx_rows, val_rows, labels, cl.get_int("seed", 31) + it
+                )
+                plan_cache = []
+            # losses stay on device through the epoch — a float() per block
+            # would sync the dispatch stream every step; the convergence
+            # check only needs the epoch total, fetched in ONE batched
+            # device_get at the epoch boundary (graftcheck G002)
+            epoch_losses = []
+            for bi, block in enumerate(
+                    prepared_blocks(idx_rows, val_rows, labels, dims,
+                                    block_size, width)):
+                if mode == "batch":
+                    from ..core.batch_update import stage_block_plans
 
-                if bi >= len(plan_cache):
-                    # device_put once at staging: replayed epochs must not
-                    # re-upload the plan arrays every block
-                    plan_cache.append(jax.tree_util.tree_map(
-                        jax.device_put,
-                        stage_block_plans(block.indices, batch_b, dims)))
-                state, loss = step(state, block.indices, block.values,
-                                   block.labels, plan_cache[bi])
-            else:
-                state, loss = step(state, block.indices, block.values,
-                                   block.labels)
-            epoch_losses.append(loss)
-            row_counter.increment(block.batch_size)
-        iter_counter.increment()
-        epoch_loss = float(np.sum(jax.device_get(epoch_losses)))
-        conv.incr_loss(epoch_loss)
-        if iters > 1 and conv.is_converged(n):
-            break
+                    if bi >= len(plan_cache):
+                        # device_put once at staging: replayed epochs must
+                        # not re-upload the plan arrays every block
+                        plan_cache.append(jax.tree_util.tree_map(
+                            jax.device_put,
+                            stage_block_plans(block[0], batch_b, dims)))
+                    block += (plan_cache[bi],)
+                state, loss = dispatch_step(step, step_no, state, *block)
+                step_no += 1
+                epoch_losses.append(loss)
+                row_counter.increment(block[0].shape[0])
+            iter_counter.increment()
+            with TRACER.span(SPAN_SYNC,
+                             args={"fetches": len(epoch_losses)}):
+                epoch_loss = float(np.sum(jax.device_get(epoch_losses)))
+            epoch.set(steps=len(epoch_losses))
+            call.set(epochs=it + 1)
+            conv.incr_loss(epoch_loss)
+            if iters > 1 and conv.is_converged(n):
+                break
     return TrainedLinearModel(state=state, rule=rule, dims=dims, block_width=width)
 
 

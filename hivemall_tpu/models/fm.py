@@ -33,11 +33,19 @@ from flax import struct
 
 from ..constants import DEFAULT_NUM_FEATURES
 from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
+from ..core.state import table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.scatter import scatter_rows_flat
 from ..ops.eta import EtaEstimator, get_eta
+from ..runtime.metrics import REGISTRY
+from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
+                               SCOPE_PACK_TABLES, SCOPE_REDUCE, SCOPE_RULE,
+                               SCOPE_TOUCHED, SPAN_CALL, SPAN_EMIT,
+                               SPAN_EMIT_SELECT, SPAN_EPOCH, SPAN_SYNC,
+                               TRACER)
 from ..utils.options import Options
-from .base import FeatureRows, _stage_rows, base_options
+from .base import (FeatureRows, _stage_rows, base_options, dispatch_step,
+                   init_state_spanned, prepared_blocks, stage_training_rows)
 
 DOUBLE_MIN = -1.7976931348623157e308  # mirrors Double.MIN_VALUE default semantics:
 # the reference's minTarget default is Double.MIN_VALUE (smallest positive!),
@@ -258,15 +266,19 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
             return wg, vg, vmask, lidx, p, sum_vfx
 
     def row_deltas(state: FMState, idx, val, y, t, packed=None, pg=None):
-        eta = hyper.eta.eta(t)
-        wg, vg, eff_val, sidx, p, sum_vfx = gather_and_predict(
-            state, idx, val, packed, pg)
-        g, loss = _dloss_and_loss(p, y, hyper)
-        dw0 = -eta * (g + 2.0 * state.lambda_w0 * state.w0)
-        dw = -eta * (g * eff_val + 2.0 * state.lambda_w * wg)
-        x2 = eff_val * eff_val
-        grad_v = eff_val[:, None] * sum_vfx[None, :] - vg * x2[:, None]
-        dv = -eta * (g * grad_v + 2.0 * state.lambda_v[None, :] * vg)
+        with jax.named_scope(SCOPE_RULE):
+            eta = hyper.eta.eta(t)
+        with jax.named_scope(SCOPE_GATHER):
+            wg, vg, eff_val, sidx, p, sum_vfx = gather_and_predict(
+                state, idx, val, packed, pg)
+        with jax.named_scope(SCOPE_LOSS):
+            g, loss = _dloss_and_loss(p, y, hyper)
+        with jax.named_scope(SCOPE_RULE):
+            dw0 = -eta * (g + 2.0 * state.lambda_w0 * state.w0)
+            dw = -eta * (g * eff_val + 2.0 * state.lambda_w * wg)
+            x2 = eff_val * eff_val
+            grad_v = eff_val[:, None] * sum_vfx[None, :] - vg * x2[:, None]
+            dv = -eta * (g * grad_v + 2.0 * state.lambda_v[None, :] * vg)
         return dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx
 
     def lambda_deltas(state: FMState, idx, val, y, t, wg, vg, g, sum_vfx, eta):
@@ -316,7 +328,9 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     def minibatch_step(state: FMState, indices, values, labels, va_mask):
         b = indices.shape[0]
         ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
-        packed = (state.v.at[:, w_lane].set(state.w) if use_packed else None)
+        with jax.named_scope(SCOPE_PACK_TABLES):
+            packed = (state.v.at[:, w_lane].set(state.w) if use_packed
+                      else None)
 
         plan = None
         if use_mxu:
@@ -359,9 +373,12 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
             # FloatAccumulator denominators (shared by the packed and
             # unpacked apply below): per-feature touch counts, w0 by the
             # effective batch size
-            counts = jnp.zeros((state.w.shape[0],), jnp.float32).at[sidx].add(
-                jnp.broadcast_to(theta[:, None], sidx.shape), mode="drop")
-            denom = jnp.maximum(counts, 1.0)
+            with jax.named_scope(SCOPE_REDUCE):
+                counts = jnp.zeros((state.w.shape[0],), jnp.float32) \
+                    .at[sidx].add(jnp.broadcast_to(theta[:, None], sidx.shape),
+                                  mode="drop")
+            with jax.named_scope(SCOPE_APPLY):
+                denom = jnp.maximum(counts, 1.0)
 
         if use_mxu:
             # dv and dw ride one windowed scatter over the packed layout
@@ -418,25 +435,30 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
         elif use_packed:
             # dw rides lane w_lane of the same flat row scatter as dv
             k_log = hyper.factors
-            upd = jnp.concatenate([dv[..., :k_log], dw[..., None]], axis=-1)
+            with jax.named_scope(SCOPE_REDUCE):
+                upd = jnp.concatenate([dv[..., :k_log], dw[..., None]],
+                                      axis=-1)
             if mini_batch_average:
-                acc = scatter_rows_flat(jnp.zeros(state.v.shape, acc_v),
-                                        sidx,
-                                        theta[:, None, None]
-                                        * upd.astype(acc_v))
-                new_w = (state.w.astype(acc_v) + acc[:, w_lane] / denom) \
-                    .astype(state.w.dtype)
-                new_v = (state.v.astype(acc_v)
-                         + acc.at[:, w_lane].set(0.0) / denom[:, None]) \
-                    .astype(state.v.dtype)
-                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                    jnp.sum(theta), 1.0)
+                with jax.named_scope(SCOPE_REDUCE):
+                    acc = scatter_rows_flat(jnp.zeros(state.v.shape, acc_v),
+                                            sidx,
+                                            theta[:, None, None]
+                                            * upd.astype(acc_v))
+                with jax.named_scope(SCOPE_APPLY):
+                    new_w = (state.w.astype(acc_v)
+                             + acc[:, w_lane] / denom).astype(state.w.dtype)
+                    new_v = (state.v.astype(acc_v)
+                             + acc.at[:, w_lane].set(0.0) / denom[:, None]) \
+                        .astype(state.v.dtype)
+                    new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
+                        jnp.sum(theta), 1.0)
             else:
-                pk = scatter_rows_flat(packed, sidx,
-                                       theta[:, None, None] * upd)
-                new_w = pk[:, w_lane]
-                new_v = pk.at[:, w_lane].set(0.0)
-                new_w0 = state.w0 + jnp.sum(theta * dw0)
+                with jax.named_scope(SCOPE_APPLY):
+                    pk = scatter_rows_flat(packed, sidx,
+                                           theta[:, None, None] * upd)
+                    new_w = pk[:, w_lane]
+                    new_v = pk.at[:, w_lane].set(0.0)
+                    new_w0 = state.w0 + jnp.sum(theta * dw0)
         elif mini_batch_average:
             # FloatAccumulator semantics via full-table delta temporaries +
             # one elementwise apply: scattering counts and delta SUMS then
@@ -444,25 +466,31 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
             # for the per-lane denominator GATHER the pre-divided variant
             # needs (diag micro gather rate on v5e) — same math, the
             # denominators just divide at the table instead of the lanes.
-            dw_sum = jnp.zeros(state.w.shape, acc_w).at[sidx].add(
-                theta[:, None] * dw.astype(acc_w), mode="drop")
-            new_w = (state.w.astype(acc_w) + dw_sum / denom) \
-                .astype(state.w.dtype)
-            dv_sum = scatter_v(jnp.zeros(state.v.shape, acc_v),
-                               theta[:, None, None] * dv.astype(acc_v))
-            new_v = (state.v.astype(acc_v) + dv_sum / denom[:, None]) \
-                .astype(state.v.dtype)
-            new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                jnp.sum(theta), 1.0)
+            with jax.named_scope(SCOPE_REDUCE):
+                dw_sum = jnp.zeros(state.w.shape, acc_w).at[sidx].add(
+                    theta[:, None] * dw.astype(acc_w), mode="drop")
+            with jax.named_scope(SCOPE_APPLY):
+                new_w = (state.w.astype(acc_w) + dw_sum / denom) \
+                    .astype(state.w.dtype)
+            with jax.named_scope(SCOPE_REDUCE):
+                dv_sum = scatter_v(jnp.zeros(state.v.shape, acc_v),
+                                   theta[:, None, None] * dv.astype(acc_v))
+            with jax.named_scope(SCOPE_APPLY):
+                new_v = (state.v.astype(acc_v) + dv_sum / denom[:, None]) \
+                    .astype(state.v.dtype)
+                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
+                    jnp.sum(theta), 1.0)
         else:
-            new_w = state.w.at[sidx].add(theta[:, None] * dw, mode="drop")
-            new_v = scatter_v(state.v, theta[:, None, None] * dv)
-            new_w0 = state.w0 + jnp.sum(theta * dw0)
+            with jax.named_scope(SCOPE_APPLY):
+                new_w = state.w.at[sidx].add(theta[:, None] * dw, mode="drop")
+                new_v = scatter_v(state.v, theta[:, None, None] * dv)
+                new_w0 = state.w0 + jnp.sum(theta * dw0)
         if not use_mxu:
-            touched = state.touched.at[sidx].max(
-                jnp.broadcast_to((theta > 0).astype(jnp.int8)[:, None],
-                                 sidx.shape),
-                mode="drop")
+            with jax.named_scope(SCOPE_TOUCHED):
+                touched = state.touched.at[sidx].max(
+                    jnp.broadcast_to((theta > 0).astype(jnp.int8)[:, None],
+                                     sidx.shape),
+                    mode="drop")
         new_state = state.replace(
             w0=new_w0,
             w=new_w,
@@ -483,7 +511,9 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
                 lambda_v=jnp.maximum(0.0, state.lambda_v
                                      + jnp.sum(vam[:, None] * dl_v, axis=0)),
             )
-        return new_state, jnp.sum(theta * loss)
+        with jax.named_scope(SCOPE_LOSS):
+            loss_sum = jnp.sum(theta * loss)
+        return new_state, loss_sum
 
     step = scan_step if mode == "scan" else minibatch_step
     # jit=False returns the raw traceable fn for embedding in an outer scan
@@ -520,12 +550,23 @@ class TrainedFMModel:
     def model_rows(self):
         """(feature, Wi, Vi[factors]) rows + the w0 bias row (feature 0 carries
         w0, ref: forwardAsIntFeature FactorizationMachineUDTF.java:446-519)."""
-        touched = np.asarray(self.state.touched) != 0
-        feats = np.nonzero(touched)[0].astype(np.int64)
-        w = np.asarray(self.state.w)[feats]
-        # slice physical lane padding (padded_factors) back to the logical k
-        v = np.asarray(self.state.v)[feats][:, :self.hyper.factors]
-        return float(self.state.w0), feats, w, v
+        st = self.state
+        with TRACER.span(SPAN_EMIT, args={
+                "table_dtype": str(st.v.dtype)}) as emit:
+            tables = [table_to_host(getattr(st, name), name)
+                      for name in ("touched", "w", "v", "w0")]
+            touched, w, v, w0 = tables
+            with TRACER.span(SPAN_EMIT_SELECT) as select:
+                feats = np.nonzero(touched != 0)[0].astype(np.int64)
+                # slice physical lane padding (padded_factors) back to the
+                # logical k
+                out = (float(w0), feats, w[feats],
+                       v[feats][:, :self.hyper.factors])
+                select.set(rows_out=len(feats))
+            emit.set(rows_out=len(feats),
+                     d2h_bytes=sum(t.nbytes for t in tables))
+        REGISTRY.counter("emit", "rows").increment(len(feats))
+        return out
 
 
 def _fm_options() -> Options:
@@ -556,6 +597,11 @@ def _fm_options() -> Options:
 
 def train_fm(features: FeatureRows, targets, options: Optional[str] = None,
              **kw) -> TrainedFMModel:
+    with TRACER.span(SPAN_CALL, args={"entry": "fm"}) as call:
+        return _train_fm(call, features, targets, options)
+
+
+def _train_fm(call, features, targets, options) -> TrainedFMModel:
     cl = _fm_options().parse(options, "train_fm")
     dims = cl.get_int("dims") or cl.get_int("p") or DEFAULT_NUM_FEATURES
     hyper = FMHyper(
@@ -573,35 +619,54 @@ def train_fm(features: FeatureRows, targets, options: Optional[str] = None,
     targets = np.asarray(targets, dtype=np.float32)
     if hyper.classification:
         targets = np.where(targets > 0, 1.0, -1.0).astype(np.float32)
-    idx_rows, val_rows = _stage_rows(features, dims)
+    idx_rows, val_rows, width = stage_training_rows(features, dims)
     n = len(idx_rows)
-    width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
     mini_batch = cl.get_int("mini_batch", 1)
     mode = "minibatch" if mini_batch > 1 else "scan"
     block = mini_batch if mode == "minibatch" else cl.get_int("block_size", 4096)
     iters = cl.get_int("iters", 1)
+    call.set(dims=dims, rows=n, mini_batch=mini_batch, mode=mode)
     if cl.has("native_scan"):
         return _train_fm_native_scan(cl, hyper, dims, idx_rows, val_rows,
                                      targets, width, block, mode, iters)
     backend = "mxu" if (cl.has("mxu_scatter") and mode == "minibatch") \
         else "xla"
     step = make_fm_step(hyper, mode, update_backend=backend)
-    state = init_fm_state(dims, hyper)
+    state = init_state_spanned(init_fm_state, dims, hyper)
+    call.set(table_dtype=str(state.v.dtype))
     rng = np.random.RandomState(hyper.seed)
+
+    def va_mask(blk):
+        va = (rng.rand(blk.batch_size) < hyper.va_ratio).astype(np.float32) \
+            if hyper.adareg else np.zeros(blk.batch_size, np.float32)
+        return (va,)
+
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
+    # progress counters, as fit_linear keeps them
+    iter_counter = REGISTRY.counter("hivemall", "fm.iterations")
+    row_counter = REGISTRY.counter("hivemall", "fm.examples")
+    step_no = 0
     for it in range(max(1, iters)):
-        if cl.has("shuffle") and it > 0:
-            idx_rows, val_rows, targets = shuffle_rows(idx_rows, val_rows, targets,
-                                                       hyper.seed + it)
-        epoch_loss = 0.0
-        for blk in iter_blocks(idx_rows, val_rows, targets, dims, block, width):
-            va = (rng.rand(blk.batch_size) < hyper.va_ratio).astype(np.float32) \
-                if hyper.adareg else np.zeros(blk.batch_size, np.float32)
-            state, loss = step(state, blk.indices, blk.values, blk.labels, va)
-            epoch_loss += float(loss)
-        conv.incr_loss(epoch_loss)
-        if iters > 1 and conv.is_converged(n):
-            break
+        with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
+            if cl.has("shuffle") and it > 0:
+                idx_rows, val_rows, targets = shuffle_rows(
+                    idx_rows, val_rows, targets, hyper.seed + it)
+            epoch_loss = 0.0
+            steps = 0
+            for blk in prepared_blocks(idx_rows, val_rows, targets, dims,
+                                       block, width, extra=va_mask):
+                state, loss = dispatch_step(step, step_no, state, *blk)
+                step_no += 1
+                steps += 1
+                with TRACER.span(SPAN_SYNC, args={"fetches": 1}):
+                    epoch_loss += float(loss)
+                row_counter.increment(blk[0].shape[0])
+            iter_counter.increment()
+            epoch.set(steps=steps)
+            call.set(epochs=it + 1)
+            conv.incr_loss(epoch_loss)
+            if iters > 1 and conv.is_converged(n):
+                break
     return TrainedFMModel(state=state, hyper=hyper, dims=dims)
 
 

@@ -45,7 +45,8 @@ from ..core.engine import DELTA_SLOT, Rule, make_train_fn
 from ..core.state import LinearState, init_linear_state
 from .mesh import WORKER_AXIS, make_mesh
 from ..runtime.jax_compat import shard_map
-from ..runtime.tracing import TRACER
+from ..runtime.tracing import (SPAN_COMPILED_STEP, SPAN_DATA_PREP, SPAN_SYNC,
+                               TRACER)
 
 
 def mix_average(weights, delta_upd, axis_name: str = WORKER_AXIS):
@@ -439,13 +440,13 @@ class MixTrainer:
         under a ``train.compiled_step`` span: inside a driver's
         ``tracing.step_span`` it becomes the per-step timeline's
         compiled-step stage (runtime/tracing.py)."""
-        with TRACER.span("train.compiled_step", args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_COMPILED_STEP, args={"trainer": "mix_dp"}):
             return self._step(state, indices, values, labels)
 
     def shard_blocks(self, indices, values, labels):
         """Host helper: split [n_dev * k, B, ...] host blocks into the
         [n_dev, k, B, ...] layout."""
-        with TRACER.span("train.data_prep", args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_DATA_PREP, args={"trainer": "mix_dp"}):
             return split_replica_blocks(self.n_dev, indices, values, labels)
 
     def collapse_host(self, host: LinearState) -> LinearState:
@@ -467,6 +468,6 @@ class MixTrainer:
     def final_state(self, state: LinearState) -> LinearState:
         """Collapse the device axis after the trailing mix into one model a
         warm restart can resume from — see collapse_host."""
-        with TRACER.span("train.sync", args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "mix_dp"}):
             host = jax.device_get(state)
         return self.collapse_host(host)

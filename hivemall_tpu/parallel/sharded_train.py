@@ -59,7 +59,8 @@ from .mix import (MixConfig, add_replica_base, collapse_linear_replicas,
                   split_replica_blocks, strip_replica_base)
 from .sharded import stripe_score
 from ..runtime.jax_compat import shard_map
-from ..runtime.tracing import TRACER
+from ..runtime.tracing import (SPAN_COMPILED_STEP, SPAN_DATA_PREP, SPAN_SYNC,
+                               TRACER)
 
 
 def _resolve_1d_mesh(mesh: Optional[Mesh], who: str):
@@ -235,14 +236,14 @@ class ShardedTrainer:
         driver's ``tracing.step_span`` it becomes the per-step timeline's
         compiled-step stage (data-prep and sync are the caller's stages —
         see runtime/tracing.py)."""
-        with TRACER.span("train.compiled_step",
+        with TRACER.span(SPAN_COMPILED_STEP,
                          args={"trainer": "sharded_1d"}):
             return self._step(state, indices, values, labels)
 
     def final_state(self, state: LinearState) -> LinearState:
         """Host-side copy with the padding sliced back off — a plain [dims]
         model for export / warm start / init_linear_state round trips."""
-        with TRACER.span("train.sync", args={"trainer": "sharded_1d"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "sharded_1d"}):
             host = jax.device_get(state)
         return _unpad_state(host, self.dims,
                             self.dims_padded, self._specs, self.axis)
@@ -326,13 +327,13 @@ class FMShardedTrainer:
             # np.shape reads the .shape attribute — no device->host copy of
             # the labels block on the per-step path (graftcheck G002)
             va = np.zeros(np.shape(labels), np.float32)
-        with TRACER.span("train.compiled_step",
+        with TRACER.span(SPAN_COMPILED_STEP,
                          args={"trainer": "fm_sharded"}):
             return self._step(state, indices, values, labels, va)
 
     def final_state(self, state):
         """Host-side copy with the padding sliced back off."""
-        with TRACER.span("train.sync", args={"trainer": "fm_sharded"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "fm_sharded"}):
             host = jax.device_get(state)
         return _unpad_state(host, self.dims,
                             self.dims_padded, self._specs, self.axis)
@@ -449,7 +450,7 @@ class FFMShardedTrainer:
 
     def step(self, state, indices, values, fields, labels):
         """indices/values/fields: [B, K]; labels: [B] (replicated)."""
-        with TRACER.span("train.compiled_step",
+        with TRACER.span(SPAN_COMPILED_STEP,
                          args={"trainer": "ffm_sharded"}):
             return self._step(state, indices, values, fields, labels)
 
@@ -488,7 +489,7 @@ class FFMShardedTrainer:
         TWO independently padded table families (linear at num_features, V
         at v_dims), so the unpad is field-wise rather than the shared
         spec-driven helper (which assumes one padded extent)."""
-        with TRACER.span("train.sync", args={"trainer": "ffm_sharded"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "ffm_sharded"}):
             host = jax.device_get(state)
         nf, dv = self.hyper.num_features, self.hyper.v_dims
         return host.replace(
@@ -563,13 +564,13 @@ class MCShardedTrainer:
 
     def step(self, state, indices, values, labels):
         """indices/values: [B, K]; labels: [B] int (replicated)."""
-        with TRACER.span("train.compiled_step",
+        with TRACER.span(SPAN_COMPILED_STEP,
                          args={"trainer": "mc_sharded"}):
             return self._step(state, indices, values, labels)
 
     def final_state(self, state):
         """Host-side copy with the padding sliced back off."""
-        with TRACER.span("train.sync", args={"trainer": "mc_sharded"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "mc_sharded"}):
             host = jax.device_get(state)
         return _unpad_state(host, self.dims,
                             self.dims_padded, self._specs, self.axis)
@@ -745,13 +746,13 @@ class Sharded2DTrainer:
         """indices/values: [R, k, B, K]; labels: [R, k, B] — replica r's k
         blocks. Each group of mix_every blocks trains locally, then the
         replicas mix."""
-        with TRACER.span("train.compiled_step",
+        with TRACER.span(SPAN_COMPILED_STEP,
                          args={"trainer": "sharded_2d"}):
             return self._step(state, indices, values, labels)
 
     def shard_blocks(self, indices, values, labels):
         """Host helper: split [R * k, B, ...] blocks into [R, k, B, ...]."""
-        with TRACER.span("train.data_prep", args={"trainer": "sharded_2d"}):
+        with TRACER.span(SPAN_DATA_PREP, args={"trainer": "sharded_2d"}):
             return split_replica_blocks(self.n_replicas, indices, values,
                                         labels)
 
@@ -762,7 +763,7 @@ class Sharded2DTrainer:
         run (init(from_state=...)) strips the seeded base from each
         replica's additive statistics before the merge and restores it
         once after — see strip_replica_base/add_replica_base."""
-        with TRACER.span("train.sync", args={"trainer": "sharded_2d"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": "sharded_2d"}):
             host = jax.device_get(state)
         kinds = dict(self.rule.slot_merge)
         base = self._resume_base

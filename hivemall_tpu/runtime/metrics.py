@@ -9,19 +9,18 @@ Mirrors the reference's observability surface (SURVEY.md §5):
 - the MIX server's ThroughputCounter msgs/sec sampling + JMX MBean registry
   (ref: mixserv/.../metrics/ThroughputCounter.java:34, MetricsRegistry.java)
 
-Plus the TPU-native upgrade the reference lacks: `trace()` wraps a block in
-the JAX profiler so kernels show up in xprof/TensorBoard.
+Stage timing lives in runtime/tracing.py, whose spans are also the JAX
+profiler's host marks; `recompile_guard` here is the jit-cache witness.
 """
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import re
 import threading
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, Optional
+from typing import Dict, Optional
 
 
 class StopWatch:
@@ -317,7 +316,7 @@ class recompile_guard:
     recompiling per invocation shows a recompile counter growing linearly
     with steps (the recompilation-count production metric of the ads-infra
     paper, PAPERS.md). ``expect_stable=True`` raises on any miss — used by
-    tests and scripts/profile_step.py to pin the steady state.
+    tests to pin the steady state.
 
     Every guard also taps the jax compile log (``_CompileLogCapture``) and
     records one attribution per compile in ``guard.attributions``:
@@ -410,18 +409,3 @@ class recompile_guard:
                 f"recompile_guard({self.name!r}): {self.compiles} jit cache "
                 f"miss(es) in a section expected steady — a G001-class "
                 f"hazard is retracing the step function ({attrib})")
-
-
-@contextlib.contextmanager
-def trace(name: str, log_dir: Optional[str] = None) -> Iterator[None]:
-    """Wrap a block in the JAX profiler (xprof trace) when log_dir is given;
-    always records wall time as a gauge."""
-    sw = StopWatch(name)
-    if log_dir:
-        import jax
-
-        with jax.profiler.trace(log_dir):
-            yield
-    else:
-        yield
-    REGISTRY.set_gauge(f"{name}.seconds", sw.elapsed())

@@ -42,6 +42,11 @@ regardless (they are cheap); the decision gates which traces are
 threshold commits even when unsampled, so the tail is never invisible.
 ``enabled=False`` turns span creation into a no-op entirely.
 
+One clock with the device: every ``Tracer.span`` extent is also a
+``jax.profiler.TraceAnnotation`` of the same name, so whenever a profile is
+being taken the program's spans lie in the profiler's trace beside the
+device ops (with no session active the annotation is one atomic check).
+
 Usage::
 
     from hivemall_tpu.runtime.tracing import TRACER, step_span
@@ -50,10 +55,11 @@ Usage::
         staged = servable.stage(chunk, b_pad, width_cap)
 
     with step_span("sharded_1d", step=i):        # training timeline
-        with TRACER.span("train.data_prep"):
+        with TRACER.span(SPAN_DATA_PREP):
             blocks = make_blocks(...)
         state, loss = trainer.step(state, *blocks)   # train.compiled_step
-        sync_ready(loss)                             # train.sync
+        with TRACER.span(SPAN_SYNC):
+            jax.block_until_ready(loss)
 
     TRACER.export_chrome("trace.json")   # -> ui.perfetto.dev
 """
@@ -72,7 +78,50 @@ import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional, Tuple
 
+# The vocabulary of one `train_*` UDTF call (docs/observability.md, "The
+# training call's timeline"): host spans from fit_linear/train_fm down to
+# model_rows(), and the device scope names inside the jitted steps. Readers
+# (benchmark/readers/, /trace consumers) match on these strings, so they stay
+# put across refactors. The per-step three are the names the multi-chip
+# trainers (parallel/) emit under their `train.step` root.
+SPAN_CALL = "train.call"
+SPAN_STAGE = "train.stage"
+SPAN_PARSE = "train.parse"
+SPAN_INIT_STATE = "train.init_state"
+SPAN_EPOCH = "train.epoch"
+SPAN_DATA_PREP = "train.data_prep"
+SPAN_COMPILED_STEP = "train.compiled_step"
+SPAN_SYNC = "train.sync"
+SPAN_EMIT = "emit.model_rows"
+SPAN_EMIT_D2H = "emit.d2h"
+SPAN_EMIT_SELECT = "emit.select"
+
+SCOPE_PACK_TABLES = "hm.pack_tables"  # tables stacked for one paired gather
+SCOPE_GATHER = "hm.gather"            # table reads at the block's ids
+SCOPE_RULE = "hm.rule"                # the learner's closed-form update
+SCOPE_REDUCE = "hm.reduce"            # scatters into zeroed delta tables
+SCOPE_APPLY = "hm.apply"              # table writes: add/divide/cast, scatter
+SCOPE_TOUCHED = "hm.touched"          # the emitted-rows flags
+SCOPE_LOSS = "hm.loss"                # FM's loss and its gradient scalar
+LINEAR_SCOPES = (SCOPE_PACK_TABLES, SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE,
+                 SCOPE_APPLY, SCOPE_TOUCHED)
+FM_SCOPES = LINEAR_SCOPES + (SCOPE_LOSS,)
+
 _ID_COUNTER = itertools.count(1)  # __next__ is GIL-atomic: no lock needed
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, bound at the first span
+
+
+def _annotation(name: str):
+    """The profiler's host mark for a span's extent. jax is imported at the
+    first span, not with this module: the tracer stays a leaf that adapters
+    import before any backend is chosen."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION(name)
 
 
 def _new_id(prefix: str) -> str:
@@ -205,13 +254,11 @@ class Tracer:
 
     def __init__(self, capacity: int = 256, sample_rate: float = 1.0,
                  slow_ms: Optional[float] = None, seed: Optional[int] = None,
-                 enabled: bool = True, jax_annotations: bool = False,
-                 slow_reserve: float = 0.25) -> None:
+                 enabled: bool = True, slow_reserve: float = 0.25) -> None:
         self.capacity = int(capacity)
         self.sample_rate = float(sample_rate)
         self.slow_ms = slow_ms
         self.enabled = bool(enabled)
-        self.jax_annotations = bool(jax_annotations)
         self._rng = random.Random(seed)
         # slow-trace retention: with slow_ms set, a fraction of the ring is
         # RESERVED for slow_ms-qualified traces — under sustained overload
@@ -376,22 +423,16 @@ class Tracer:
              ) -> Iterator[Span]:
         """Context-managed span, set as the thread's current for its
         extent so nested spans parent automatically. ``remote`` threads a
-        parsed client ``traceparent`` through to begin(). With
-        ``jax_annotations=True`` the extent is also wrapped in a
-        jax.profiler.TraceAnnotation, so the stage shows up in xprof
-        device timelines under the same name."""
+        parsed client ``traceparent`` through to begin(). The extent is
+        also a jax.profiler.TraceAnnotation, so the stage lies in a
+        profiler trace beside the device ops under the same name."""
         span = self.begin(name, parent=parent, args=args, remote=remote)
         if span is NULL_SPAN:
             yield span
             return
         token = _current.set(span)
         try:
-            if self.jax_annotations:
-                import jax
-
-                with jax.profiler.TraceAnnotation(name):
-                    yield span
-            else:
+            with _annotation(name):
                 yield span
         finally:
             _current.reset(token)
@@ -536,14 +577,12 @@ def _env_float(name: str, default: float) -> float:
 #   HIVEMALL_TPU_TRACE_SLOW_RESERVE=0.25  ring fraction reserved for slow
 #                                    traces (only meaningful with SLOW_MS)
 #   HIVEMALL_TPU_TRACE_CAPACITY=256  ring size (committed traces)
-#   HIVEMALL_TPU_TRACE_JAX=1         bridge spans into jax TraceAnnotations
 _slow = os.environ.get("HIVEMALL_TPU_TRACE_SLOW_MS")
 TRACER = Tracer(
     capacity=int(_env_float("HIVEMALL_TPU_TRACE_CAPACITY", 256)),
     sample_rate=_env_float("HIVEMALL_TPU_TRACE_SAMPLE", 1.0),
     slow_ms=float(_slow) if _slow else None,
     enabled=os.environ.get("HIVEMALL_TPU_TRACE", "1") != "0",
-    jax_annotations=os.environ.get("HIVEMALL_TPU_TRACE_JAX", "0") == "1",
     slow_reserve=_env_float("HIVEMALL_TPU_TRACE_SLOW_RESERVE", 0.25),
 )
 
@@ -554,12 +593,13 @@ def step_span(trainer: str, step: Optional[int] = None,
     """Root span for ONE training step — the per-step timeline the sharded
     and mix trainers feed: open it in the driving loop, and the trainer's
     dispatch lands as a ``train.compiled_step`` child, host block building
-    under ``train.data_prep``, ``sync_ready`` as ``train.sync``::
+    under ``train.data_prep``, the loop's own wait as ``train.sync``::
 
         for i, blk in enumerate(blocks):
             with step_span("sharded_1d", step=i):
                 state, loss = trainer.step(state, *blk)
-                sync_ready(loss)
+                with TRACER.span(SPAN_SYNC):
+                    jax.block_until_ready(loss)
     """
     t = tracer if tracer is not None else TRACER
     args = {"trainer": trainer}
@@ -568,12 +608,3 @@ def step_span(trainer: str, step: Optional[int] = None,
     with t.span("train.step", args=args) as s:
         yield s
 
-
-def sync_ready(tree, tracer: Optional[Tracer] = None):
-    """jax.block_until_ready under a ``train.sync`` span — makes the
-    host-sync cost of a step visible as its own stage; returns ``tree``."""
-    t = tracer if tracer is not None else TRACER
-    with t.span("train.sync"):
-        import jax
-
-        return jax.block_until_ready(tree)

@@ -20,6 +20,8 @@ from typing import List, Sequence, Tuple, Union
 
 import numpy as np
 
+from ..runtime.metrics import REGISTRY
+from ..runtime.tracing import SPAN_PARSE, TRACER
 from .hashing import DEFAULT_NUM_FEATURES, mhash, murmurhash3_bytes_batch
 
 FeatureLike = Union[str, Tuple[int, float], Tuple[str, float]]
@@ -78,6 +80,16 @@ def parse_features_batch(
     matching the reference's dense-model int-feature path
     (ref: LearnerBaseUDTF.java:164-196 dense vs sparse model selection).
     """
+    with TRACER.span(SPAN_PARSE) as sp:
+        idx_rows, val_rows, took_native = _parse_rows(rows, num_features)
+        tokens = sum(len(r) for r in idx_rows)
+        sp.set(tokens=tokens, native=took_native)
+    REGISTRY.counter("train", "parse_tokens").increment(tokens)
+    return idx_rows, val_rows
+
+
+def _parse_rows(rows, num_features: int):
+    """(indices, values, whether the native parser took the rows)."""
     from .. import native
 
     # C fast path: one pass over a concatenated token buffer (parse + hash +
@@ -85,7 +97,7 @@ def parse_features_batch(
     # numeric literals, or malformed tokens (identical error behavior).
     fast = native.parse_features_bulk(rows, num_features)
     if fast is not None:
-        return fast
+        return fast + (True,)
 
     idx_rows: List[np.ndarray] = []
     val_rows: List[np.ndarray] = []
@@ -113,7 +125,7 @@ def parse_features_batch(
         hashed = murmurhash3_bytes_batch(str_names, num_features)
         for (r, k), h in zip(str_slots, hashed):
             idx_rows[r][k] = h
-    return idx_rows, val_rows
+    return idx_rows, val_rows, False
 
 
 @dataclass

@@ -9,7 +9,6 @@ from hivemall_tpu.nlp import tokenize_ja, tokenize_ja_bulk
 from hivemall_tpu.runtime import (Counter, MetricsRegistry, StopWatch,
                                   ThroughputCounter)
 from hivemall_tpu.runtime.cluster import parse_mix_option
-from hivemall_tpu.runtime.metrics import trace
 
 
 class TestRuntime:
@@ -30,13 +29,6 @@ class TestRuntime:
         for _ in range(100):
             t.record(10)
         assert t.last_reads_per_sec > 0
-
-    def test_trace_records_gauge(self):
-        from hivemall_tpu.runtime.metrics import REGISTRY
-
-        with trace("unit_test_block"):
-            pass
-        assert "unit_test_block.seconds" in REGISTRY.snapshot()
 
     def test_parse_mix_option(self):
         assert parse_mix_option("host1,host2") == ("host1", 11212)

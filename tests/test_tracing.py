@@ -11,8 +11,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from hivemall_tpu.runtime.tracing import (TRACER, Tracer, step_span,
-                                          sync_ready)
+from hivemall_tpu.runtime.tracing import TRACER, Tracer, step_span
 
 
 def _make_model(dims=256, n=120, seed=0):
@@ -211,16 +210,38 @@ def test_stage_breakdown_and_slowest():
     assert slowest[0]["stages_ms"]["work"] >= 5.0
 
 
-def test_jax_annotation_bridge():
-    """jax_annotations=True wraps each span extent in a
-    jax.profiler.TraceAnnotation — same span names in xprof timelines;
-    tracing semantics are unchanged."""
-    t = Tracer(seed=0, jax_annotations=True)
+def test_every_span_is_a_profiler_annotation(monkeypatch):
+    """The bridge has no switch: each span's extent is a
+    jax.profiler.TraceAnnotation of the same name, opened after the span
+    and closed before it, so a profile being taken holds the program's
+    spans on its own clock; a disabled tracer opens none."""
+    from hivemall_tpu.runtime import tracing
+
+    seen = []
+
+    class Mark:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            seen.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            seen.append(("close", self.name))
+
+    monkeypatch.setattr(tracing, "_ANNOTATION", Mark)
+    t = Tracer(seed=0)
     with t.span("annotated"):
         with t.span("inner"):
             pass
+    assert seen == [("open", "annotated"), ("open", "inner"),
+                    ("close", "inner"), ("close", "annotated")]
     (trace,) = t.traces()
     assert {s["name"] for s in trace["spans"]} == {"annotated", "inner"}
+    with Tracer(enabled=False).span("off"):
+        pass
+    assert len(seen) == 4
+    assert not hasattr(t, "jax_annotations")
 
 
 # -- serving-path wiring -----------------------------------------------------
@@ -428,8 +449,10 @@ def test_http_predict_root_span_end_to_end():
 
 def test_step_span_times_training_phases():
     """The per-step training timeline: step_span root, trainer dispatch as
-    train.compiled_step, host block building as train.data_prep,
-    sync_ready as train.sync — all one trace per step."""
+    train.compiled_step, host block building as train.data_prep, the
+    loop's wait as train.sync — all one trace per step."""
+    import jax
+
     from hivemall_tpu.models.classifier import AROW
     from hivemall_tpu.parallel import MixConfig, MixTrainer, make_mesh
 
@@ -444,7 +467,8 @@ def test_step_span_times_training_phases():
         with step_span("mix_dp", step=i):
             blocks = tr.shard_blocks(idx, val, lab)
             state, loss = tr.step(state, *blocks)
-            sync_ready(loss)
+            with TRACER.span("train.sync"):
+                jax.block_until_ready(loss)
     steps = [t for t in TRACER.traces() if t["root"] == "train.step"]
     assert len(steps) == 2
     for want_step, trace in enumerate(steps):
@@ -642,3 +666,217 @@ def test_slow_reserve_is_a_floor_not_a_partition():
         with plain.span(f"r{i}"):
             pass
     assert [tr["root"] for tr in plain.traces()] == ["r2", "r3", "r4"]
+
+
+# -- the training call's vocabulary ------------------------------------------
+# One train_* UDTF call is one `train.call` trace and its model_rows() one
+# `emit.model_rows` trace: names, parents, counts and counters as
+# docs/observability.md ("The training call's timeline") lists them.
+
+CALL_PARENTS = {
+    "train.call": None,
+    "train.stage": "train.call",
+    "train.parse": "train.stage",
+    "train.init_state": "train.call",
+    "train.epoch": "train.call",
+    "train.data_prep": "train.epoch",
+    "train.compiled_step": "train.epoch",
+    "train.sync": "train.epoch",
+}
+EMIT_PARENTS = {"emit.model_rows": None, "emit.d2h": "emit.model_rows",
+                "emit.select": "emit.model_rows"}
+CALL_COUNTERS = ("train.h2d_bytes", "train.parse_tokens", "train.jit_compiles",
+                 "emit.d2h_bytes", "emit.rows")
+
+
+def _rows(form, n=64, dims=256, seed=0):
+    rng = np.random.RandomState(seed)
+    idx = [rng.randint(0, dims, rng.randint(3, 8)) for _ in range(n)]
+    val = [rng.rand(len(r)).astype(np.float32) for r in idx]
+    labels = rng.choice([-1, 1], n)
+    if form == "text":
+        return [[f"{i}:{v:.4f}" for i, v in zip(r, x)]
+                for r, x in zip(idx, val)], labels, idx
+    return (idx, val), labels, idx
+
+
+def _counters():
+    from hivemall_tpu.runtime.metrics import REGISTRY
+
+    snap = REGISTRY.snapshot()
+    return {k: snap.get(k, 0.0) for k in CALL_COUNTERS}
+
+
+def _traced_call(entry, options, form, **kw):
+    """(train.call trace, emit.model_rows trace, emitted rows, counter
+    deltas, staged id rows) of one call and its model_rows()."""
+    from hivemall_tpu.sql.registry import get_function
+
+    rows, labels, idx = _rows(form, **kw)
+    before = _counters()
+    TRACER.clear()
+    model = get_function(entry)(rows, labels, options)
+    emitted = model.model_rows()
+    after = _counters()
+    call, emit = TRACER.traces()
+    assert (call["root"], emit["root"]) == ("train.call", "emit.model_rows")
+    return call, emit, emitted, \
+        {k: after[k] - before[k] for k in CALL_COUNTERS}, idx
+
+
+def _parents(trace):
+    names = {s["span_id"]: s["name"] for s in trace["spans"]}
+    return {(s["name"], names.get(s["parent_id"])) for s in trace["spans"]}
+
+
+def _sum(trace, name, key):
+    return sum(s["args"][key] for s in trace["spans"] if s["name"] == name)
+
+
+@pytest.mark.parametrize("form", ["text", "arrays"])
+@pytest.mark.parametrize("entry,options,epochs,feats_at", [
+    ("train_arow", "-dims 256 -mini_batch 16", 1, 0),
+    ("train_fm", "-c -factor 10 -dims 256 -mini_batch 16 -iters 2 -disable_cv",
+     2, 1),
+])
+def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
+                                           form):
+    call, emit, emitted, counted, idx = _traced_call(entry, options, form)
+    want = dict(CALL_PARENTS)
+    if form == "arrays":
+        want.pop("train.parse")   # no parser on pre-hashed rows
+    assert _parents(call) == set(want.items())
+    assert _parents(emit) == set(EMIT_PARENTS.items())
+    by_name = {}
+    for s in call["spans"] + emit["spans"]:
+        by_name.setdefault(s["name"], []).append(s)
+    steps = epochs * 64 // 16
+    for per_step in ("train.data_prep", "train.compiled_step"):
+        assert len(by_name[per_step]) == steps
+    assert [s["args"]["step"] for s in by_name["train.compiled_step"]] \
+        == list(range(steps))
+    assert len(by_name["train.epoch"]) == epochs
+    assert _sum(call, "train.epoch", "steps") == steps
+    # fit_linear waits once an epoch for every loss; train_fm every step
+    fm = entry == "train_fm"
+    assert len(by_name["train.sync"]) == (steps if fm else epochs)
+    assert _sum(call, "train.sync", "fetches") == steps
+    (root,) = by_name["train.call"]
+    assert root["args"] == {
+        "entry": "fm" if fm else "arow", "dims": 256, "rows": 64,
+        "mini_batch": 16, "epochs": epochs, "mode": "minibatch",
+        "table_dtype": "float32"}
+    nnz = sum(len(r) for r in idx)
+    (stage,) = by_name["train.stage"]
+    assert stage["args"] == {"form": form, "rows": 64, "nnz": nnz}
+    if form == "text":
+        (parse,) = by_name["train.parse"]
+        assert parse["args"]["tokens"] == nnz
+    assert by_name["train.init_state"][0]["args"]["state_bytes"] > 256 * 4
+    # h2d_bytes is the nbytes of what each step was handed: [16, 8] int32
+    # ids and float32 values, 16 labels, and FM's 16-row validation mask
+    block = 16 * 8 * 4 * 2 + 16 * 4 + (16 * 4 if fm else 0)
+    assert {s["args"]["h2d_bytes"] for s in by_name["train.data_prep"]} \
+        == {block}
+    assert _sum(call, "train.data_prep", "rows") == 64 * epochs
+    # the counters are the args' sums, made at the same place
+    assert counted["train.h2d_bytes"] == steps * block
+    assert counted["train.parse_tokens"] == (nnz if form == "text" else 0)
+    assert counted["train.jit_compiles"] == sum(
+        s["args"]["compiled"] for s in by_name["train.compiled_step"])
+    feats = emitted[feats_at]
+    assert len(feats) and set(feats) <= {i for r in idx for i in r}
+    (emit_root,) = by_name["emit.model_rows"]
+    assert emit_root["args"]["rows_out"] == len(feats)
+    assert by_name["emit.select"][0]["args"]["rows_out"] == len(feats)
+    assert counted["emit.rows"] == len(feats)
+    d2h = _sum(emit, "emit.d2h", "bytes")
+    assert emit_root["args"]["d2h_bytes"] == d2h == counted["emit.d2h_bytes"]
+    tables = {s["args"]["table"] for s in by_name["emit.d2h"]}
+    assert tables == ({"touched", "w", "v", "w0"} if fm
+                      else {"touched", "weights", "covars"})
+    assert d2h >= 256 * (1 + 4 + 4)
+
+
+def test_root_self_time_is_what_the_children_leave():
+    """Self time by the rule of benchmark/readers/_program_spans.py: a
+    span's duration less the union of its children's intervals. The
+    children of train.call and train.epoch lie inside them, one after the
+    other, so self time is also duration less the children's sum."""
+    from benchmark.readers import _program_spans as ps
+
+    call, emit, _, _, _ = _traced_call(
+        "train_arow", "-dims 256 -mini_batch 16", "text")
+    for trace, roots in ((call, ("train.call", "train.epoch")),
+                         (emit, ("emit.model_rows",))):
+        spans = trace["spans"]
+        for name in roots:
+            (root,) = ps.named(spans, name)
+            kids = [s for s in spans if s["parent_id"] == root["span_id"]]
+            assert kids
+            lo = root["start_us"]
+            for k in sorted(kids, key=lambda s: s["start_us"]):
+                assert lo <= k["start_us"]
+                lo = k["start_us"] + k["dur_us"]
+            assert lo <= root["start_us"] + root["dur_us"]
+            left = root["dur_us"] - sum(k["dur_us"] for k in kids)
+            assert ps.self_ms(spans, name) == pytest.approx(left / 1e3)
+            assert 0 <= left < root["dur_us"]
+
+
+def test_each_call_compiles_on_its_first_step_and_says_so():
+    """make_train_step returns a fresh jax.jit a call, so a second call of
+    the same shapes traces and lowers its step again: `compiled` on step 0
+    alone, with the `jit_recompile` instant recompile_guard also emits."""
+    for _ in range(2):
+        call, _, _, counted, _ = _traced_call(
+            "train_arow", "-dims 256 -mini_batch 16 -iters 2 -disable_cv",
+            "arrays")
+        steps = [s for s in call["spans"]
+                 if s["name"] == "train.compiled_step"]
+        assert [s["args"]["compiled"] for s in steps] == [True] + [False] * 7
+        assert [[e["name"] for e in s["events"]] for s in steps] \
+            == [["jit_recompile"]] + [[]] * 7
+        assert steps[0]["events"][0]["args"] == {"guard": "train.call",
+                                                 "compiles": 1}
+        assert counted["train.jit_compiles"] == 1
+
+
+# gather and scatter ops in each step's lowered text at the parent commit
+# (61d9a95, before any scope): a scope is metadata and adds no op
+PARENT_OP_COUNTS = {"arow_minibatch": (2, 6), "arow_scan": (4, 6),
+                    "fm_minibatch": (2, 12)}
+
+
+@pytest.mark.parametrize("family", sorted(PARENT_OP_COUNTS))
+def test_steps_carry_every_scope_and_no_new_op(family):
+    import re
+
+    import jax.numpy as jnp
+
+    from hivemall_tpu.core.engine import make_train_step
+    from hivemall_tpu.core.state import init_linear_state
+    from hivemall_tpu.models.classifier import AROW
+    from hivemall_tpu.models.fm import FMHyper, init_fm_state, make_fm_step
+    from hivemall_tpu.runtime import tracing
+
+    block = (jnp.zeros((16, 8), jnp.int32), jnp.ones((16, 8), jnp.float32),
+             jnp.ones((16,), jnp.float32))
+    if family == "fm_minibatch":
+        hyper = FMHyper(factors=10, classification=True)
+        lowered = make_fm_step(hyper, "minibatch").lower(
+            init_fm_state(512, hyper), *block, jnp.zeros((16,), jnp.float32))
+        want = set(tracing.FM_SCOPES)
+    else:
+        mode = family.split("_")[1]
+        lowered = make_train_step(AROW, {"r": 0.1}, mode=mode).lower(
+            init_linear_state(512, use_covariance=True), *block)
+        want = set(tracing.LINEAR_SCOPES)
+        if mode == "scan":   # per-row: nothing is stacked, nothing reduced
+            want -= {tracing.SCOPE_PACK_TABLES, tracing.SCOPE_REDUCE}
+    text = lowered.as_text(debug_info=True)
+    assert set(re.findall(r"hm\.[a-z_]+", text)) == want
+    assert not re.search(r"hm\.", lowered.as_text())   # metadata only
+    assert (len(re.findall("stablehlo.gather", text)),
+            len(re.findall("stablehlo.scatter", text))) \
+        == PARENT_OP_COUNTS[family]
