@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from ..ops.scatter import reduce_block_runs, write_runs
 from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_PACK_TABLES,
                                SCOPE_REDUCE, SCOPE_RULE, SCOPE_TOUCHED)
 from .state import LinearState
@@ -112,10 +113,11 @@ def _row_ctx(state_tables, idx, val, y, t, use_cov, globals_=None, packed=None):
     weights, covars, slots = state_tables
     if packed is not None:
         # w+cov interleaved as a [D,2] table: ONE pair-row gather costs the
-        # same as ONE scalar gather on v5e (diag micro2 gather_pair 13.0ms
-        # vs scalar gather 12.9ms per 512k ids), so this halves the gather
-        # side of every covariance learner. The pair fill is 0.0; cov's
-        # fill is 1.0 (fresh variance), restored on the pad lanes.
+        # same as ONE scalar gather on v5e (0.5 ms per 65536 ids at 2^22
+        # dims), which pays for stacking a SMALL table every block; at
+        # 2^28 dims the stack alone was 20 ms of a 52 ms step, so only the
+        # dense strategy packs (apply_strategy). The pair fill is 0.0;
+        # cov's fill is 1.0 (fresh variance), restored on the pad lanes.
         pairs = packed.at[idx].get(mode="fill", fill_value=0.0)
         w = pairs[..., 0]
         oob = (idx < 0) | (idx >= weights.shape[0])
@@ -132,6 +134,25 @@ def _row_ctx(state_tables, idx, val, y, t, use_cov, globals_=None, packed=None):
 
 DELTA_SLOT = "__delta_upd"  # per-feature update count since the last mix —
 # the TPU analog of DenseModel's deltaUpdates byte array (ref: DenseModel.java:52)
+
+
+# The -mini_batch step applies a block in one of two ways, chosen when the
+# step is traced, from shapes alone. "batch_local": reduce the block's
+# deltas in the block's own index space and write the touched entries in
+# place, whatever the table's length. "dense": sum them into zeroed [dims]
+# tables and pass over the whole table, with w and cov packed for one pair
+# gather: ten passes that cost less than the sort, the scans and the second
+# gather while the table is small. On a v5e with a [1024, 64] block the two
+# meet at 2^24 dims (3.65 against 3.80 ms a step), "dense" is ahead by 12-36%
+# at 2^22 and 75% at 2^20, "batch_local" by 2.2x at 2^26 and 5.5x at 2^28
+# (PERF.md section 6, PR 27).
+DENSE_APPLY_BELOW = 256  # table entries per block lane
+
+
+def apply_strategy(dims: int, lanes: int) -> str:
+    """Which way `minibatch_step` applies a block of `lanes` = B x K lanes
+    to tables of `dims` entries (a stripe's length inside shard_map)."""
+    return "dense" if dims < DENSE_APPLY_BELOW * lanes else "batch_local"
 
 
 def make_batch_update(rule: Rule, hyper: dict):
@@ -297,13 +318,14 @@ def make_train_fn(
         gl = state.globals
         if rule.pre_batch is not None:
             gl = rule.pre_batch(gl, labels)
+        dense = apply_strategy(state.weights.shape[0],
+                               indices.size) == "dense"
 
-        # pack w+cov once per block so every row's two scalar gathers become
-        # one pair-row gather (see _row_ctx; the [D,2] stack is one ~0.1ms
-        # full-table pass vs ~13ms saved per 512k-update block on v5e)
+        # a small table: pack w+cov once per block so every row's two
+        # scalar gathers become one pair-row gather (see _row_ctx)
         with jax.named_scope(SCOPE_PACK_TABLES):
             packed = (jnp.stack([state.weights, state.covars], axis=-1)
-                      if use_cov else None)
+                      if use_cov and dense else None)
 
         def per_row(idx, val, y, tf):
             with jax.named_scope(SCOPE_GATHER):
@@ -311,21 +333,79 @@ def make_train_fn(
                     (state.weights, state.covars, state.slots), idx, val, y,
                     tf, gl, packed)
             with jax.named_scope(SCOPE_RULE):
-                return rule.update(ctx, hyper), sidx
+                # the gathered batch-start values ride along: the block-local
+                # write is `old + sum/count`, with no second gather
+                old = {"w": ctx.w, "cov": ctx.cov, "slots": ctx.slots}
+                return rule.update(ctx, hyper), sidx, old
 
-        outs, sidx = jax.vmap(per_row)(indices, values, labels, ts)
+        outs, sidx, old = jax.vmap(per_row)(indices, values, labels, ts)
         with jax.named_scope(SCOPE_RULE):
             upd = outs.updated.astype(jnp.float32)  # [B]
             lane_upd = upd[:, None] * jnp.ones_like(values)  # [B, K]
 
         weights, covars, slots = state.weights, state.covars, state.slots
+        new_slots = dict(slots)
+        has_cov = use_cov and outs.dcov is not None
+        # Per-feature averaged application, exactly the reference's
+        # FloatAccumulator semantics (RegressionBaseUDTF.java:236-295).
+        # Accumulate in f32 even over bf16 tables, cast once at the table
+        # write (the SpaceEfficientDenseModel analog stores compact, never
+        # accumulates compact).
+        acc = jnp.promote_types(weights.dtype, jnp.float32)
+        if mini_batch_average and not dense:
+            # In the BLOCK's index space: the lanes are sorted by feature id
+            # with their deltas and their gathered old values as payload,
+            # each run of equal ids is summed in f32, and the run's lanes
+            # write `old + sum/count` back with one rounding to the table's
+            # storage type. Nothing but the in-place writes is as long as
+            # the table (PERF.md section 6, PR 27).
+            summed = {"count": lane_upd, "w": outs.dw, "slots": outs.dslots,
+                      "cov": outs.dcov if has_cov else None}
+            with jax.named_scope(SCOPE_REDUCE):
+                runs = reduce_block_runs(
+                    sidx.reshape(-1), weights.shape[0],
+                    jax.tree_util.tree_map(
+                        lambda v: v.reshape(-1).astype(acc), summed),
+                    jax.tree_util.tree_map(lambda v: v.reshape(-1), old))
+            with jax.named_scope(SCOPE_APPLY):
+                sums = runs.sums
+                old = jax.tree_util.tree_map(lambda v: v.astype(acc),
+                                             runs.carried)
+                count = sums["count"]
+                denom = jnp.maximum(count, 1.0)
+                w_new = old["w"] + sums["w"] / denom
+                # optimizer slots are summed, not averaged, as in every mode
+                slot_sums = dict(sums["slots"])
+                if track_deltas:
+                    slot_sums[DELTA_SLOT] = count
+                sl_new = {k: v + slot_sums[k] if k in slot_sums else v
+                          for k, v in old["slots"].items()}
+                if rule.derive_w is not None:
+                    # Dual-averaging weights are a pure function of the
+                    # *updated* accumulators; a feature no row fired on
+                    # keeps its value
+                    tf_end = (t0 + b).astype(jnp.float32)
+                    w_new = jnp.where(
+                        count > 0,
+                        rule.derive_w(sl_new, tf_end, hyper).astype(acc),
+                        w_new)
+                weights = write_runs(weights, runs, w_new)
+                if has_cov:
+                    covars = write_runs(
+                        covars, runs, old["cov"] + sums["cov"] / denom)
+                for k in slot_sums:
+                    new_slots[k] = write_runs(slots[k], runs, sl_new[k])
+            with jax.named_scope(SCOPE_TOUCHED):
+                touched = write_runs(state.touched, runs, count > 0, "max")
+            new_state = state.replace(
+                weights=weights, covars=covars, slots=new_slots,
+                touched=touched, step=t0 + b, globals=gl)
+            with jax.named_scope(SCOPE_RULE):
+                return new_state, jnp.sum(outs.loss)
+
         if mini_batch_average:
-            # Per-feature averaged application, exactly the reference's
-            # FloatAccumulator semantics (RegressionBaseUDTF.java:236-295).
-            # Accumulate in f32 even over bf16 tables, cast once at the
-            # table write (the SpaceEfficientDenseModel analog stores
-            # compact, never accumulates compact).
-            acc = jnp.promote_types(weights.dtype, jnp.float32)
+            # A table a few hundred times the block at most: ten passes over
+            # it cost less than the sort, the scans and the second gather.
             # scopes follow the statements' order: moving one would change
             # the traced program, and with it the compile cache's key
             with jax.named_scope(SCOPE_REDUCE):
@@ -339,7 +419,7 @@ def make_train_fn(
             with jax.named_scope(SCOPE_APPLY):
                 weights = (weights.astype(acc) + dw_sum / denom) \
                     .astype(weights.dtype)
-            if use_cov and outs.dcov is not None:
+            if has_cov:
                 with jax.named_scope(SCOPE_REDUCE):
                     dc_sum = jnp.zeros(covars.shape, acc).at[sidx].add(
                         outs.dcov.astype(acc), mode="drop")
@@ -350,10 +430,9 @@ def make_train_fn(
             with jax.named_scope(SCOPE_APPLY):
                 weights = weights.at[sidx].add(
                     outs.dw.astype(weights.dtype), mode="drop")
-                if use_cov and outs.dcov is not None:
+                if has_cov:
                     covars = covars.at[sidx].add(
                         outs.dcov.astype(covars.dtype), mode="drop")
-        new_slots = dict(slots)
         with jax.named_scope(SCOPE_APPLY):
             for k in rule.slot_names:
                 if k in outs.dslots:
@@ -372,9 +451,8 @@ def make_train_fn(
                     w_new.astype(weights.dtype), mode="drop")
         if mini_batch_average:
             # `counts` is exactly this block's per-feature lane_upd scatter,
-            # so touched and the MIX delta clock derive from it with cheap
-            # full-table elementwise ops instead of two more scalar
-            # scatters (~7ms each per 512k-update block on v5e).
+            # so touched and the MIX delta clock derive from it with
+            # full-table elementwise ops instead of two more scatters
             with jax.named_scope(SCOPE_TOUCHED):
                 touched = jnp.maximum(state.touched,
                                       (counts > 0).astype(jnp.int8))

@@ -27,7 +27,8 @@ import numpy as np
 
 from ..constants import DEFAULT_NUM_FEATURES
 from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
-from ..core.engine import Rule, make_predict, make_train_step
+from ..core.engine import (Rule, apply_strategy, make_predict,
+                           make_train_step)
 from ..core.state import LinearState, init_linear_state, model_rows
 from ..ops.convergence import ConversionState
 from ..runtime.metrics import REGISTRY, _jit_cache_size
@@ -457,6 +458,10 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
             else "xla"
         step = make_train_step(rule, hyper, mode=mode,
                                update_backend=backend)
+        if mode == "minibatch" and backend == "xla":
+            # which way the step applies a block: the same static test of
+            # shapes the step makes when it is traced
+            call.set(apply=apply_strategy(dims, block_size * width))
     # SpaceEfficientDenseModel analog: above 2^24 dims the reference switches
     # to half-float storage unless -disable_halffloat
     # (ref: LearnerBaseUDTF.java:172-175); TPU-native that is bf16.

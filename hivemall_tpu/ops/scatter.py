@@ -126,6 +126,96 @@ def dedup_scatter_set_uniform(table: jnp.ndarray, plan: DedupPlan,
 
 
 # --------------------------------------------------------------------------
+# Block runs: the -mini_batch step's reduction, in the block's own index
+# space. The flat lane ids are sorted with every per-lane column riding as
+# payload, a segmented scan sums each run of equal ids, and every lane of a
+# run ends up holding its feature's totals — nothing here is as long as the
+# table, nothing is gathered a second time, and no segment_sum/segment_min
+# (which lower back to scatters) is involved. The writes that follow take
+# the sorted ids as they are: all lanes of a feature write the same value,
+# so `indices_are_sorted` is true and the duplicates cannot disagree. (On
+# the v5e a sorted scatter into a 2^28 table costs half an unsorted one, and
+# a false `indices_are_sorted` writes wrong entries: PERF.md section 6,
+# PR 27.)
+# --------------------------------------------------------------------------
+
+
+class BlockRuns(NamedTuple):
+    """One block's lanes sorted by feature id and reduced per feature."""
+
+    ids: jnp.ndarray  # [N] int32 — the lanes' feature ids, ascending; every
+    # lane outside the table carries `dims` and sits at the tail
+    sums: object  # `summed`'s tree of [N]: a run's total, on all its lanes
+    carried: object  # `carried`'s tree of [N], in sorted lane order
+
+
+def _sort_columns(ids: jnp.ndarray, cols):
+    """Each [N] column of `cols` (one dtype) reordered as `ids` sorts.
+
+    The columns ride one at a time, each through the same two-operand sort
+    inside a loop: the TPU compiler takes about 10 s per operand of a
+    65536-lane sort (90 s for all of an AROW step's columns at once), and
+    a loop's body is compiled once. On a v5e the step's six sorts take
+    0.2 ms together (PERF.md section 6, PR 27)."""
+    return jax.lax.map(
+        lambda col: jax.lax.sort((ids, col), num_keys=1, is_stable=False)[1],
+        jnp.stack(cols))
+
+
+def reduce_block_runs(idx_flat: jnp.ndarray, dims: int, summed,
+                      carried) -> BlockRuns:
+    """`idx_flat` [N] int lane ids (pad lanes == dims); the [N] columns of
+    the tree `summed` are added up over each run of equal ids in a fixed
+    order; the [N] columns of the tree `carried` (equal on all lanes of one
+    id) just ride the sort. Every column comes back in `summed`'s dtype.
+
+    Two Hillis-Steele passes over the sorted lanes, s = 1, 2, 4, ...: lane i
+    adds lane i-s while both hold the same id (sorted ids make "same id"
+    the whole segment test), which leaves a run's total on its last lane;
+    then lane i copies lane i+s under the same test, which hands that total
+    back to the run's other lanes, bit for bit. Each pass is a loop with a
+    rolled shift, not 16 unrolled steps: `fit_linear` traces its step anew
+    every call, and 500 traced operations cost it a quarter of a second."""
+    n = idx_flat.shape[0]
+    sum_cols, sum_tree = jax.tree_util.tree_flatten(summed)
+    ride_cols, ride_tree = jax.tree_util.tree_flatten(carried)
+    acc = sum_cols[0].dtype
+    # `.at[]` counts negative ids from the table's end; so do we. Whatever
+    # is outside the table after that is a dropped lane, at the sort's tail
+    ids = jnp.where(idx_flat < 0, idx_flat + dims, idx_flat)
+    ids = jnp.where((ids >= 0) & (ids < dims), ids, dims)
+    cols = _sort_columns(ids, [c.astype(acc) for c in sum_cols + ride_cols])
+    ids = jax.lax.sort(ids, is_stable=False)
+    lane = jnp.arange(n)
+    passes = max(n - 1, 0).bit_length()
+
+    def add_left(p, sums):
+        s = 1 << p
+        same = (jnp.roll(ids, s) == ids) & (lane >= s)
+        return sums + jnp.where(same, jnp.roll(sums, s, axis=1), 0)
+
+    def copy_right(p, sums):
+        s = 1 << p
+        same = (jnp.roll(ids, -s) == ids) & (lane < n - s)
+        return jnp.where(same, jnp.roll(sums, -s, axis=1), sums)
+
+    sums = jax.lax.fori_loop(0, passes, add_left, cols[:len(sum_cols)])
+    sums = jax.lax.fori_loop(0, passes, copy_right, sums)
+    return BlockRuns(ids=ids, sums=sum_tree.unflatten(list(sums)),
+                     carried=ride_tree.unflatten(list(cols[len(sum_cols):])))
+
+
+def write_runs(table: jnp.ndarray, runs: BlockRuns, values: jnp.ndarray,
+               op: str = "set") -> jnp.ndarray:
+    """`table[id] = values` (or `max(table[id], values)`) at the block's
+    ids, in place. `values` [N] must be equal on all lanes of one id, as
+    anything computed from `runs.sums` and `runs.carried` is."""
+    at = table.at[runs.ids]
+    return getattr(at, op)(values.astype(table.dtype), mode="drop",
+                           indices_are_sorted=True)
+
+
+# --------------------------------------------------------------------------
 # Staged plans: the sort moved to staging time, the scatter shrunk to the
 # unique slots.
 #

@@ -96,11 +96,11 @@ SPAN_EMIT = "emit.model_rows"
 SPAN_EMIT_D2H = "emit.d2h"
 SPAN_EMIT_SELECT = "emit.select"
 
-SCOPE_PACK_TABLES = "hm.pack_tables"  # tables stacked for one paired gather
+SCOPE_PACK_TABLES = "hm.pack_tables"  # small tables stacked for a paired gather
 SCOPE_GATHER = "hm.gather"            # table reads at the block's ids
 SCOPE_RULE = "hm.rule"                # the learner's closed-form update
-SCOPE_REDUCE = "hm.reduce"            # scatters into zeroed delta tables
-SCOPE_APPLY = "hm.apply"              # table writes: add/divide/cast, scatter
+SCOPE_REDUCE = "hm.reduce"            # per-feature sums of a block's deltas
+SCOPE_APPLY = "hm.apply"              # table writes: in place, or add/divide/cast
 SCOPE_TOUCHED = "hm.touched"          # the emitted-rows flags
 SCOPE_LOSS = "hm.loss"                # FM's loss and its gradient scalar
 LINEAR_SCOPES = (SCOPE_PACK_TABLES, SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE,

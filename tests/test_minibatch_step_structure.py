@@ -1,0 +1,182 @@
+"""The `-mini_batch` step has no O(dims) pass where the table is long against
+the block (`engine.apply_strategy` says `batch_local`): nothing it computes
+is as long as the table but the in-place writes of the touched entries.
+
+(a) on the CPU, by walking the step's jaxpr; (b) for a described v5e, by
+compiling the benchmark's AROW step (2^28 dims, bfloat16 tables, a
+[1024, 64] block, donated state) and reading the compiler's own account.
+Nothing runs in (b): on-chip-measurement guide, section 2. The topology is
+described inside a module-scoped fixture, never at import.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from hivemall_tpu.core.engine import (DELTA_SLOT, apply_strategy,
+                                      make_train_fn)
+from hivemall_tpu.core.state import init_linear_state
+from hivemall_tpu.models import classifier as C
+from hivemall_tpu.models import regression as R
+
+DIMS = 1 << 20
+
+# name -> (rule, hyper, table dtype, track_deltas)
+STEPS = {
+    "arow_f32": (C.AROW, {"r": 0.1}, jnp.float32, False),
+    "arow_bf16": (C.AROW, {"r": 0.1}, jnp.bfloat16, False),
+    "arow_bf16_track_deltas": (C.AROW, {"r": 0.1}, jnp.bfloat16, True),
+    "perceptron": (C.PERCEPTRON, {}, jnp.float32, False),
+    "adagrad_regr": (R.ADAGRAD_REGR, {"eta": 1.0, "eps": 1.0, "scale": 100.0},
+                     jnp.float32, False),
+    "adagrad_rda": (C.ADAGRAD_RDA,
+                    {"eta": 0.1, "lambda": 1e-6, "scale": 100.0},
+                    jnp.float32, False),
+    "pa1a_regr_globals": (R.PA1A_REGR, {"c": 1.0, "epsilon": 0.01},
+                          jnp.float32, False),
+}
+
+
+def _state_shape(rule, dims, dtype, track=False):
+    slots = tuple(rule.slot_names) + ((DELTA_SLOT,) if track else ())
+    return jax.eval_shape(lambda: init_linear_state(
+        dims, use_covariance=rule.use_covariance, slot_names=slots,
+        global_names=rule.global_names, dtype=dtype))
+
+
+def _block(rows, width):
+    return (jax.ShapeDtypeStruct((rows, width), jnp.int32),
+            jax.ShapeDtypeStruct((rows, width), jnp.float32),
+            jax.ShapeDtypeStruct((rows,), jnp.float32))
+
+
+def _table_long_equations(jaxpr, dims):
+    """(primitive, shape) of every equation, nested ones too, that yields an
+    array with a `dims`-long axis."""
+    found = []
+    for eqn in jaxpr.eqns:
+        inner = [p for k, v in eqn.params.items()
+                 if k != "update_jaxpr"   # a scatter's combiner is no body
+                 for p in (v if isinstance(v, (list, tuple)) else (v,))
+                 if hasattr(p, "eqns") or hasattr(p, "jaxpr")]
+        for sub in inner:
+            found += _table_long_equations(getattr(sub, "jaxpr", sub), dims)
+        if inner:   # a call's or a loop's own results are its body's
+            continue
+        for out in eqn.outvars:
+            if dims in getattr(out.aval, "shape", ()):
+                found.append((eqn.primitive.name, out.aval.shape))
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(STEPS))
+def test_only_the_table_writes_are_table_long(name):
+    rule, hyper, dtype, track = STEPS[name]
+    step = make_train_fn(rule, hyper, mode="minibatch", track_deltas=track)
+    state = _state_shape(rule, DIMS, dtype, track)
+    jaxpr = jax.make_jaxpr(step)(state, *_block(32, 8))
+    long = _table_long_equations(jaxpr.jaxpr, DIMS)
+    # one write each: weights, touched (a max), covariances, every slot
+    tables = 2 + bool(rule.use_covariance) + len(rule.slot_names) + track
+    assert sorted(p for p, _ in long) \
+        == ["scatter"] * (tables - 1) + ["scatter-max"], long
+
+
+def test_the_walk_sees_a_dense_pass():
+    """The parent's formula, as a control: the walk reports it."""
+    def dense(w, idx, dw):
+        total = jnp.zeros(w.shape, jnp.float32).at[idx].add(dw, mode="drop")
+        return (w.astype(jnp.float32) + total).astype(w.dtype)
+
+    jaxpr = jax.make_jaxpr(dense)(
+        jax.ShapeDtypeStruct((DIMS,), jnp.bfloat16),
+        jax.ShapeDtypeStruct((64,), jnp.int32),
+        jax.ShapeDtypeStruct((64,), jnp.float32))
+    names = [p for p, _ in _table_long_equations(jaxpr.jaxpr, DIMS)]
+    assert "scatter-add" in names and "convert_element_type" in names
+
+
+def test_block_local_step_carries_its_scopes_and_packs_nothing():
+    from hivemall_tpu.runtime import tracing
+
+    state = _state_shape(C.AROW, DIMS, jnp.bfloat16)
+    lowered = jax.jit(make_train_fn(C.AROW, {"r": 0.1}, mode="minibatch")) \
+        .lower(state, *_block(32, 8))
+    scopes = set(re.findall(r"hm\.[a-z_]+", lowered.as_text(debug_info=True)))
+    assert scopes == set(tracing.LINEAR_SCOPES) - {tracing.SCOPE_PACK_TABLES}
+    assert not re.search(r"hm\.", lowered.as_text())   # metadata only
+
+
+@pytest.mark.parametrize("options,want", [
+    ("-dims 131072 -mini_batch 4", "batch_local"),
+    ("-dims 4096 -mini_batch 64", "dense"),
+    ("-dims 4096", None),                       # the scan has no such stage
+])
+def test_train_call_span_says_which_strategy_ran(options, want):
+    import numpy as np
+
+    from hivemall_tpu.models.classifier import train_arow
+    from hivemall_tpu.runtime.tracing import SPAN_CALL, TRACER
+
+    rng = np.random.default_rng(0)
+    idx = rng.integers(0, 4096, size=(128, 5))
+    model = train_arow((idx, np.ones(idx.shape, np.float32)),
+                       np.sign(rng.normal(size=128)), options)
+    call = next(sp for sp in TRACER.traces()[-1]["spans"]
+                if sp["name"] == SPAN_CALL)
+    assert call["args"].get("apply") == want
+    if want:
+        block = int(options.split()[-1]) * model.block_width
+        assert apply_strategy(model.dims, block) == want
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    dims, rows, width = 1 << 28, 1024, 64
+    assert apply_strategy(dims, rows * width) == "batch_local"
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    step = make_train_fn(C.AROW, {"r": 0.1}, mode="minibatch")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(
+            on(_state_shape(C.AROW, dims, jnp.bfloat16)),
+            *on(_block(rows, width))).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+    # an instruction is `%name = type[shape]{layout} opcode(operands), ...`;
+    # those that only name or pass on a table do no pass over it
+    passes_on = {"parameter", "tuple", "get-tuple-element", "bitcast"}
+    long = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(",
+                     line)
+        if m and f"[{dims}]" in m.group(1) and m.group(2) not in passes_on:
+            long.append((m.group(2), line.strip()))
+    assert long, "the step writes three tables"
+    for opcode, line in long:
+        assert opcode in ("scatter", "fusion"), line[:200]
+        if opcode == "fusion":   # the fusion that holds a scatter, alone
+            assert re.search(r'op_name="[^"]*/scatter(-max)?"', line), line[:200]

@@ -765,7 +765,9 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     assert root["args"] == {
         "entry": "fm" if fm else "arow", "dims": 256, "rows": 64,
         "mini_batch": 16, "epochs": epochs, "mode": "minibatch",
-        "table_dtype": "float32"}
+        "table_dtype": "float32",
+        # the linear step's way with a block: a 256-entry table is small
+        **({} if fm else {"apply": "dense"})}
     nnz = sum(len(r) for r in idx)
     (stage,) = by_name["train.stage"]
     assert stage["args"] == {"form": form, "rows": 64, "nnz": nnz}
