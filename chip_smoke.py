@@ -546,14 +546,43 @@ def stage_mesh(dims: int, width: int, mini_batch: int, n_devices: int = 4,
               f"mesh: replicated init peaked at {peak0} bytes on device 0 "
               f"for a {replica}-byte replica — every replica was staged "
               f"there")
-    for _ in range(3):
-        state, loss = mix.step(state, *mix.shard_blocks(
-            *blocks(n_devices * k)))
+    per_step = n_devices * k
+    mi, mv, ml = blocks(3 * per_step)
+    for s in range(3):
+        at = slice(s * per_step, (s + 1) * per_step)
+        state, loss = mix.step(state, *mix.shard_blocks(mi[at], mv[at], ml[at]))
         check(np.isfinite(float(loss)), "mesh: mix loss not finite")
     final = mix.final_state(state)
     finite_model("mix", final)
-    report["mix"] = {"steps": 3, "blocks_per_step": n_devices * k,
+    report["mix"] = {"steps": 3, "blocks_per_step": per_step,
                      "loss": float(loss)}
+    # the same rows through the normal path: `train_arow -mix`, each
+    # replica's blocks laid end to end as its contiguous share, a mix after
+    # every block as the hand-built trainer's default cadence has it
+    from hivemall_tpu.runtime.tracing import TRACER
+    from hivemall_tpu.sql.registry import get_function
+
+    order = [s * per_step + r * k + j for r in range(n_devices)
+             for s in range(3) for j in range(k)]
+    entry = get_function("train_arow")(
+        (list(mi[order].reshape(-1, width)), list(mv[order].reshape(-1, width))),
+        ml[order].reshape(-1),
+        f"-dims {dims} -mini_batch {mini_batch} -mix smoke -mix_threshold 1")
+    call = next(sp["args"] for sp in TRACER.traces()[-1]["spans"]
+                if sp["name"] == "train.call")
+    check(call.get("replicas") == n_devices,
+          f"mesh: train_arow -mix trained {call.get('replicas')} replica(s), "
+          f"the hand-built trainer {n_devices}")
+    check(int(entry.state.step) == 3 * per_step * mini_batch,
+          f"mesh: train_arow -mix counted {int(entry.state.step)} rows")
+    for what in ("weights", "covars", "touched"):
+        a = np.asarray(getattr(entry.state, what), np.float32)
+        b = np.asarray(getattr(final, what), np.float32)
+        check(np.allclose(a, b, rtol=1e-4, atol=1e-5),
+              f"mesh: train_arow -mix {what} differ from MixTrainer's by "
+              f"{float(np.max(np.abs(a - b))):.3g}")
+    report["mix"]["entry_point_matches"] = True
+    del entry
     mixed_model = TrainedLinearModel(
         state=jax.device_put(final), rule=AROW, dims=dims,
         block_width=width)

@@ -26,15 +26,16 @@ import jax
 import numpy as np
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
+from ..core.batch import (iter_blocks, pack_rows, pad_to_bucket,
+                          shuffle_rows)
 from ..core.engine import (Rule, apply_strategy, make_predict,
                            make_train_step)
 from ..core.state import LinearState, init_linear_state, model_rows
 from ..ops.convergence import ConversionState
 from ..runtime.metrics import REGISTRY, _jit_cache_size
 from ..runtime.tracing import (SPAN_CALL, SPAN_COMPILED_STEP, SPAN_DATA_PREP,
-                               SPAN_EPOCH, SPAN_INIT_STATE, SPAN_STAGE,
-                               SPAN_SYNC, TRACER)
+                               SPAN_EPOCH, SPAN_INIT_STATE, SPAN_MIX,
+                               SPAN_SHARD_ROWS, SPAN_STAGE, SPAN_SYNC, TRACER)
 from ..utils.feature import parse_features_batch
 from ..utils.options import CommandLine, Options
 
@@ -48,13 +49,23 @@ def base_options() -> Options:
     o.add("disable_halffloat", None, False, "(accepted for parity; TPU uses fp32/bf16)")
     o.add("loadmodel", None, True,
           "Warm-start from a saved model-rows table (ref: LearnerBaseUDTF.java:215-333)")
-    # MIX client options accepted for signature parity
-    # (ref: LearnerBaseUDTF.java:92-103). In the TPU build, model mixing is a
-    # collective inside the train step — use parallel.MixTrainer on a mesh
-    # (and runtime.init_cluster for multi-host) instead of a server fleet.
-    o.add("mix", "mix_servers", True, "(parity) MIX server list; see parallel.MixTrainer")
+    # MIX (ref: LearnerBaseUDTF.java:92-103). Upstream, mappers exchange
+    # per-feature partial results with a server fleet while they train; here
+    # the mappers are this host's devices and the exchange is a collective
+    # (parallel/mix.py::MixedReplicas; docs/distributed_training.md)
+    o.add("mix", "mix_servers", True,
+          "Train one replica on each local device, each on its contiguous "
+          "share of the call's rows, and mix them into one model while they "
+          "train (argmin-KLD for covariance learners, delta-weighted average "
+          "else). The server list itself is accepted for parity only; with "
+          "one device this is the plain call. Needs -mini_batch B > 1")
     o.add("mix_session", "mix_session_name", True, "(parity) MIX session name")
-    o.add("mix_threshold", None, True, "(parity) MIX push threshold", type=int)
+    o.add("mix_threshold", None, True,
+          "With -mix: the replicas mix after every this many blocks (a block "
+          "is one averaged update of each feature it carries) and once more "
+          "when the rows end [default: 3, range 1-127]. Every feature with a "
+          "pending update on any replica is mixed; upstream's per-feature "
+          "push gate is not reproduced", type=int)
     o.add("mix_cancel", "enable_mix_canceling", False, "(parity) no-op under sync SPMD")
     o.add("ssl", None, False, "(parity) TLS handled by the deployment, not the library")
     o.add("mini_batch", "mini_batch_size", True,
@@ -109,14 +120,26 @@ def _stage_rows(features: FeatureRows, dims: int) -> ArrayRows:
     return parse_features_batch(features, dims)
 
 
-def stage_training_rows(features: FeatureRows, dims: int):
+def stage_training_rows(features: FeatureRows, dims: int, replicas: int = 1):
     """(idx_rows, val_rows, block width) of a training call's rows, under a
-    `train.stage` span (text rows open `train.parse` inside it)."""
+    `train.stage` span (text rows open `train.parse` inside it). With
+    `replicas` > 1 (`-mix`) the rows are dealt inside it, under
+    `train.shard_rows`: idx_rows and val_rows are then one list a replica,
+    its contiguous share (parallel/mix.py::deal_rows)."""
     with TRACER.span(SPAN_STAGE, args={
             "form": "arrays" if _is_array_rows(features) else "text"}) as sp:
         idx_rows, val_rows = _stage_rows(features, dims)
         lens = [len(r) for r in idx_rows]
         sp.set(rows=len(lens), nnz=sum(lens))
+        if replicas > 1:
+            from ..parallel.mix import deal_rows
+
+            with TRACER.span(SPAN_SHARD_ROWS,
+                             args={"replicas": replicas}) as deal:
+                shares = deal_rows(len(lens), replicas)
+                idx_rows = [idx_rows[lo:hi] for lo, hi in shares]
+                val_rows = [val_rows[lo:hi] for lo, hi in shares]
+                deal.set(rows=len(lens), rows_each=shares[0][1] - shares[0][0])
     return idx_rows, val_rows, pad_to_bucket(max(lens, default=1))
 
 
@@ -150,15 +173,56 @@ def prepared_blocks(idx_rows, val_rows, labels, dims, block_size, width,
         yield arrays
 
 
+def prepared_replica_blocks(idx_shares, val_shares, label_shares, dims,
+                            block_size, width):
+    """`prepared_blocks` for `-mix`: step j's arrays hold block j of every
+    replica's share end to end, `(indices [R*B, K], values, labels [R*B],
+    real rows [R])`. A share's last block is padded with empty rows up to the
+    block's shape, so that every call dispatches one shape; a replica whose
+    share has run out sends a block of padding."""
+    n_blocks = max(1, max(-(-len(s) // block_size) for s in idx_shares))
+    h2d_counter = REGISTRY.counter("train", "h2d_bytes")
+    for j in range(n_blocks):
+        with TRACER.span(SPAN_DATA_PREP) as sp:
+            lo, hi = j * block_size, (j + 1) * block_size
+            blocks = [pack_rows(i[lo:hi], v[lo:hi], y[lo:hi], dims,
+                                width=width, batch_size=block_size)
+                      for i, v, y in zip(idx_shares, val_shares, label_shares)]
+            real = np.asarray([len(i[lo:hi]) for i in idx_shares], np.int32)
+            arrays = (np.concatenate([b.indices for b in blocks]),
+                      np.concatenate([b.values for b in blocks]),
+                      np.concatenate([b.labels for b in blocks]), real)
+            h2d = sum(a.nbytes for a in arrays)
+            sp.set(rows=int(real.sum()), width=width, h2d_bytes=h2d)
+        h2d_counter.increment(h2d)
+        yield arrays
+
+
+def table_dtype(dims: int, cl: CommandLine):
+    """The tables' storage. SpaceEfficientDenseModel analog: above 2^24 dims
+    the reference switches to half-float storage unless -disable_halffloat
+    (ref: LearnerBaseUDTF.java:172-175); TPU-native that is bf16."""
+    import jax.numpy as jnp
+
+    half = dims > (1 << 24) and not cl.has("disable_halffloat")
+    return jnp.bfloat16 if half else jnp.float32
+
+
 def dispatch_step(step, step_no: int, *args):
     """One call of the jitted `step` under a `train.compiled_step` span.
     `compiled` is true where the jit's cache grew across the call: the span
     then holds the trace, the lowering and the compile (or the persistent
     cache's read), and says so by a `jit_recompile` instant."""
-    with TRACER.span(SPAN_COMPILED_STEP, args={"step": step_no}) as sp:
-        before = _jit_cache_size(step)
-        out = step(*args)
-        grew = _jit_cache_size(step) - before
+    return dispatch_spanned(SPAN_COMPILED_STEP, {"step": step_no}, step, *args)
+
+
+def dispatch_spanned(span: str, span_args: dict, program, *args):
+    """`dispatch_step` under any span of the vocabulary (`-mix` dispatches
+    its mix rounds under `train.mix`)."""
+    with TRACER.span(span, args=span_args) as sp:
+        before = _jit_cache_size(program)
+        out = program(*args)
+        grew = _jit_cache_size(program) - before
         sp.set(compiled=grew > 0)
         if grew > 0:
             sp.event("jit_recompile", guard=SPAN_CALL, compiles=grew)
@@ -377,6 +441,20 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
         feats0, w0, c0 = load_model_rows(cl.get("loadmodel"))
         initial_weights, initial_covars = dense_from_rows(dims, feats0, w0, c0)
 
+    # -mix: one replica a local device; with one device, the plain call
+    replicas = 1
+    if cl.has("mix"):
+        from ..parallel.mix import mix_devices
+
+        replicas = len(mix_devices())
+    if replicas > 1:
+        reason = _mix_unsupported_reason(rule, cl, mini_batch)
+        if reason:
+            raise ValueError(f"-mix on {replicas} devices {reason}")
+        return _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
+                                 mini_batch, iters, replicas, initial_weights,
+                                 initial_covars)
+
     idx_rows, val_rows, width = stage_training_rows(features, dims)
     n = len(idx_rows)
     if n == 0:
@@ -421,10 +499,8 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
     if mode == "batch" and cl.has("native_apply"):
         from ..core.native_batch import native_batch_unsupported_reason
 
-        f32_tables = not (dims > (1 << 24)
-                          and not cl.has("disable_halffloat"))
         reason = native_batch_unsupported_reason(
-            rule, table_dtype_is_f32=f32_tables)
+            rule, table_dtype_is_f32=np.dtype(table_dtype(dims, cl)) == np.float32)
         if reason is None:
             return _fit_native_batch(rule, hyper, cl, dims, idx_rows,
                                      val_rows, labels, width, block_size,
@@ -462,21 +538,13 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
             # which way the step applies a block: the same static test of
             # shapes the step makes when it is traced
             call.set(apply=apply_strategy(dims, block_size * width))
-    # SpaceEfficientDenseModel analog: above 2^24 dims the reference switches
-    # to half-float storage unless -disable_halffloat
-    # (ref: LearnerBaseUDTF.java:172-175); TPU-native that is bf16.
-    import jax.numpy as jnp
-
-    dtype = jnp.float32
-    if dims > (1 << 24) and not cl.has("disable_halffloat"):
-        dtype = jnp.bfloat16
     state = init_state_spanned(
         init_linear_state,
         dims,
         use_covariance=rule.use_covariance,
         slot_names=rule.slot_names,
         global_names=rule.global_names,
-        dtype=dtype,
+        dtype=table_dtype(dims, cl),
         initial_weights=initial_weights,
         initial_covars=initial_covars,
     )
@@ -531,6 +599,110 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
             if iters > 1 and conv.is_converged(n):
                 break
     return TrainedLinearModel(state=state, rule=rule, dims=dims, block_width=width)
+
+
+def _mix_unsupported_reason(rule: Rule, cl: CommandLine,
+                            mini_batch: int) -> Optional[str]:
+    """Why this call cannot train mixed replicas, or None. Stated, not
+    silent: each is a path nobody has needed mixed yet."""
+    backends = [f"-{o}" for o in ("batch", "native_scan", "native_apply",
+                                  "pallas", "mxu_scatter") if cl.has(o)]
+    if backends:
+        return (f"does not compose with {'/'.join(backends)}: the replicas "
+                "run the XLA -mini_batch step; drop one of the two")
+    if mini_batch <= 1:
+        return ("needs -mini_batch B > 1: the replicas mix between blocks, "
+                "and the exact per-row scan (-mini_batch 1) has none; add "
+                "-mini_batch 1024 or drop -mix")
+    if rule.global_names or rule.derive_w is not None:
+        return (f"is not supported for {rule.name}: its running label "
+                "statistics or derived weights count the rows that pad a "
+                "replica's last block; train without -mix, or drive "
+                "parallel.MixTrainer by hand")
+    n = cl.get_int("mix_threshold", 3)
+    if not 1 <= n <= 127:
+        return f"needs -mix_threshold in 1..127 (blocks between mixes): {n}"
+    return None
+
+
+def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
+                      mini_batch, iters, replicas, initial_weights,
+                      initial_covars) -> TrainedLinearModel:
+    """`_fit_linear` with `-mix` on `replicas` devices: each trains its
+    contiguous share of the rows in blocks of `-mini_batch`, all mix after
+    every `-mix_threshold` blocks and once more when the rows end, and one
+    model comes back (parallel/mix.py::MixedReplicas)."""
+    from ..parallel.mix import MixedReplicas, deal_rows, mix_devices
+
+    mix_every = cl.get_int("mix_threshold", 3)
+    idx_shares, val_shares, width = stage_training_rows(features, dims, replicas)
+    n = sum(len(s) for s in idx_shares)
+    if n == 0:
+        raise ValueError("no training rows")
+    label_shares = [labels[lo:hi] for lo, hi in deal_rows(n, replicas)]
+    trainer = MixedReplicas(rule, hyper, dims, table_dtype(dims, cl),
+                            mix_devices())
+    call.set(dims=dims, rows=n, mini_batch=mini_batch, mode="minibatch",
+             apply=apply_strategy(dims, mini_batch * width), replicas=replicas,
+             mix_every=mix_every, reduction=trainer.reduction)
+    state = init_state_spanned(trainer.init, initial_weights, initial_covars)
+    call.set(table_dtype=str(state.weights.dtype))
+
+    conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
+    iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
+    row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
+    step_no = round_no = 0
+    for it in range(max(1, iters)):
+        with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
+            if cl.has("shuffle") and it > 0:
+                # a row stays on its mapper: each share is shuffled alone
+                dealt = [shuffle_rows(i, v, y, cl.get_int("seed", 31) + it)
+                         for i, v, y in zip(idx_shares, val_shares, label_shares)]
+                idx_shares, val_shares, label_shares = map(list, zip(*dealt))
+            # losses and due counts stay on the device through the epoch and
+            # come back in its ONE device_get, as in _fit_linear
+            losses, dues = [], []
+
+            def mix_round(state, trailing):
+                state, due = dispatch_spanned(
+                    SPAN_MIX, {"round": round_no + len(dues),
+                               "trailing": trailing}, trainer.mix, state)
+                dues.append(due)
+                return state
+
+            pending = 0
+            for block in prepared_replica_blocks(
+                    idx_shares, val_shares, label_shares, dims, mini_batch,
+                    width):
+                state, loss = dispatch_step(trainer.step, step_no, state, *block)
+                step_no += 1
+                losses.append(loss)
+                row_counter.increment(int(block[3].sum()))
+                pending = (pending + 1) % mix_every
+                if not pending:
+                    state = mix_round(state, trailing=False)
+            if pending:
+                # the rows have ended short of a full group
+                state = mix_round(state, trailing=True)
+            iter_counter.increment()
+            with TRACER.span(SPAN_SYNC,
+                             args={"fetches": len(losses) + len(dues)}):
+                losses, dues = jax.device_get((losses, dues))
+            round_no += len(dues)
+            mixed = {"mix_rounds": len(dues),
+                     "mix_exchanged_entries": len(dues) * dims,
+                     "mix_due_entries": int(np.sum(dues, dtype=np.int64))}
+            for name, count in mixed.items():
+                REGISTRY.counter("train", name).increment(count)
+            epoch.set(steps=len(losses), **mixed)
+            call.set(epochs=it + 1)
+            conv.incr_loss(float(np.sum(losses)))
+            if iters > 1 and conv.is_converged(n):
+                break
+    # every epoch ended in a mix: weights and covariances are equal on every
+    # replica, and one of them is the model
+    return TrainedLinearModel(state=trainer.collapse(state), rule=rule,
+                              dims=dims, block_width=width)
 
 
 def binary_label_map(labels: np.ndarray) -> np.ndarray:

@@ -45,27 +45,41 @@ from ..core.engine import DELTA_SLOT, Rule, make_train_fn
 from ..core.state import LinearState, init_linear_state
 from .mesh import WORKER_AXIS, make_mesh
 from ..runtime.jax_compat import shard_map
-from ..runtime.tracing import (SPAN_COMPILED_STEP, SPAN_DATA_PREP, SPAN_SYNC,
-                               TRACER)
+from ..runtime.tracing import (SCOPE_MIX, SCOPE_MIX_ALLREDUCE, SCOPE_MIX_APPLY,
+                               SPAN_COMPILED_STEP, SPAN_DATA_PREP, SPAN_SYNC,
+                               TRACER, TRAINER_MIX)
+
+
+def _f32(x):
+    """Tables may be stored compact (bfloat16 above 2^24 dims); a mix sums in
+    float32 and rounds once at the write, as the step does."""
+    return x.astype(jnp.promote_types(x.dtype, jnp.float32))
 
 
 def mix_average(weights, delta_upd, axis_name: str = WORKER_AXIS):
     """Delta-weighted arithmetic mean across the mesh axis
     (ref: PartialAverage.java getWeight = scaledSumWeights/totalUpdates)."""
-    total = jax.lax.psum(delta_upd, axis_name)
-    wsum = jax.lax.psum(weights * delta_upd, axis_name)
-    return jnp.where(total > 0.0, wsum / jnp.maximum(total, 1.0), weights), total
+    with jax.named_scope(SCOPE_MIX_ALLREDUCE):
+        total = jax.lax.psum(delta_upd, axis_name)
+        wsum = jax.lax.psum(_f32(weights) * delta_upd, axis_name)
+    with jax.named_scope(SCOPE_MIX_APPLY):
+        mixed = (wsum / jnp.maximum(total, 1.0)).astype(weights.dtype)
+        return jnp.where(total > 0.0, mixed, weights), total
 
 
 def mix_argmin_kld(weights, covars, delta_upd, axis_name: str = WORKER_AXIS):
     """Precision-weighted (inverse-variance) mean across the mesh axis
     (ref: PartialArgminKLD.java:43-63, ensemble/ArgminKLDistanceUDAF.java:28-90)."""
-    total = jax.lax.psum(delta_upd, axis_name)
-    inv = 1.0 / covars
-    sum_inv = jax.lax.psum(inv, axis_name)
-    sum_wdiv = jax.lax.psum(weights * inv, axis_name)
-    mixed_w = jnp.where(total > 0.0, sum_wdiv / sum_inv, weights)
-    mixed_cov = jnp.where(total > 0.0, 1.0 / sum_inv, covars)
+    with jax.named_scope(SCOPE_MIX_ALLREDUCE):
+        total = jax.lax.psum(delta_upd, axis_name)
+        inv = 1.0 / _f32(covars)
+        sum_inv = jax.lax.psum(inv, axis_name)
+        sum_wdiv = jax.lax.psum(_f32(weights) * inv, axis_name)
+    with jax.named_scope(SCOPE_MIX_APPLY):
+        mixed_w = jnp.where(total > 0.0,
+                            (sum_wdiv / sum_inv).astype(weights.dtype), weights)
+        mixed_cov = jnp.where(total > 0.0,
+                              (1.0 / sum_inv).astype(covars.dtype), covars)
     return mixed_w, mixed_cov, total
 
 
@@ -162,23 +176,42 @@ def split_replica_blocks(n_replicas: int, *arrays):
     return tuple(a.reshape((n_replicas, k) + a.shape[1:]) for a in arrays)
 
 
-def make_linear_mix(reduction: str, axis: str):
-    """The collective mix applied to a LinearState replica: delta-weighted
-    average or argminKLD over `axis`, then reset the pending-delta counter.
-    Shared by the data-parallel MixTrainer and the replica axis of the 2-D
-    (replicas x feature stripes) trainer."""
-
-    def mix(st: LinearState) -> LinearState:
-        delta = st.slots[DELTA_SLOT]
+def mix_linear_replica(st: LinearState, reduction: str, axis: str):
+    """One mix round of a LinearState replica inside shard_map: the
+    delta-weighted average or argminKLD over `axis` of every feature with a
+    pending update on any replica (the rest keep their local value: upstream's
+    per-feature push gate, MixClient.java:117-142, is not reproduced), then
+    the pending counts reset. Returns (state, entries that were due)."""
+    delta = st.slots[DELTA_SLOT]
+    with jax.named_scope(SCOPE_MIX):
         if reduction == "argmin_kld":
-            w, cov, _ = mix_argmin_kld(st.weights, st.covars, delta, axis)
+            w, cov, total = mix_argmin_kld(st.weights, st.covars, delta, axis)
             st = st.replace(weights=w, covars=cov)
         else:
-            w, _ = mix_average(st.weights, delta, axis)
+            w, total = mix_average(st.weights, delta, axis)
             st = st.replace(weights=w)
-        return st.replace(slots={**st.slots, DELTA_SLOT: jnp.zeros_like(delta)})
+        due = jnp.sum(total > 0.0, dtype=jnp.int32)
+        st = st.replace(slots={**st.slots, DELTA_SLOT: jnp.zeros_like(delta)})
+    return st, due
+
+
+def make_linear_mix(reduction: str, axis: str):
+    """The collective mix applied to a LinearState replica (see
+    mix_linear_replica). Shared by the data-parallel MixTrainer and the
+    replica axis of the 2-D (replicas x feature stripes) trainer."""
+
+    def mix(st: LinearState) -> LinearState:
+        return mix_linear_replica(st, reduction, axis)[0]
 
     return mix
+
+
+def resolve_reduction(reduction: str, rule: Rule) -> str:
+    """`auto`: argminKLD for covariance learners, the delta-weighted average
+    else (the reference's event selection)."""
+    if reduction == "auto":
+        return "argmin_kld" if rule.use_covariance else "average"
+    return reduction
 
 
 def _welford_sub(nc, mc, m2c, n0, mu0, m20):
@@ -338,16 +371,15 @@ class MixTrainer:
     """
 
     def __init__(self, rule: Rule, hyper: dict, dims: int, mesh: Optional[Mesh] = None,
-                 config: MixConfig = MixConfig(), mode: str = "minibatch"):
+                 config: MixConfig = MixConfig(), mode: str = "minibatch",
+                 dtype=jnp.float32):
         self.rule = rule
         self.hyper = hyper
         self.dims = dims
+        self.dtype = dtype  # the tables' storage (fit_linear: bf16 above 2^24)
         self.mesh = mesh if mesh is not None else make_mesh()
         self.config = config
-        reduction = config.reduction
-        if reduction == "auto":
-            reduction = "argmin_kld" if rule.use_covariance else "average"
-        self.reduction = reduction
+        self.reduction = resolve_reduction(config.reduction, rule)
         self.n_dev = self.mesh.devices.size
         self._resume_base = None  # set by init(from_state=...) on warm restart
         axis = config.axis_name
@@ -391,6 +423,7 @@ class MixTrainer:
             use_covariance=self.rule.use_covariance,
             slot_names=tuple(self.rule.slot_names) + (DELTA_SLOT,),
             global_names=self.rule.global_names,
+            dtype=self.dtype,
         )
 
     def init(self, from_state: Optional[LinearState] = None) -> LinearState:
@@ -440,13 +473,13 @@ class MixTrainer:
         under a ``train.compiled_step`` span: inside a driver's
         ``tracing.step_span`` it becomes the per-step timeline's
         compiled-step stage (runtime/tracing.py)."""
-        with TRACER.span(SPAN_COMPILED_STEP, args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_COMPILED_STEP, args={"trainer": TRAINER_MIX}):
             return self._step(state, indices, values, labels)
 
     def shard_blocks(self, indices, values, labels):
         """Host helper: split [n_dev * k, B, ...] host blocks into the
         [n_dev, k, B, ...] layout."""
-        with TRACER.span(SPAN_DATA_PREP, args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_DATA_PREP, args={"trainer": TRAINER_MIX}):
             return split_replica_blocks(self.n_dev, indices, values, labels)
 
     def collapse_host(self, host: LinearState) -> LinearState:
@@ -468,6 +501,161 @@ class MixTrainer:
     def final_state(self, state: LinearState) -> LinearState:
         """Collapse the device axis after the trailing mix into one model a
         warm restart can resume from — see collapse_host."""
-        with TRACER.span(SPAN_SYNC, args={"trainer": "mix_dp"}):
+        with TRACER.span(SPAN_SYNC, args={"trainer": TRAINER_MIX}):
             host = jax.device_get(state)
         return self.collapse_host(host)
+
+
+def mix_devices():
+    """The devices `-mix` trains one replica on each: this process's own."""
+    return jax.local_devices()
+
+
+def deal_rows(n_rows: int, n_replicas: int):
+    """[(lo, hi)] of each replica's share of a call's rows: the mappers'
+    splits laid end to end, `ceil(n / R)` rows each, the last one shorter."""
+    each = -(-n_rows // n_replicas)
+    return [(min(r * each, n_rows), min((r + 1) * each, n_rows))
+            for r in range(n_replicas)]
+
+
+def merge_slots_on_device(slots: dict, touched, kinds: dict, axis: str):
+    """merge_slot_arrays inside shard_map: each rule slot summed ("sum") or
+    averaged ("mean") over the replicas that touched the entry; the pending
+    delta counter is dropped by the caller."""
+    if not slots:
+        return {}
+    mask = touched.astype(jnp.float32)
+    n_touch = jnp.maximum(jax.lax.psum(mask, axis), 1.0)
+    merged = {}
+    for name, arr in slots.items():
+        total = jax.lax.psum(arr * mask, axis)
+        merged[name] = total if kinds.get(name, "mean") == "sum" \
+            else total / n_touch
+    return merged
+
+
+class MixedReplicas:
+    """The `-mix` path of `fit_linear`: one model replica a device, the
+    replicated local step and the mix as two programs whose shapes do not
+    depend on how many rows a call brings, and a collapse that leaves ONE
+    model on the first device (the tables of replica 0, which the trailing
+    mix made equal on all; `touched` their union; the step counter and the
+    rule's slots merged), so that `model_rows()` copies one model.
+
+    Every state leaf is one global array with the replicas end to end
+    (`[R * dims]` tables, `[R]` scalars), sharded over the mesh axis: inside
+    shard_map a device sees its own `[dims]` replica, and a device's shard
+    IS a single-device array of the model's shape, with no copy.
+    """
+
+    def __init__(self, rule: Rule, hyper: dict, dims: int, dtype, devices):
+        self.rule, self.dims, self.dtype = rule, dims, dtype
+        self.axis = axis = WORKER_AXIS
+        self.mesh = make_mesh(devices=list(devices), axis_name=axis)
+        self.n_dev = self.mesh.devices.size
+        self.reduction = resolve_reduction("auto", rule)
+        local_fn = make_train_fn(rule, hyper, mode="minibatch",
+                                 track_deltas=True)
+        pad_loss = _pad_row_loss(rule, hyper)
+        kinds = dict(rule.slot_merge)
+
+        one = jax.eval_shape(self._init_one)          # a replica's leaves
+        merged_one = jax.eval_shape(lambda: self._init_one(False))
+
+        def replica_step(state, indices, values, labels, n_real):
+            st = _replica_in(state, one)
+            t0 = st.step
+            st, loss = local_fn(st, indices, values, labels)
+            # rows that pad a share's last block up to the block's shape
+            # write nothing (all their lanes are out of range): take them
+            # back out of the row counter and of the loss
+            real = n_real[0]
+            st = st.replace(step=t0 + real)
+            loss = loss - pad_loss * (indices.shape[0] - real)
+            # each replica's own loss: the host sums them, and the step
+            # holds no collective (the replicas meet only in the mix)
+            return _replica_out(st), loss.reshape((1,))
+
+        def mix_round(state):
+            st, due = mix_linear_replica(_replica_in(state, one),
+                                         self.reduction, axis)
+            return _replica_out(st), due
+
+        def collapse_replicas(state):
+            st = _replica_in(state, one)
+            slots = {k: v for k, v in st.slots.items() if k != DELTA_SLOT}
+            st = st.replace(
+                slots=merge_slots_on_device(slots, st.touched, kinds, axis),
+                touched=jax.lax.pmax(st.touched, axis),
+                step=jax.lax.psum(st.step, axis))
+            return _replica_out(st)
+
+        spec = P(axis)
+        state_spec = jax.tree.map(lambda _: spec, one)
+        merged_spec = jax.tree.map(lambda _: spec, merged_one)
+        smap = partial(shard_map, mesh=self.mesh)
+        self._merged_one, self._state_spec = merged_one, state_spec
+        self.step = jax.jit(
+            smap(replica_step, in_specs=(state_spec, spec, spec, spec, spec),
+                 out_specs=(state_spec, spec)), donate_argnums=(0,))
+        self.mix = jax.jit(
+            smap(mix_round, in_specs=(state_spec,),
+                 out_specs=(state_spec, P())), donate_argnums=(0,))
+        self._collapse = jax.jit(
+            smap(collapse_replicas, in_specs=(state_spec,),
+                 out_specs=merged_spec), donate_argnums=(0,))
+
+    def _init_one(self, delta_slot: bool = True, initial_weights=None,
+                  initial_covars=None) -> LinearState:
+        slots = tuple(self.rule.slot_names)
+        return init_linear_state(
+            self.dims, use_covariance=self.rule.use_covariance,
+            slot_names=slots + ((DELTA_SLOT,) if delta_slot else ()),
+            global_names=self.rule.global_names, dtype=self.dtype,
+            initial_weights=initial_weights, initial_covars=initial_covars)
+
+    def init(self, initial_weights=None, initial_covars=None) -> LinearState:
+        """Every replica's fresh state, made on its own device (a warm start
+        seeds each with the same weights and covariances)."""
+        if initial_weights is None and initial_covars is not None:
+            raise ValueError("initial covariances need initial weights")
+        warm = [np.asarray(a) for a in (initial_weights, initial_covars)
+                if a is not None]
+
+        def init_replicas(*warm):
+            return _replica_out(self._init_one(True, *warm))
+
+        return jax.jit(shard_map(
+            init_replicas, mesh=self.mesh, in_specs=(P(),) * len(warm),
+            out_specs=self._state_spec))(*warm)
+
+    def collapse(self, state: LinearState) -> LinearState:
+        """One model, on the first device: see the class's description."""
+        merged = self._collapse(state)
+        return jax.tree.map(
+            lambda x, a: x.addressable_shards[0].data.reshape(a.shape),
+            merged, self._merged_one)
+
+
+def _replica_in(state, one):
+    """A device's view of the replicated state as one model shaped like
+    `one`: scalars arrive as `[1]` slices of their `[R]` arrays."""
+    return jax.tree.map(lambda x, a: x.reshape(a.shape), state, one)
+
+
+def _replica_out(state):
+    return jax.tree.map(lambda x: x.reshape((1,)) if x.ndim == 0 else x, state)
+
+
+def _pad_row_loss(rule: Rule, hyper: dict) -> float:
+    """The rule's loss on a row whose every lane is padding (score 0, label
+    0): what one such row adds to a block's loss sum."""
+    from ..core.engine import _row_ctx
+
+    slots = {k: jnp.zeros((1,)) for k in tuple(rule.slot_names) + (DELTA_SLOT,)}
+    cov = jnp.ones((1,)) if rule.use_covariance else None
+    ctx = _row_ctx((jnp.zeros((1,)), cov, slots), jnp.asarray([1]),
+                   jnp.zeros((1,)), jnp.float32(0.0), jnp.float32(1.0),
+                   rule.use_covariance, {})
+    return float(rule.update(ctx, hyper).loss)

@@ -92,6 +92,9 @@ SPAN_EPOCH = "train.epoch"
 SPAN_DATA_PREP = "train.data_prep"
 SPAN_COMPILED_STEP = "train.compiled_step"
 SPAN_SYNC = "train.sync"
+# `-mix` (parallel/mix.py::MixedReplicas): one replica a local device
+SPAN_SHARD_ROWS = "train.shard_rows"  # under train.stage: rows dealt to replicas
+SPAN_MIX = "train.mix"                # under train.epoch: one mix round's dispatch
 SPAN_EMIT = "emit.model_rows"
 SPAN_EMIT_D2H = "emit.d2h"
 SPAN_EMIT_SELECT = "emit.select"
@@ -103,6 +106,11 @@ SCOPE_REDUCE = "hm.reduce"            # per-feature sums of a block's deltas
 SCOPE_APPLY = "hm.apply"              # table writes: in place, or add/divide/cast
 SCOPE_TOUCHED = "hm.touched"          # the emitted-rows flags
 SCOPE_LOSS = "hm.loss"                # FM's loss and its gradient scalar
+SCOPE_MIX = "hm.mix"                  # the replicas' reduction, and inside it:
+SCOPE_MIX_ALLREDUCE = "allreduce"     # hm.mix/allreduce: the psums and their operands
+SCOPE_MIX_APPLY = "apply"             # hm.mix/apply: the mixed values' write
+# the `trainer` argument of the spans the hand-driven trainers emit
+TRAINER_MIX = "mix_dp"
 LINEAR_SCOPES = (SCOPE_PACK_TABLES, SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE,
                  SCOPE_APPLY, SCOPE_TOUCHED)
 FM_SCOPES = LINEAR_SCOPES + (SCOPE_LOSS,)

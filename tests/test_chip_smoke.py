@@ -71,12 +71,19 @@ def test_kernels_stage_checks_hold():
         AROW, chip_smoke.FULL["dims"])
 
 
-def test_mesh_stage_checks_hold():
-    """Four of the conftest's eight virtual devices."""
+def test_mesh_stage_checks_hold(monkeypatch):
+    """Four of the conftest's eight virtual devices (`-mix` trains a replica
+    on every local device: the test hands it the same four)."""
+    import jax
+
+    from hivemall_tpu.parallel import mix
+
+    monkeypatch.setattr(mix, "mix_devices", lambda: jax.local_devices()[:4])
     rep = chip_smoke.stage_mesh(DIMS, WIDTH, MINI_BATCH, n_devices=4,
                                 max_batch=16, max_width=16,
                                 catalog_items=512)
     assert rep["devices"] == 4
+    assert rep["mix"]["entry_point_matches"]
     assert rep["sharded"]["matches_single_device"]
     assert rep["sharded2d"]["mesh"] == [2, 2]
     assert rep["serving"]["placement"]["model_shards"] == 4

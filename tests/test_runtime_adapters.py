@@ -202,10 +202,26 @@ class TestAdapters:
 
         df = lr_datagen_frame("-n_examples 120 -n_features 5 -n_dims 32 -cl")
         assert len(df) == 120 and set(df["label"]) <= {0.0, 1.0}
-        # -mix injection must parse cleanly through every trainer's options
+        # -mix injection parses through every trainer's options and, since
+        # PR 28, means what it says: one replica a local device (the tests'
+        # eight), mixed into one model
         hf = hivemall_ops(df).set_mix_servs("host1,host2")
-        model = hf.train_perceptron("features", "label", "-dims 32")
+        model = hf.train_perceptron("features", "label",
+                                    "-dims 32 -mini_batch 8")
         assert model.predict(df["features"].tolist()).shape == (120,)
+        assert int(model.state.step) == 120
+
+    def test_injected_mix_with_the_exact_scan_is_refused_in_words(self):
+        import pytest
+
+        from hivemall_tpu.adapters import hivemall_ops
+        from hivemall_tpu.adapters.dataframe import lr_datagen_frame
+
+        df = lr_datagen_frame("-n_examples 40 -n_features 5 -n_dims 32 -cl")
+        hf = hivemall_ops(df).set_mix_servs("host1,host2")
+        with pytest.raises(ValueError, match="-mix on 8 devices needs "
+                                             "-mini_batch B > 1"):
+            hf.train_perceptron("features", "label", "-dims 32")
 
 
 class TestTokenizeJaExtended:
