@@ -21,8 +21,8 @@ import numpy as np
 from flax import struct
 
 from ..runtime.metrics import REGISTRY
-from ..runtime.tracing import (SPAN_EMIT, SPAN_EMIT_D2H, SPAN_EMIT_SELECT,
-                               TRACER)
+from ..runtime.tracing import SPAN_EMIT, TRACER
+from .emission import select_rows
 
 
 @struct.dataclass
@@ -83,36 +83,19 @@ def init_linear_state(
     )
 
 
-def table_to_host(table, name: str) -> np.ndarray:
-    """One whole table copied to the host under an `emit.d2h` span; the
-    bytes go to the `emit.d2h_bytes` counter and to the open
-    `emit.model_rows` span's sum."""
-    with TRACER.span(SPAN_EMIT_D2H, args={"table": name}) as sp:
-        out = np.asarray(table)
-        sp.set(bytes=out.nbytes)
-    REGISTRY.counter("emit", "d2h_bytes").increment(out.nbytes)
-    return out
-
-
 def model_rows(state: LinearState, filter_zero: bool = False):
     """Dump the model as (feature, weight[, covar]) arrays over touched
     entries — the close() model emission (ref: BinaryOnlineClassifierUDTF.java:254-291).
+    Device tables are selected on the device and only the emitted entries
+    cross to the host (core/emission.py).
     """
     with TRACER.span(SPAN_EMIT, args={
             "table_dtype": str(state.weights.dtype)}) as emit:
-        touched = table_to_host(state.touched, "touched")
-        weights = table_to_host(state.weights, "weights")
-        covars = table_to_host(state.covars, "covars") \
-            if state.covars is not None else None
-        tables = [t for t in (touched, weights, covars) if t is not None]
-        with TRACER.span(SPAN_EMIT_SELECT) as select:
-            keep = touched != 0
-            if filter_zero:
-                keep &= weights != 0.0
-            feats = np.nonzero(keep)[0].astype(np.int64)
-            out = (feats,) + tuple(t[feats] for t in tables[1:])
-            select.set(rows_out=len(feats))
-        emit.set(rows_out=len(feats),
-                 d2h_bytes=sum(t.nbytes for t in tables))
+        tables = [("weights", state.weights)]
+        if state.covars is not None:
+            tables.append(("covars", state.covars))
+        feats, values, stats = select_rows(
+            state.touched, tables, state.weights if filter_zero else None)
+        emit.set(rows_out=len(feats), **stats)
     REGISTRY.counter("emit", "rows").increment(len(feats))
-    return out
+    return (feats, *values)

@@ -33,16 +33,15 @@ from flax import struct
 
 from ..constants import DEFAULT_NUM_FEATURES
 from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
-from ..core.state import table_to_host
+from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.scatter import scatter_rows_flat
 from ..ops.eta import EtaEstimator, get_eta
 from ..runtime.metrics import REGISTRY
 from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
                                SCOPE_PACK_TABLES, SCOPE_REDUCE, SCOPE_RULE,
-                               SCOPE_TOUCHED, SPAN_CALL, SPAN_EMIT,
-                               SPAN_EMIT_SELECT, SPAN_EPOCH, SPAN_SYNC,
-                               TRACER)
+                               SCOPE_TOUCHED, SPAN_CALL, SPAN_EMIT, SPAN_EPOCH,
+                               SPAN_SYNC, TRACER)
 from ..utils.options import Options
 from .base import (FeatureRows, _stage_rows, base_options, dispatch_step,
                    init_state_spanned, prepared_blocks, stage_training_rows)
@@ -553,18 +552,13 @@ class TrainedFMModel:
         st = self.state
         with TRACER.span(SPAN_EMIT, args={
                 "table_dtype": str(st.v.dtype)}) as emit:
-            tables = [table_to_host(getattr(st, name), name)
-                      for name in ("touched", "w", "v", "w0")]
-            touched, w, v, w0 = tables
-            with TRACER.span(SPAN_EMIT_SELECT) as select:
-                feats = np.nonzero(touched != 0)[0].astype(np.int64)
-                # slice physical lane padding (padded_factors) back to the
-                # logical k
-                out = (float(w0), feats, w[feats],
-                       v[feats][:, :self.hyper.factors])
-                select.set(rows_out=len(feats))
-            emit.set(rows_out=len(feats),
-                     d2h_bytes=sum(t.nbytes for t in tables))
+            feats, (w, v), stats = select_rows(
+                st.touched, [("w", st.w), ("v", st.v)])
+            w0 = table_to_host(st.w0, "w0", stats)
+            # slice physical lane padding (padded_factors) back to the
+            # logical k
+            out = (float(w0), feats, w, v[:, :self.hyper.factors])
+            emit.set(rows_out=len(feats), **stats)
         REGISTRY.counter("emit", "rows").increment(len(feats))
         return out
 

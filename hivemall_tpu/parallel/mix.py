@@ -541,7 +541,8 @@ class MixedReplicas:
     depend on how many rows a call brings, and a collapse that leaves ONE
     model on the first device (the tables of replica 0, which the trailing
     mix made equal on all; `touched` their union; the step counter and the
-    rule's slots merged), so that `model_rows()` copies one model.
+    rule's slots merged), so that `model_rows()` reads one model, on that
+    device.
 
     Every state leaf is one global array with the replicas end to end
     (`[R * dims]` tables, `[R]` scalars), sharded over the mesh axis: inside

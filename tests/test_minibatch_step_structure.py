@@ -147,24 +147,30 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
+def _compile_uncached(lowered):
+    """Compiled for the described chip with the persistent cache off: an
+    entry written for a device that is not attached cannot be read back."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
     dims, rows, width = 1 << 28, 1024, 64
     assert apply_strategy(dims, rows * width) == "batch_local"
     on = lambda tree: jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         tree)
     step = make_train_fn(C.AROW, {"r": 0.1}, mode="minibatch")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    try:
-        compiled = jax.jit(step, donate_argnums=(0,)).lower(
-            on(_state_shape(C.AROW, dims, jnp.bfloat16)),
-            *on(_block(rows, width))).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
+    compiled = _compile_uncached(jax.jit(step, donate_argnums=(0,)).lower(
+        on(_state_shape(C.AROW, dims, jnp.bfloat16)),
+        *on(_block(rows, width))))
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
     # an instruction is `%name = type[shape]{layout} opcode(operands), ...`;
     # those that only name or pass on a table do no pass over it
@@ -180,3 +186,45 @@ def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
         assert opcode in ("scatter", "fusion"), line[:200]
         if opcode == "fusion":   # the fusion that holds a scatter, alone
             assert re.search(r'op_name="[^"]*/scatter(-max)?"', line), line[:200]
+
+
+# close()'s emission at the cell's sizes (core/emission.py): the mask's
+# packing and the chunked gather hold no table-long scratch either. Here,
+# not in a file of their own: one worker describes the topology.
+EMISSION_PROGRAMS = {
+    # name -> (program, argument shapes, most temporary bytes)
+    "mask_2^28": ("_pack_mask", lambda S, c: (S((1 << 28,), jnp.int8), None),
+                  1 << 20),
+    # filter_zero writes one flag an entry first (a pred[2^28])
+    "mask_nonzero_2^28": ("_pack_mask", lambda S, c: (
+        S((1 << 28,), jnp.int8), S((1 << 28,), jnp.bfloat16)),
+        (256 << 20) + (1 << 20)),
+    "mask_odd_dims": ("_pack_mask", lambda S, c: (
+        S((100_000_003,), jnp.int8), None), 1 << 20),
+    "gather_arow_bf16": ("_gather_rows", lambda S, c: (
+        (S((1 << 28,), jnp.bfloat16), S((1 << 28,), jnp.bfloat16)),
+        S((c,), jnp.int32)), 1 << 20),
+    "gather_fm_rows": ("_gather_rows", lambda S, c: (
+        (S((1 << 23,), jnp.float32), S((1 << 23, 16), jnp.float32)),
+        S((c,), jnp.int32)), 1 << 20),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EMISSION_PROGRAMS))
+def test_compiled_emission_program_has_no_table_long_scratch(one_chip, name):
+    from hivemall_tpu.core import emission
+
+    program, shapes, most = EMISSION_PROGRAMS[name]
+    S = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                  sharding=one_chip)
+    args = shapes(S, emission.GATHER_CHUNK)
+    m = _compile_uncached(
+        getattr(emission, program).lower(*args)).memory_analysis()
+    assert m.temp_size_in_bytes <= most
+    dims = jax.tree_util.tree_leaves(args)[0].shape[0]
+    if program == "_pack_mask":   # one bit an entry comes out (+ a tile)
+        assert 0 <= m.output_size_in_bytes - 4 * -(-dims // 32) < 4096
+    else:                         # a chunk's rows, compact
+        assert m.output_size_in_bytes <= 1.07 * sum(
+            emission.GATHER_CHUNK * t.dtype.itemsize
+            * (t.shape[1] if t.ndim > 1 else 1) for t in args[0])

@@ -794,10 +794,16 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     assert counted["emit.rows"] == len(feats)
     d2h = _sum(emit, "emit.d2h", "bytes")
     assert emit_root["args"]["d2h_bytes"] == d2h == counted["emit.d2h_bytes"]
+    # the mask comes over, then the emitted entries' values a chunk at a
+    # time (core/emission.py): never a whole table
     tables = {s["args"]["table"] for s in by_name["emit.d2h"]}
-    assert tables == ({"touched", "w", "v", "w0"} if fm
-                      else {"touched", "weights", "covars"})
-    assert d2h >= 256 * (1 + 4 + 4)
+    assert tables == ({"mask", "w", "v", "w0"} if fm
+                      else {"mask", "weights", "covars"})
+    assert emit_root["args"]["select"] == "device"
+    assert emit_root["args"]["chunks"] == 1
+    assert emit_root["args"]["h2d_bytes"] == 256 * 4
+    row = 4 + 16 * 4 if fm else 4 + 4
+    assert d2h == 256 // 8 + 256 * row + (4 if fm else 0)
 
 
 def test_root_self_time_is_what_the_children_leave():
