@@ -12,9 +12,9 @@ the reference's default capacity), in ONE process:
            /predict over HTTP at several batch sizes and row widths, scores
            equal to model.predict; retrain, hot-swap to v2; /metrics shows
            zero post-warmup recompiles
-  kernels  the optional update backends: -pallas refused in words at dims
+  kernels  the optional update backend: -pallas refused in words at dims
            that cannot be VMEM-resident and compiled + matched where they
-           can; one -mxu_scatter step against the XLA step on the same block
+           can
   mesh     (>= 4 devices) MixTrainer / ShardedTrainer / Sharded2DTrainer at
            the same dims, ModelSharded(4) serving == single-device serving,
            sharded /topk == single-device /topk, per-device bytes spread.
@@ -377,15 +377,10 @@ def stage_serve(meter: CompileMeter, model, data: dict, dims: int,
 # --- stage: kernels ---------------------------------------------------------
 
 
-def stage_kernels(dims: int, pallas_dims: int, width: int, mini_batch: int,
+def stage_kernels(dims: int, pallas_dims: int, width: int,
                   seed: int = 11, pallas_interpret: bool = False) -> dict:
-    """The optional update backends compile and match, or are refused in
-    words before reaching the compiler. Neither is timed for a claim."""
-    import jax
-    import jax.numpy as jnp
-
-    from hivemall_tpu.core.engine import make_train_fn
-    from hivemall_tpu.core.state import init_linear_state
+    """The optional update backend compiles and matches, or is refused in
+    words before reaching the compiler. It is not timed for a claim."""
     from hivemall_tpu.kernels.linear_scan import vmem_resident_reason
     from hivemall_tpu.models.classifier import AROW
     from hivemall_tpu.sql import get_function
@@ -421,40 +416,6 @@ def stage_kernels(dims: int, pallas_dims: int, width: int, mini_batch: int,
         else:
             raise SmokeFailure(f"kernels: -pallas at {dims} dims was not "
                                f"refused")
-
-    # -mxu_scatter: one minibatch step against the XLA step, same block
-    from hivemall_tpu.core.batch import iter_blocks
-    from hivemall_tpu.utils.feature import parse_features_batch
-
-    rows, labels = make_rows(rng, mini_batch, dims, width,
-                             planted_weights(rng, dims))
-    idx_rows, val_rows = parse_features_batch(rows, dims)
-    block = next(iter(iter_blocks(idx_rows, val_rows,
-                                  labels.astype(np.float32), dims,
-                                  mini_batch, width)))
-    args = (jnp.asarray(block.indices), jnp.asarray(block.values),
-            jnp.asarray(block.labels))
-    states = {}
-    for backend in ("xla", "mxu"):
-        step = jax.jit(make_train_fn(AROW, {"r": 0.1}, mode="minibatch",
-                                     update_backend=backend))
-        st, loss = step(init_linear_state(dims, use_covariance=True), *args)
-        check(np.isfinite(float(loss)), f"kernels: {backend} loss not "
-                                        f"finite")
-        states[backend] = st
-    deltas = {}
-    for what in ("weights", "covars"):
-        a = np.asarray(getattr(states["mxu"], what))
-        b = np.asarray(getattr(states["xla"], what))
-        deltas[what] = float(np.max(np.abs(a - b)))
-        check(np.allclose(a, b, rtol=1e-4, atol=1e-5),
-              f"kernels: mxu {what} differ from the xla step by "
-              f"{deltas[what]:.3g}")
-    check(np.array_equal(np.asarray(states["mxu"].touched),
-                         np.asarray(states["xla"].touched)),
-          "kernels: mxu touched mask differs from the xla step")
-    report["mxu_scatter"] = {"dims": dims, "rows": mini_batch,
-                             "matches_xla": True, "max_abs_delta": deltas}
     return report
 
 
@@ -787,7 +748,7 @@ def main() -> int:
     gc.collect()
 
     rep, timing = meter.timed(stage_kernels, s["dims"], s["pallas_dims"],
-                              s["width"], s["mini_batch"])
+                              s["width"])
     stages["kernels"] = {**timing, **rep}
     say(f"kernels ok {timing}")
 

@@ -12,7 +12,7 @@ Two advisory shapes of the same waste, scoped to the hot-path modules
   reduced-precision (bf16/f16/int8) and whose target is f32/f64 — a
   full widened copy of a quantized array. The dequant-free serving
   contract wants the cast fused per-tile/per-window inside the consuming
-  loop (the ``ops/mxu_scatter.py`` window pattern), not a whole-table
+  loop (widen the gathered window only), not a whole-table
   materialization that erases the bandwidth the quantization bought.
 
 Both are warnings: widening can be the right call (an f32 accumulator),
@@ -68,8 +68,8 @@ def check_program(program: ProgramModel, scanned: Set[str]
                         f"{site.target_dt.name}) of a "
                         f"{site.receiver_dt.name} array copies the whole "
                         f"table widened — cast per-tile/per-window inside "
-                        f"the consuming loop (the ops/mxu_scatter.py "
-                        f"window pattern) to keep the bandwidth the "
-                        f"reduced dtype bought",
+                        f"the consuming loop (widen the gathered window "
+                        f"only) to keep the bandwidth the reduced dtype "
+                        f"bought",
                         model.snippet(site.node.lineno)))
     return findings

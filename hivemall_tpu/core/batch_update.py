@@ -25,9 +25,8 @@ backend, with the sort moved OUT of the step entirely:
   single compact unique+sorted scatter — U unique lanes instead of B*K
   update lanes, no full-[D] temporaries anywhere;
 - B is the AdaBatch dial (PAPERS.md): batch size trades throughput
-  against update staleness, and bench.py sweeps it with a pinned
-  holdout-logloss parity tolerance so the chosen default is measured,
-  not assumed.
+  against update staleness; the last sweep's numbers (holdout logloss
+  per B, CPU) are in docs/execution_backends.md.
 
 Semantics are the engine's minibatch mode exactly (same sums, f32
 accumulation, per-feature count averaging) up to float reduction order;
@@ -132,7 +131,6 @@ def make_batch_train_fn(
     rule: Rule,
     hyper: dict,
     batch_size: int,
-    mini_batch_average: bool = True,
     track_deltas: bool = False,
 ):
     """Raw (unjitted) `step(state, indices, values, labels, plans) ->
@@ -183,12 +181,11 @@ def make_batch_train_fn(
                           axis=-1)
         sums = staged_segment_totals(plan, stack)  # [U, nd]
         counts = sums[:, nd - 1]
-        denom = counts if mini_batch_average else None
 
-        weights = staged_scatter_add(weights, plan, sums[:, 0], denom)
+        weights = staged_scatter_add(weights, plan, sums[:, 0], counts)
         pos = 1
         if use_cov and out.dcov is not None:
-            covars = staged_scatter_add(covars, plan, sums[:, pos], denom)
+            covars = staged_scatter_add(covars, plan, sums[:, pos], counts)
             pos += 1
         new_slots = dict(slots)
         slot_sums = {}
@@ -257,12 +254,10 @@ def make_batch_train_step(
     rule: Rule,
     hyper: dict,
     batch_size: int,
-    mini_batch_average: bool = True,
     track_deltas: bool = False,
     donate: bool = True,
 ):
     """Jitted wrapper over make_batch_train_fn (the single-replica path)."""
     fn = make_batch_train_fn(rule, hyper, batch_size,
-                             mini_batch_average=mini_batch_average,
                              track_deltas=track_deltas)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())

@@ -9,8 +9,8 @@ FeatureBlock [B, K]:
   Bit-faithful to the reference's sequential semantics (used for parity tests
   and small models).
 - **minibatch mode** — one vectorized gather [B, K], the rule vmapped over
-  rows against the *stale* batch-start weights, deltas scatter-added (averaged
-  per feature when `mini_batch_average`). This is exactly the reference's own
+  rows against the *stale* batch-start weights, deltas averaged per feature
+  and applied once. This is exactly the reference's own
   documented mini-batch semantic (ref: RegressionBaseUDTF.java:236-295 +
   utils/lang/FloatAccumulator.java:38-41: accumulate per-feature deltas over
   the batch, apply sum/count once), and is the TPU hot path: one big gather +
@@ -197,10 +197,8 @@ def make_train_fn(
     rule: Rule,
     hyper: dict,
     mode: str = "minibatch",
-    mini_batch_average: bool = True,
     track_deltas: bool = False,
     feature_shard: Optional[Tuple[str, int]] = None,
-    update_backend: str = "xla",
 ):
     """Build the raw (unjitted) `step(state, indices, values, labels) ->
     (state, loss_sum)` — composable inside shard_map/scan by parallel/mix.py.
@@ -223,15 +221,6 @@ def make_train_fn(
     """
     if mode not in ("scan", "minibatch"):
         raise ValueError(f"unknown mode {mode!r}")
-    if update_backend not in ("xla", "mxu"):
-        raise ValueError(f"unknown update_backend {update_backend!r}")
-    if update_backend == "mxu":
-        if mode != "minibatch":
-            raise ValueError("update_backend='mxu' requires minibatch mode "
-                             "(scan mode is sequential per row)")
-        if feature_shard is not None:
-            raise ValueError("update_backend='mxu' does not compose with "
-                             "feature_shard yet; use the xla backend")
     use_cov = rule.use_covariance
 
     if feature_shard is None:
@@ -352,7 +341,7 @@ def make_train_fn(
         # write (the SpaceEfficientDenseModel analog stores compact, never
         # accumulates compact).
         acc = jnp.promote_types(weights.dtype, jnp.float32)
-        if mini_batch_average and not dense:
+        if not dense:
             # In the BLOCK's index space: the lanes are sorted by feature id
             # with their deltas and their gathered old values as payload,
             # each run of equal ids is summed in f32, and the run's lanes
@@ -403,36 +392,28 @@ def make_train_fn(
             with jax.named_scope(SCOPE_RULE):
                 return new_state, jnp.sum(outs.loss)
 
-        if mini_batch_average:
-            # A table a few hundred times the block at most: ten passes over
-            # it cost less than the sort, the scans and the second gather.
-            # scopes follow the statements' order: moving one would change
-            # the traced program, and with it the compile cache's key
+        # A table a few hundred times the block at most: ten passes over it
+        # cost less than the sort, the scans and the second gather.
+        # scopes follow the statements' order: moving one would change the
+        # traced program, and with it the compile cache's key
+        with jax.named_scope(SCOPE_REDUCE):
+            counts = jnp.zeros(weights.shape, acc).at[sidx].add(
+                lane_upd, mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            denom = jnp.maximum(counts, 1.0)
+        with jax.named_scope(SCOPE_REDUCE):
+            dw_sum = jnp.zeros(weights.shape, acc).at[sidx].add(
+                outs.dw.astype(acc), mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            weights = (weights.astype(acc) + dw_sum / denom) \
+                .astype(weights.dtype)
+        if has_cov:
             with jax.named_scope(SCOPE_REDUCE):
-                counts = jnp.zeros(weights.shape, acc).at[sidx].add(
-                    lane_upd, mode="drop")
+                dc_sum = jnp.zeros(covars.shape, acc).at[sidx].add(
+                    outs.dcov.astype(acc), mode="drop")
             with jax.named_scope(SCOPE_APPLY):
-                denom = jnp.maximum(counts, 1.0)
-            with jax.named_scope(SCOPE_REDUCE):
-                dw_sum = jnp.zeros(weights.shape, acc).at[sidx].add(
-                    outs.dw.astype(acc), mode="drop")
-            with jax.named_scope(SCOPE_APPLY):
-                weights = (weights.astype(acc) + dw_sum / denom) \
-                    .astype(weights.dtype)
-            if has_cov:
-                with jax.named_scope(SCOPE_REDUCE):
-                    dc_sum = jnp.zeros(covars.shape, acc).at[sidx].add(
-                        outs.dcov.astype(acc), mode="drop")
-                with jax.named_scope(SCOPE_APPLY):
-                    covars = (covars.astype(acc) + dc_sum / denom) \
-                        .astype(covars.dtype)
-        else:
-            with jax.named_scope(SCOPE_APPLY):
-                weights = weights.at[sidx].add(
-                    outs.dw.astype(weights.dtype), mode="drop")
-                if has_cov:
-                    covars = covars.at[sidx].add(
-                        outs.dcov.astype(covars.dtype), mode="drop")
+                covars = (covars.astype(acc) + dc_sum / denom) \
+                    .astype(covars.dtype)
         with jax.named_scope(SCOPE_APPLY):
             for k in rule.slot_names:
                 if k in outs.dslots:
@@ -440,39 +421,29 @@ def make_train_fn(
                         outs.dslots[k].astype(slots[k].dtype), mode="drop")
             if rule.derive_w is not None:
                 # Dual-averaging weights are a pure function of the
-                # *updated* accumulators — gather-after-scatter makes
-                # duplicate features across the batch deterministic.
+                # *updated* accumulators: one more pass over the table,
+                # kept where no row fired on the feature (as the
+                # block-local arm keeps it). A per-lane write would let a
+                # quiet row's lane put the old weight back over a firing
+                # row's.
                 tf_end = (t0 + b).astype(jnp.float32)
-                sl_g = {k: _gather(new_slots[k], sidx) for k in new_slots}
-                w_new = rule.derive_w(sl_g, tf_end, hyper)  # [B, K]
-                keep = _gather(weights, sidx)
-                w_new = jnp.where(lane_upd > 0, w_new, keep)
-                weights = weights.at[sidx].set(
-                    w_new.astype(weights.dtype), mode="drop")
-        if mini_batch_average:
-            # `counts` is exactly this block's per-feature lane_upd scatter,
-            # so touched and the MIX delta clock derive from it with
-            # full-table elementwise ops instead of two more scatters
-            with jax.named_scope(SCOPE_TOUCHED):
-                touched = jnp.maximum(state.touched,
-                                      (counts > 0).astype(jnp.int8))
-            if track_deltas:
-                with jax.named_scope(SCOPE_APPLY):
-                    delta_tab = new_slots.get(DELTA_SLOT,
-                                              state.slots[DELTA_SLOT])
-                    new_slots[DELTA_SLOT] = delta_tab + counts.astype(
-                        delta_tab.dtype)
-        else:
-            with jax.named_scope(SCOPE_TOUCHED):
-                touched = state.touched.at[sidx].max(
-                    lane_upd.astype(jnp.int8), mode="drop"
-                )
-            if track_deltas:
-                with jax.named_scope(SCOPE_APPLY):
-                    delta_tab = new_slots.get(DELTA_SLOT,
-                                              state.slots[DELTA_SLOT])
-                    new_slots[DELTA_SLOT] = delta_tab.at[sidx].add(
-                        lane_upd.astype(delta_tab.dtype), mode="drop")
+                w_full = rule.derive_w(
+                    {k: v.astype(acc) for k, v in new_slots.items()},
+                    tf_end, hyper)
+                weights = jnp.where(counts > 0,
+                                    w_full.astype(weights.dtype), weights)
+        # `counts` is exactly this block's per-feature lane_upd scatter, so
+        # touched and the MIX delta clock derive from it with full-table
+        # elementwise ops instead of two more scatters
+        with jax.named_scope(SCOPE_TOUCHED):
+            touched = jnp.maximum(state.touched,
+                                  (counts > 0).astype(jnp.int8))
+        if track_deltas:
+            with jax.named_scope(SCOPE_APPLY):
+                delta_tab = new_slots.get(DELTA_SLOT,
+                                          state.slots[DELTA_SLOT])
+                new_slots[DELTA_SLOT] = delta_tab + counts.astype(
+                    delta_tab.dtype)
         new_state = state.replace(
             weights=weights,
             covars=covars,
@@ -485,132 +456,19 @@ def make_train_fn(
             loss_sum = jnp.sum(outs.loss)
         return new_state, loss_sum
 
-    def minibatch_step_mxu(state: LinearState, indices, values, labels):
-        """minibatch_step with every random table access routed through
-        ops/mxu_scatter (sorted-window one-hot matmuls) instead of XLA's
-        scalar gather/scatter engine — same FloatAccumulator semantics, f32
-        sums equal up to addition order. One packed gather serves w, cov and
-        every optimizer slot; one stacked scatter-add serves every delta
-        column plus the update counts; derive_w rules recompute w as a
-        full-table elementwise map masked by the counts (no
-        gather-after-scatter round trip at all)."""
-        from ..ops import mxu_scatter as mxu
-
-        b, k = indices.shape
-        t0 = state.step
-        ts = (t0 + 1 + jnp.arange(b)).astype(jnp.float32)
-        gl = state.globals
-        if rule.pre_batch is not None:
-            gl = rule.pre_batch(gl, labels)
-
-        d = state.weights.shape[0]
-        slot_names = tuple(sorted(state.slots))
-        plan = mxu.make_plan(indices.reshape(-1), d)
-
-        # ONE gather for everything: w [+ cov] [+ slots], padded to a
-        # power-of-two column count
-        cols = [state.weights] + ([state.covars] if use_cov else []) + \
-               [state.slots[s] for s in slot_names]
-        ncol = len(cols)
-        cpad = mxu.pad_cols(ncol)
-        packed = jnp.stack(
-            cols + [cols[0]] * (cpad - ncol), axis=-1).astype(jnp.float32)
-        g = mxu.gather(packed, plan).reshape(b, k, cpad)
-        w_g = g[..., 0]
-        pos = 1
-        cov_g = None
-        if use_cov:
-            oob = (indices < 0) | (indices >= d)
-            cov_g = jnp.where(oob, 1.0, g[..., pos])
-            pos += 1
-        sl_g = {s: g[..., pos + i] for i, s in enumerate(slot_names)}
-
-        def per_row(w, cov, sl, val, y, tf):
-            score = jnp.sum(w * val)
-            sq_norm = jnp.sum(val * val)
-            variance = jnp.sum(cov * val * val) if use_cov else jnp.zeros(())
-            ctx = RowContext(w, cov, sl, val, y, score, sq_norm, variance,
-                             tf, gl)
-            return rule.update(ctx, hyper)
-
-        outs = jax.vmap(per_row)(w_g, cov_g, sl_g, values, labels, ts)
-        upd = outs.updated.astype(jnp.float32)  # [B]
-        lane_upd = upd[:, None] * jnp.ones_like(values)  # [B, K]
-
-        # ONE stacked scatter-add into zeros: dw [+ dcov] [+ dslots] + counts
-        dcols = [outs.dw]
-        if use_cov and outs.dcov is not None:
-            dcols.append(outs.dcov)
-        scat_slots = [s for s in rule.slot_names if s in outs.dslots]
-        dcols += [outs.dslots[s] for s in scat_slots]
-        dcols.append(lane_upd)
-        nd = len(dcols)
-        dpad = mxu.pad_cols(nd)
-        dstack = jnp.stack(dcols, axis=-1).reshape(b * k, nd)
-        sums = mxu.scatter_add(
-            jnp.zeros((d, dpad), jnp.float32), indices.reshape(-1), dstack,
-            plan)
-        counts = sums[:, nd - 1]
-
-        acc = jnp.promote_types(state.weights.dtype, jnp.float32)
-        dw_sum = sums[:, 0].astype(acc)
-        denom = jnp.maximum(counts, 1.0).astype(acc) if mini_batch_average \
-            else jnp.ones((), acc)
-        weights = (state.weights.astype(acc) + dw_sum / denom) \
-            .astype(state.weights.dtype)
-        covars = state.covars
-        pos = 1
-        if use_cov and outs.dcov is not None:
-            dc_sum = sums[:, pos].astype(acc)
-            covars = (state.covars.astype(acc) + dc_sum / denom) \
-                .astype(state.covars.dtype)
-            pos += 1
-        new_slots = dict(state.slots)
-        for s in scat_slots:
-            new_slots[s] = (state.slots[s].astype(acc) +
-                            sums[:, pos].astype(acc)).astype(
-                                state.slots[s].dtype)
-            pos += 1
-
-        if rule.derive_w is not None:
-            # w is a pure elementwise function of the slots, so recompute it
-            # over the WHOLE table and keep old values where nothing fired —
-            # one fused full-table pass (~0.1ms/100MB on v5e) replaces the
-            # xla path's gather-after-scatter + set
-            tf_end = (t0 + b).astype(jnp.float32)
-            sl_full = {s: new_slots[s].astype(jnp.float32)
-                       for s in new_slots}
-            w_full = rule.derive_w(sl_full, tf_end, hyper)
-            weights = jnp.where(counts > 0,
-                                w_full.astype(state.weights.dtype), weights)
-
-        touched = jnp.maximum(state.touched, (counts > 0).astype(jnp.int8))
-        if track_deltas:
-            delta_tab = new_slots.get(DELTA_SLOT, state.slots[DELTA_SLOT])
-            new_slots[DELTA_SLOT] = delta_tab + counts.astype(delta_tab.dtype)
-
-        new_state = state.replace(
-            weights=weights, covars=covars, slots=new_slots, touched=touched,
-            step=t0 + b, globals=gl)
-        return new_state, jnp.sum(outs.loss)
-
     if mode == "scan":
         return scan_step
-    return minibatch_step_mxu if update_backend == "mxu" else minibatch_step
+    return minibatch_step
 
 
 def make_train_step(
     rule: Rule,
     hyper: dict,
     mode: str = "minibatch",
-    mini_batch_average: bool = True,
     donate: bool = True,
-    update_backend: str = "xla",
 ):
     """Jitted wrapper over make_train_fn (the single-replica path)."""
-    fn = make_train_fn(rule, hyper, mode=mode,
-                       mini_batch_average=mini_batch_average,
-                       update_backend=update_backend)
+    fn = make_train_fn(rule, hyper, mode=mode)
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 

@@ -92,8 +92,7 @@ def init_native_tables(dims: int, use_covariance: bool,
     return t
 
 
-def make_native_batch_step(rule: Rule, hyper: dict,
-                           mini_batch_average: bool = True):
+def make_native_batch_step(rule: Rule, hyper: dict):
     """`step(tables, values, labels, plans) -> loss_sum` applying one
     staged block through the native pass. `plans` is the block's
     stage_block_plans output, HOST-side (the plan ABI forbids device
@@ -109,7 +108,7 @@ def make_native_batch_step(rule: Rule, hyper: dict,
         loss = native.batch_apply_block(
             rule.name, hyper, values, labels, plans.main, plans.tail,
             tables["w"].shape[0], tables["w"], tables["cov"],
-            tables["touched"], mini_batch_average=mini_batch_average)
+            tables["touched"])
         if loss is None:  # the .so vanished between probe and call
             raise RuntimeError("native batch apply became unavailable "
                                f"mid-run: {native.load_error()}")

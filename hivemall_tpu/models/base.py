@@ -86,10 +86,6 @@ def base_options() -> Options:
           "Run exact scan epochs through the native C row loop — the "
           "host fast path for accelerator-less mappers (train_arow: any "
           "options; train_fm: -classification with a fixed -eta)")
-    o.add("mxu_scatter", None, False,
-          "Route -mini_batch table updates through the sorted-window MXU "
-          "gather/scatter (ops/mxu_scatter.py) instead of XLA's scalar "
-          "scatter engine — same semantics, f32 sums up to addition order")
     o.add("batch", "batch_backend", True,
           "Segment-sum batched backend: apply minibatches of B rows "
           "through one host-staged dedup plan (core/batch_update.py) — "
@@ -468,17 +464,16 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
         if mini_batch > 1:
             raise ValueError("-batch IS the mini-batch backend; drop "
                              "-mini_batch (its size becomes -batch's B)")
-        if cl.has("native_scan") or cl.has("pallas") \
-                or cl.has("mxu_scatter"):
+        if cl.has("native_scan") or cl.has("pallas"):
             raise ValueError("-batch does not compose with -native_scan/"
-                             "-pallas/-mxu_scatter; pick one execution "
-                             "backend (docs/execution_backends.md)")
+                             "-pallas; pick one execution backend "
+                             "(docs/execution_backends.md)")
         mode = "batch"
     if cl.has("native_apply") and mode != "batch":
         # -native_apply is a modifier of the batch backend, not a backend
         # of its own — and it never composes with the other execution
-        # flags (the -mxu_scatter/-pallas/-native_scan combos land here
-        # or in the -batch refusal above)
+        # flags (the -pallas/-native_scan combos land here or in the
+        # -batch refusal above)
         raise ValueError("-native_apply rides the -batch backend; add "
                          "-batch B (docs/execution_backends.md)")
     if mode == "minibatch":
@@ -530,11 +525,8 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
         # guard when the first block is traced (vmem_resident_reason)
         step = make_pallas_scan_step(rule, hyper, interpret=pallas_interpret)
     else:
-        backend = "mxu" if (cl.has("mxu_scatter") and mode == "minibatch") \
-            else "xla"
-        step = make_train_step(rule, hyper, mode=mode,
-                               update_backend=backend)
-        if mode == "minibatch" and backend == "xla":
+        step = make_train_step(rule, hyper, mode=mode)
+        if mode == "minibatch":
             # which way the step applies a block: the same static test of
             # shapes the step makes when it is traced
             call.set(apply=apply_strategy(dims, block_size * width))
@@ -606,7 +598,7 @@ def _mix_unsupported_reason(rule: Rule, cl: CommandLine,
     """Why this call cannot train mixed replicas, or None. Stated, not
     silent: each is a path nobody has needed mixed yet."""
     backends = [f"-{o}" for o in ("batch", "native_scan", "native_apply",
-                                  "pallas", "mxu_scatter") if cl.has(o)]
+                                  "pallas") if cl.has(o)]
     if backends:
         return (f"does not compose with {'/'.join(backends)}: the replicas "
                 "run the XLA -mini_batch step; drop one of the two")

@@ -172,8 +172,7 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
                   row_chunk: Optional[int] = None,
                   feature_shard: Optional[Tuple[str, int, int]] = None,
                   pack_v: Optional[bool] = None,
-                  jit: bool = True,
-                  update_backend: str = "xla"):
+                  jit: bool = True):
     """`row_chunk` (minibatch mode only) tiles the batch's K^2 pairwise work:
     the [B, K, K, k] dV / [B, K, K] gg activations are the FFM memory hot
     spot (256MB at B=16384, K=32, k=4 — grows with the square of the field
@@ -191,37 +190,13 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
     it owns of the row's [K, K, k] block (exactly one owner per hashed key)
     and ONE psum reconstructs the full block everywhere; updates scatter
     back owned entries only. Keys hash with the ORIGINAL v_dims, so the
-    model is the same function as the unsharded one.
-
-    `update_backend='mxu'` (local minibatch only) routes the pairwise
-    [B*K*K] V+gg traffic — FFM's entire cost at CTR shapes — through the
-    sorted-window MXU gather/scatter (ops/mxu_scatter.py): the packed
-    [Dv, k+1] block table pads to a power-of-two lane count, ONE windowed
-    gather serves the whole batch's pair blocks, and dV+dgg ride one
-    windowed scatter whose id sort is shared with the gather's plan."""
-    if update_backend not in ("xla", "mxu"):
-        raise ValueError(f"unknown update_backend {update_backend!r}")
-    if update_backend == "mxu":
-        if mode != "minibatch" or feature_shard is not None:
-            raise ValueError("update_backend='mxu' requires the local "
-                             "minibatch path")
-        if pack_v is False:
-            raise ValueError("update_backend='mxu' rides the packed V+gg "
-                             "table; pack_v=False contradicts it")
-    use_mxu = update_backend == "mxu"
+    model is the same function as the unsharded one."""
 
     if feature_shard is None:
         translate_w = None
 
-        def predict_gather(st: FFMState, idx, val, fields, packed=None,
-                           pg=None, keys=None):
-            if pg is not None:
-                # pre-gathered [K, K, k+1] pair block (the mxu path hoists
-                # the whole batch's gather out of the vmap)
-                Vg, gg = pg[..., :-1], pg[..., -1]
-                p, _, _, xx = _row_predict(st, idx, val, fields, hyper,
-                                           Vg=Vg, keys=keys)
-            elif packed is None:
+        def predict_gather(st: FFMState, idx, val, fields, packed=None):
+            if packed is None:
                 p, keys, Vg, xx = _row_predict(st, idx, val, fields, hyper)
                 gg = st.v_gg[keys]
             else:
@@ -244,8 +219,7 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
         def translate_w(idx, val):
             return translate_to_stripe(idx, val, shard_axis, stripe_w)
 
-        def predict_gather(st: FFMState, idx, val, fields, packed=None,
-                           pg=None, keys=None):
+        def predict_gather(st: FFMState, idx, val, fields, packed=None):
             return sharded_ffm_gather(st, idx, val, fields, hyper,
                                       shard_axis, stripe_w, stripe_v)
 
@@ -256,10 +230,9 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
         pc = jnp.clip(p, hyper.min_target, hyper.max_target)
         return pc - y, 0.5 * (pc - y) ** 2
 
-    def row_updates(st: FFMState, idx, val, fields, y, t, packed=None,
-                    pg=None, keys=None):
+    def row_updates(st: FFMState, idx, val, fields, y, t, packed=None):
         p, keys, Vg, xx, gg, own = predict_gather(st, idx, val, fields,
-                                                  packed, pg, keys)
+                                                  packed)
         g, loss = dloss_fn(p, y)
         K = idx.shape[0]
         # dV[i, j] = g * x_i x_j * V_{j, f_i} for i != j
@@ -342,37 +315,15 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
         With `pk_base`/`pk_carry` (local path), V and gg live interleaved
         in one [Dv, k+1] table for the block: gathers and scatters each
         collapse to a single row op; carry.v / carry.v_gg are STALE inside
-        and the caller unpacks at block end. Under the mxu backend the
-        tables carry power-of-two pad lanes and both row ops go through
-        one shared sorted-window plan."""
-        if use_mxu:
-            from ..ops import mxu_scatter as mxu
-
-            keys_all = jax.vmap(
-                lambda i, f: _row_pair_keys(i, f, hyper.v_dims))(idx, fld)
-            plan = mxu.make_plan(keys_all.reshape(-1), hyper.v_dims)
-            kp1 = hyper.factors + 1
-            pg_all = mxu.gather(pk_base, plan) \
-                .reshape(keys_all.shape + (pk_base.shape[-1],))[..., :kp1]
-            p, g, loss, keys, dV, dgg = jax.vmap(
-                lambda i, v, f, y, t, kk, pg: row_updates(
-                    base, i, v, f, y, t, None, pg, kk))(
-                    idx, val, fld, lab, ts, keys_all, pg_all)
-        else:
-            p, g, loss, keys, dV, dgg = jax.vmap(
-                lambda i, v, f, y, t: row_updates(base, i, v, f, y, t,
-                                                  pk_base))(
-                    idx, val, fld, lab, ts)
+        and the caller unpacks at block end."""
+        p, g, loss, keys, dV, dgg = jax.vmap(
+            lambda i, v, f, y, t: row_updates(base, i, v, f, y, t,
+                                              pk_base))(
+                idx, val, fld, lab, ts)
         widx, wval = (idx, val) if translate_w is None \
             else jax.vmap(translate_w)(idx, val)
         k = dV.shape[-1]
-        if use_mxu:
-            from ..ops import mxu_scatter as mxu
-
-            upd = jnp.concatenate([dV, dgg[..., None]], axis=-1)
-            pk_carry = mxu.scatter_add(pk_carry, keys.reshape(-1),
-                                       upd.reshape(-1, k + 1), plan)
-        elif pk_carry is not None:
+        if pk_carry is not None:
             upd = jnp.concatenate([dV, dgg[..., None]], axis=-1)
             pk_carry = scatter_rows_flat(pk_carry, keys.reshape(-1),
                                          upd.reshape(-1, k + 1))
@@ -417,18 +368,7 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
         return b * K * K * 8 >= state.v.shape[0]
 
     def _pack_v(state: FFMState):
-        pk = jnp.concatenate([state.v, state.v_gg[:, None]], axis=1)
-        if use_mxu:
-            # mxu tables need power-of-two lane counts; extra pad lanes
-            # receive no updates (kl < c scatter protocol)
-            from ..ops.mxu_scatter import pad_cols
-
-            cpad = pad_cols(pk.shape[1])
-            if cpad != pk.shape[1]:
-                pk = jnp.concatenate(
-                    [pk, jnp.zeros((pk.shape[0], cpad - pk.shape[1]),
-                                   pk.dtype)], axis=1)
-        return pk
+        return jnp.concatenate([state.v, state.v_gg[:, None]], axis=1)
 
     def _unpack_v(st: FFMState, pk):
         k = hyper.factors
@@ -437,7 +377,7 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
     def minibatch_step(state: FFMState, indices, values, fields, labels):
         b = indices.shape[0]
         ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
-        pk = _pack_v(state) if use_mxu or _want_pack(
+        pk = _pack_v(state) if _want_pack(
             b, indices.shape[1], state) else None
         st, loss, g_sum, pk = apply_row_group(state, state, indices, values,
                                               fields, labels, ts,
@@ -457,7 +397,7 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
             (indices, values, fields, labels))
         ts_all = (state.step + 1 + jnp.arange(b)).astype(jnp.float32) \
             .reshape(b // c, c)
-        pk0 = _pack_v(state) if use_mxu or _want_pack(
+        pk0 = _pack_v(state) if _want_pack(
             b, indices.shape[1], state) else None
 
         def body(carry, chunk_in):
@@ -703,13 +643,10 @@ def train_ffm(rows: Sequence[Sequence[str]], labels, options: Optional[str] = No
         if block % row_chunk != 0:
             raise ValueError(
                 f"-mini_batch {block} not divisible by -row_chunk {row_chunk}")
-    backend = "mxu" if (cl.has("mxu_scatter") and mode == "minibatch") \
-        else "xla"
-    step = make_ffm_step(hyper, mode, row_chunk=row_chunk,
-                         update_backend=backend)
+    step = make_ffm_step(hyper, mode, row_chunk=row_chunk)
     # the trailing partial block (n % block rows) won't divide by row_chunk;
     # it goes through an untiled step (same semantics, small shape)
-    tail_step = make_ffm_step(hyper, mode, update_backend=backend) \
+    tail_step = make_ffm_step(hyper, mode) \
         if row_chunk is not None else step
     state = init_ffm_state(hyper)
     iters = cl.get_int("iters", 1)

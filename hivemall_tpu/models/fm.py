@@ -173,22 +173,16 @@ def _dloss_and_loss(p, y, hyper: FMHyper):
 
 
 def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
-                 mini_batch_average: bool = True,
                  feature_shard: Optional[Tuple[str, int]] = None,
-                 pack_w: bool = True,
-                 jit: bool = True,
-                 update_backend: str = "xla"):
+                 jit: bool = True):
     """Jitted FM block update. scan = reference-exact sequential; minibatch =
-    accumulate-then-apply against block-start parameters.
-
-    `mini_batch_average` applies each parameter's accumulated delta divided by
-    its update count — w/V per-feature touch counts, w0 by the batch size —
-    exactly the reference's own mini-batch application rule (sum/count,
-    ref: RegressionBaseUDTF.java:281-295 + utils/lang/FloatAccumulator.java:38-41;
-    the reference FM itself is per-row-only, so averaging is the documented
-    bridge semantic, same as core/engine.py's minibatch mode). Without it the
-    raw sums scale the effective step by the per-feature row frequency and
-    diverge at CTR batch sizes/head features.
+    accumulate-then-apply against block-start parameters: each parameter's
+    accumulated delta divided by its update count — w/V per-feature touch
+    counts, w0 by the batch size — exactly the reference's own mini-batch
+    application rule (sum/count, ref: RegressionBaseUDTF.java:281-295 +
+    utils/lang/FloatAccumulator.java:38-41; the reference FM itself is
+    per-row-only, so averaging is the documented bridge semantic, same as
+    core/engine.py's minibatch mode).
 
     `feature_shard=(axis_name, stripe)` runs the same step on a [D/stripe]
     model stripe inside shard_map — the FM analog of the engine's
@@ -201,31 +195,6 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     not supported sharded (its lambda updates need cross-stripe v' sums)."""
     if feature_shard is not None and hyper.adareg:
         raise ValueError("adareg is not supported with feature_shard")
-    if update_backend not in ("xla", "mxu"):
-        raise ValueError(f"unknown update_backend {update_backend!r}")
-    if update_backend == "mxu":
-        if mode != "minibatch" or feature_shard is not None:
-            raise ValueError("update_backend='mxu' requires the local "
-                             "minibatch path")
-        from ..ops.mxu_scatter import pad_cols
-
-        kp = hyper.padded_factors
-        if kp <= hyper.factors or not pack_w:
-            raise ValueError(
-                "the mxu FM path rides the packed [D, kp] table and borrows "
-                "pad lanes for w and the update counts; it needs "
-                "padded_factors > factors (k = 8/16 exactly have no pad "
-                "lane) and pack_w=True")
-        if pad_cols(kp) != kp:
-            # padded_factors rounds to a multiple of 8, not a power of two;
-            # the mxu lane protocol needs power-of-two columns — fail at
-            # build time with the constraint spelled out, not at trace time
-            raise ValueError(
-                f"the mxu FM path needs a power-of-two padded_factors "
-                f"(lane tiling, ops/mxu_scatter.py); factors="
-                f"{hyper.factors} pads to {kp} — choose k whose "
-                f"multiple-of-8 round-up is a power of two (k <= 7, "
-                f"9..15, 25..31, ...) or use the xla backend")
 
     # Borrowed-lane packing (minibatch local path): when V is lane-padded
     # (kp > k), the first pad lane carries w for the block — ONE [K,kp]
@@ -235,19 +204,15 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     # v5e). The pad-lane-zero invariant holds on the canonical state: the
     # lane is zeroed again at unpack.
     w_lane = hyper.factors
-    # pack_w=False forces the split path (parity tests A/B it); packing
-    # additionally requires a free pad lane (kp > k) and the local
-    # (unsharded) path — without either it silently runs split
+    # packing requires a free pad lane (kp > k) and the local (unsharded)
+    # path; without either the step runs split
     use_packed = (feature_shard is None
-                  and hyper.padded_factors > hyper.factors
-                  and pack_w)
+                  and hyper.padded_factors > hyper.factors)
 
     if feature_shard is None:
-        def gather_and_predict(state: FMState, idx, val, packed=None,
-                               pg=None):
-            if pg is not None or packed is not None:
-                if pg is None:
-                    pg = packed.at[idx].get(mode="fill", fill_value=0.0)
+        def gather_and_predict(state: FMState, idx, val, packed=None):
+            if packed is not None:
+                pg = packed.at[idx].get(mode="fill", fill_value=0.0)
                 wg = pg[:, w_lane]
                 vg = pg.at[:, w_lane].set(0.0)  # restore the pad-lane zero
             else:
@@ -258,18 +223,17 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     else:
         shard_axis, stripe = feature_shard
 
-        def gather_and_predict(state: FMState, idx, val, packed=None,
-                               pg=None):
+        def gather_and_predict(state: FMState, idx, val, packed=None):
             wg, vg, vmask, lidx, p, sum_vfx = sharded_gather_predict(
                 state.w, state.v, state.w0, idx, val, shard_axis, stripe)
             return wg, vg, vmask, lidx, p, sum_vfx
 
-    def row_deltas(state: FMState, idx, val, y, t, packed=None, pg=None):
+    def row_deltas(state: FMState, idx, val, y, t, packed=None):
         with jax.named_scope(SCOPE_RULE):
             eta = hyper.eta.eta(t)
         with jax.named_scope(SCOPE_GATHER):
             wg, vg, eff_val, sidx, p, sum_vfx = gather_and_predict(
-                state, idx, val, packed, pg)
+                state, idx, val, packed)
         with jax.named_scope(SCOPE_LOSS):
             g, loss = _dloss_and_loss(p, y, hyper)
         with jax.named_scope(SCOPE_RULE):
@@ -322,8 +286,6 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
         state, losses = jax.lax.scan(body, state, (indices, values, labels, va_mask))
         return state, jnp.sum(losses)
 
-    use_mxu = update_backend == "mxu"
-
     def minibatch_step(state: FMState, indices, values, labels, va_mask):
         b = indices.shape[0]
         ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
@@ -331,30 +293,11 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
             packed = (state.v.at[:, w_lane].set(state.w) if use_packed
                       else None)
 
-        plan = None
-        if use_mxu:
-            # sorted-window MXU path (ops/mxu_scatter.py): the packed
-            # [D, kp] table is gathered ONCE for the whole block and the
-            # update columns ride one windowed scatter — V traffic is the
-            # whole FM step cost on v5e (docs/perf_history.md FM bisection), and the
-            # scalar engine charges ~20ms/block for it
-            from ..ops import mxu_scatter as mxu
+        def per_row(idx, val, y, t):
+            return row_deltas(state, idx, val, y, t, packed)
 
-            plan = mxu.make_plan(indices.reshape(-1), state.w.shape[0])
-            pg_all = mxu.gather(packed, plan).reshape(indices.shape
-                                                      + (packed.shape[-1],))
-
-            def per_row(idx, val, y, t, pg):
-                return row_deltas(state, idx, val, y, t, None, pg)
-
-            dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx = \
-                jax.vmap(per_row)(indices, values, labels, ts, pg_all)
-        else:
-            def per_row(idx, val, y, t):
-                return row_deltas(state, idx, val, y, t, packed)
-
-            dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx = \
-                jax.vmap(per_row)(indices, values, labels, ts)
+        dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx = \
+            jax.vmap(per_row)(indices, values, labels, ts)
         theta = (1.0 - va_mask)  # [B]
 
         def scatter_v(v_table, upd):
@@ -368,97 +311,35 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
         # store-compact/accumulate-wide policy as core/engine.py)
         acc_w = jnp.promote_types(state.w.dtype, jnp.float32)
         acc_v = jnp.promote_types(state.v.dtype, jnp.float32)
-        if mini_batch_average and not use_mxu:
-            # FloatAccumulator denominators (shared by the packed and
-            # unpacked apply below): per-feature touch counts, w0 by the
-            # effective batch size
-            with jax.named_scope(SCOPE_REDUCE):
-                counts = jnp.zeros((state.w.shape[0],), jnp.float32) \
-                    .at[sidx].add(jnp.broadcast_to(theta[:, None], sidx.shape),
-                                  mode="drop")
-            with jax.named_scope(SCOPE_APPLY):
-                denom = jnp.maximum(counts, 1.0)
+        # FloatAccumulator denominators (shared by the packed and split
+        # apply below): per-feature touch counts, w0 by the effective batch
+        # size
+        with jax.named_scope(SCOPE_REDUCE):
+            counts = jnp.zeros((state.w.shape[0],), jnp.float32) \
+                .at[sidx].add(jnp.broadcast_to(theta[:, None], sidx.shape),
+                              mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            denom = jnp.maximum(counts, 1.0)
 
-        if use_mxu:
-            # dv and dw ride one windowed scatter over the packed layout
-            # (dw on lane w_lane == factors, exactly its packed position);
-            # the per-feature update counts borrow the NEXT pad lane when
-            # the shape has one, so counts, denom and touched all come out
-            # of the same matmul pass
-            from ..ops import mxu_scatter as mxu
-
-            k_log = hyper.factors
-            kp = state.v.shape[1]
-            cnt_lane = k_log + 1 if k_log + 1 < kp else None
-            ids = indices.reshape(-1)
-            scaled = (theta[:, None, None] * jnp.concatenate(
-                [dv[..., :k_log], dw[..., None]], axis=-1)).astype(acc_v)
-            if cnt_lane is not None:
-                lane_cnt = jnp.broadcast_to(
-                    theta[:, None, None].astype(acc_v),
-                    scaled.shape[:2] + (1,))
-                scaled = jnp.concatenate([scaled, lane_cnt], axis=-1)
-            upd_flat = scaled.reshape(-1, scaled.shape[-1])
-            if mini_batch_average:
-                acc = mxu.scatter_add(jnp.zeros(state.v.shape, acc_v), ids,
-                                      upd_flat, plan)
-                if cnt_lane is None:
-                    counts = mxu.scatter_add(
-                        jnp.zeros((state.w.shape[0],), jnp.float32), ids,
-                        jnp.broadcast_to(theta[:, None],
-                                         indices.shape).reshape(-1), plan)
-                else:
-                    counts = acc[:, cnt_lane]
-                denom = jnp.maximum(counts, 1.0)
-                new_w = (state.w.astype(acc_v) + acc[:, k_log] / denom) \
-                    .astype(state.w.dtype)
-                new_v = (state.v.astype(acc_v)
-                         + acc.at[:, k_log:].set(0.0) / denom[:, None]) \
-                    .astype(state.v.dtype)
-                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                    jnp.sum(theta), 1.0)
-            else:
-                pk = mxu.scatter_add(packed, ids, upd_flat, plan)
-                new_w = pk[:, w_lane]
-                if cnt_lane is None:
-                    counts = mxu.scatter_add(
-                        jnp.zeros((state.w.shape[0],), jnp.float32), ids,
-                        jnp.broadcast_to(theta[:, None],
-                                         indices.shape).reshape(-1), plan)
-                else:
-                    counts = pk[:, cnt_lane]
-                new_v = pk.at[:, k_log:].set(0.0)
-                new_w0 = state.w0 + jnp.sum(theta * dw0)
-            touched = jnp.maximum(state.touched,
-                                  (counts > 0).astype(jnp.int8))
-        elif use_packed:
+        if use_packed:
             # dw rides lane w_lane of the same flat row scatter as dv
             k_log = hyper.factors
             with jax.named_scope(SCOPE_REDUCE):
                 upd = jnp.concatenate([dv[..., :k_log], dw[..., None]],
                                       axis=-1)
-            if mini_batch_average:
-                with jax.named_scope(SCOPE_REDUCE):
-                    acc = scatter_rows_flat(jnp.zeros(state.v.shape, acc_v),
-                                            sidx,
-                                            theta[:, None, None]
-                                            * upd.astype(acc_v))
-                with jax.named_scope(SCOPE_APPLY):
-                    new_w = (state.w.astype(acc_v)
-                             + acc[:, w_lane] / denom).astype(state.w.dtype)
-                    new_v = (state.v.astype(acc_v)
-                             + acc.at[:, w_lane].set(0.0) / denom[:, None]) \
-                        .astype(state.v.dtype)
-                    new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                        jnp.sum(theta), 1.0)
-            else:
-                with jax.named_scope(SCOPE_APPLY):
-                    pk = scatter_rows_flat(packed, sidx,
-                                           theta[:, None, None] * upd)
-                    new_w = pk[:, w_lane]
-                    new_v = pk.at[:, w_lane].set(0.0)
-                    new_w0 = state.w0 + jnp.sum(theta * dw0)
-        elif mini_batch_average:
+                acc = scatter_rows_flat(jnp.zeros(state.v.shape, acc_v),
+                                        sidx,
+                                        theta[:, None, None]
+                                        * upd.astype(acc_v))
+            with jax.named_scope(SCOPE_APPLY):
+                new_w = (state.w.astype(acc_v)
+                         + acc[:, w_lane] / denom).astype(state.w.dtype)
+                new_v = (state.v.astype(acc_v)
+                         + acc.at[:, w_lane].set(0.0) / denom[:, None]) \
+                    .astype(state.v.dtype)
+                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
+                    jnp.sum(theta), 1.0)
+        else:
             # FloatAccumulator semantics via full-table delta temporaries +
             # one elementwise apply: scattering counts and delta SUMS then
             # dividing table-wide costs ~0.5ms of HBM streaming, vs ~13ms
@@ -479,17 +360,11 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
                     .astype(state.v.dtype)
                 new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
                     jnp.sum(theta), 1.0)
-        else:
-            with jax.named_scope(SCOPE_APPLY):
-                new_w = state.w.at[sidx].add(theta[:, None] * dw, mode="drop")
-                new_v = scatter_v(state.v, theta[:, None, None] * dv)
-                new_w0 = state.w0 + jnp.sum(theta * dw0)
-        if not use_mxu:
-            with jax.named_scope(SCOPE_TOUCHED):
-                touched = state.touched.at[sidx].max(
-                    jnp.broadcast_to((theta > 0).astype(jnp.int8)[:, None],
-                                     sidx.shape),
-                    mode="drop")
+        with jax.named_scope(SCOPE_TOUCHED):
+            touched = state.touched.at[sidx].max(
+                jnp.broadcast_to((theta > 0).astype(jnp.int8)[:, None],
+                                 sidx.shape),
+                mode="drop")
         new_state = state.replace(
             w0=new_w0,
             w=new_w,
@@ -623,9 +498,7 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
     if cl.has("native_scan"):
         return _train_fm_native_scan(cl, hyper, dims, idx_rows, val_rows,
                                      targets, width, block, mode, iters)
-    backend = "mxu" if (cl.has("mxu_scatter") and mode == "minibatch") \
-        else "xla"
-    step = make_fm_step(hyper, mode, update_backend=backend)
+    step = make_fm_step(hyper, mode)
     state = init_state_spanned(init_fm_state, dims, hyper)
     call.set(table_dtype=str(state.v.dtype))
     rng = np.random.RandomState(hyper.seed)

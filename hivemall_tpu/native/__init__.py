@@ -343,8 +343,7 @@ _BATCH_APPLY_REQUIRED_HYPER = {"perceptron": (), "cw": ("phi",),
 def batch_apply_block(rule_name: str, hyper: dict, values: np.ndarray,
                       labels: np.ndarray, main_plan, tail_plan, dims: int,
                       weights: np.ndarray, covars: Optional[np.ndarray],
-                      touched: Optional[np.ndarray],
-                      mini_batch_average: bool = True) -> Optional[float]:
+                      touched: Optional[np.ndarray]) -> Optional[float]:
     """Apply one staged block through hm_batch_apply_block: the whole
     gather -> batch closed form -> segment-reduce -> scatter-back pass in
     one native call, mutating the host-resident f32 tables in place.
@@ -416,7 +415,7 @@ def batch_apply_block(rule_name: str, hyper: dict, values: np.ndarray,
         nb, bsz, slots_u, as_p(mo), as_p(mls), as_p(mrep), as_p(mst),
         as_p(men), tail_rows, tail_u, as_p(to), as_p(tls), as_p(trep),
         as_p(tst), as_p(ten), dims, as_p(weights), as_p(covars),
-        as_p(touched), 1 if mini_batch_average else 0,
+        as_p(touched), 1,  # the frozen ABI's averaging flag: always on
         ctypes.byref(loss))
     if rc != 0:
         raise ValueError("hm_batch_apply_block rejected its arguments "

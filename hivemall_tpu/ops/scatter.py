@@ -1,33 +1,16 @@
-"""Duplicate-free scatter: sort -> segment-reduce -> unique-index scatter.
+"""Sort a block's feature ids, reduce each run of equal ids, write each id
+once: the engine's hot write is `table.at[idx].add(upd)` with HEAVILY
+duplicated indices (hashed CTR ids are zipf-like), which XLA applies one
+update at a time.
 
-The engine's hot write is `table.at[idx].add(upd)` with HEAVILY duplicated
-indices (hashed CTR ids are zipf-like: a 16384x32 block has ~524k update
-lanes over far fewer unique features). XLA lowers a duplicate-index
-scatter-add conservatively (updates must be applied one-at-a-time to
-preserve determinism-agnostic semantics), which on TPU serializes the op;
-one round-4 builder session (v5e, round-4 code) put the fully-synced AROW
-step at ~34 ms — consistent with serial scatter, and ~100x the step's HBM
-traffic bound.
-
-This module turns one duplicated scatter into:
-
-    order = argsort(idx)            # parallel bitonic sort
-    seg   = prefix-sum of boundaries
-    sums  = segment_sum(upd[order]) # parallel tree reduction
-    table.at[rep].add(sums, unique_indices=True, indices_are_sorted=True)
-
-— every stage is data-parallel, and the final scatter's unique+sorted
-promise lets XLA emit the vectorized path. The plan (sort + segments) is
-built ONCE per block and reused by every table the step writes (weights,
-covars, optimizer slots, touched, delta counts), so the sort cost is
-amortized over all of them; per-feature update counts (the reference's
-FloatAccumulator denominator, RegressionBaseUDTF.java:281-295) fall out of
-the same segment reduction for free — replacing the zeros+scatter+gather
-counts pattern of the direct path.
-
-Semantics: identical sums up to float reduction order (a duplicate-index
-scatter-add has no defined application order either); exactness tests pin
-integer counts and tolerance-pin float tables.
+Two forms of the idea live here. `BlockRuns` (`reduce_block_runs`,
+`write_runs`) works on the device in the block's own index space and is the
+`-mini_batch` step's reduction (core/engine.py); `StagedDedupPlan` is built
+on the host at staging time and is what the `-batch` / `-native_apply`
+backends and the frozen C ABI take (core/batch_update.py). Both give
+identical sums up to float reduction order (a duplicate-index scatter-add
+has no defined application order either). `scatter_rows_flat` is the
+FM/FFM row scatter through the flat scalar view.
 """
 
 from __future__ import annotations
@@ -36,93 +19,6 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-
-
-class DedupPlan(NamedTuple):
-    """Reusable sort/segment structure for one block of scatter indices."""
-
-    order: jnp.ndarray  # [N] int32 — permutation sorting the flat indices
-    seg: jnp.ndarray  # [N] int32 — segment id of each sorted element
-    rep: jnp.ndarray  # [N] — ascending slot->feature index; empty slots get
-    # distinct out-of-range values so `mode="drop"` discards them and the
-    # unique/sorted promises stay true
-
-
-def make_dedup_plan(idx_flat: jnp.ndarray, dims: int) -> DedupPlan:
-    """`idx_flat` [N] int32; out-of-range ids (the engine's padding protocol
-    uses idx == dims) sort to the tail and land in dropped slots."""
-    n = idx_flat.shape[0]
-    order = jnp.argsort(idx_flat)
-    si = idx_flat[order]
-    head = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), si[1:] != si[:-1]])
-    seg = jnp.cumsum(head.astype(jnp.int32)) - 1
-    rep = jax.ops.segment_min(si, seg, num_segments=n)
-    # segment_min fills empty segments with the dtype max; replace with
-    # distinct ascending out-of-range ids (keeps `indices_are_sorted` and
-    # `unique_indices` promises honest even among dropped entries)
-    empty = rep >= jnp.asarray(jnp.iinfo(si.dtype).max, si.dtype)
-    rep = jnp.where(empty, dims + jnp.arange(n, dtype=si.dtype), rep)
-    return DedupPlan(order=order, seg=seg, rep=rep)
-
-
-def segment_totals(plan: DedupPlan, upd_flat: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot sums of `upd_flat` ([N] or [N, k]) under the plan."""
-    return jax.ops.segment_sum(upd_flat[plan.order], plan.seg,
-                               num_segments=plan.order.shape[0])
-
-
-def dedup_scatter_add(table: jnp.ndarray, plan: DedupPlan,
-                      upd_flat: jnp.ndarray,
-                      denom: jnp.ndarray | None = None) -> jnp.ndarray:
-    """`table.at[idx].add(upd)` with duplicates pre-reduced; `denom` [N]
-    (per-slot counts) divides the sums first — the mini-batch averaged
-    application."""
-    sums = segment_totals(plan, upd_flat)
-    if denom is not None:
-        d = jnp.maximum(denom, 1.0)
-        sums = sums / (d[:, None] if sums.ndim == 2 else d)
-    return table.at[plan.rep].add(sums.astype(table.dtype), mode="drop",
-                                  unique_indices=True,
-                                  indices_are_sorted=True)
-
-
-def dedup_counts(plan: DedupPlan, fired_flat: jnp.ndarray) -> jnp.ndarray:
-    """Per-slot update counts (float) — the FloatAccumulator denominator."""
-    return segment_totals(plan, fired_flat)
-
-
-def dedup_touch_max(table: jnp.ndarray, plan: DedupPlan,
-                    fired_flat: jnp.ndarray) -> jnp.ndarray:
-    """`touched.at[idx].max(fired)` via the plan (int8 table)."""
-    hits = segment_totals(plan, fired_flat)
-    return table.at[plan.rep].max((hits > 0).astype(table.dtype),
-                                  mode="drop", unique_indices=True,
-                                  indices_are_sorted=True)
-
-
-def dedup_scatter_set_uniform(table: jnp.ndarray, plan: DedupPlan,
-                              val_flat: jnp.ndarray,
-                              keep_flat: jnp.ndarray) -> jnp.ndarray:
-    """`table.at[idx].set(val)` where duplicate lanes of a feature carry the
-    SAME value (the engine's derive_w path: values are a pure function of
-    the post-update slot tables, so duplicates agree — gather-after-scatter
-    determinism). `keep_flat` [N] bool keeps the old table value where no
-    lane fired."""
-    vs = val_flat[plan.order]
-    ks = keep_flat[plan.order].astype(vs.dtype)
-    # all lanes of a slot agree, so max over the segment = the value; lanes
-    # with keep=0 (no update) are excluded by pushing them to -inf
-    neg = jnp.asarray(-jnp.inf, vs.dtype)
-    picked = jax.ops.segment_max(jnp.where(ks > 0, vs, neg), plan.seg,
-                                 num_segments=plan.order.shape[0])
-    # NB: segment_totals permutes its input itself — pass the UNSORTED mask
-    fired = segment_totals(plan, keep_flat.astype(vs.dtype)) > 0
-    old = table.at[plan.rep].get(mode="fill", fill_value=0.0)
-    out = jnp.where(fired, picked, old)
-    return table.at[plan.rep].set(out.astype(table.dtype), mode="drop",
-                                  unique_indices=True,
-                                  indices_are_sorted=True)
 
 
 # --------------------------------------------------------------------------
@@ -219,13 +115,13 @@ def write_runs(table: jnp.ndarray, runs: BlockRuns, values: jnp.ndarray,
 # Staged plans: the sort moved to staging time, the scatter shrunk to the
 # unique slots.
 #
-# The jit-built DedupPlan above still pays two costs that XLA:CPU cannot
+# A plan built inside the jitted step pays two costs that XLA:CPU cannot
 # hide: the argsort runs INSIDE the step (measured 193 ms per 512k-lane
 # block on this host — XLA's comparator sort, vs 50 ms for numpy's radix
-# argsort on the same data), and the final scatter still carries one lane
-# per UPDATE (scatter is the one primitive XLA:CPU executes element-at-a-
-# time, ~15 M elt/s here, while gathers/takes run 400-800 M elt/s). Both
-# are structural, not tuning: the sort is a pure function of the block's
+# argsort on the same data), and a scatter that carries one lane per UPDATE
+# (scatter is the one primitive XLA:CPU executes element-at-a-time, ~15 M
+# elt/s here, while gathers/takes run 400-800 M elt/s). Both are
+# structural, not tuning: the sort is a pure function of the block's
 # feature ids, and the scatter only needs one lane per UNIQUE feature.
 #
 # A StagedDedupPlan therefore moves both out of the hot path:

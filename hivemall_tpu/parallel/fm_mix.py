@@ -36,8 +36,7 @@ from ..runtime.jax_compat import pcast, shard_map
 
 class FMMixTrainer:
     def __init__(self, hyper: FMHyper, dims: int, mesh: Optional[Mesh] = None,
-                 mode: str = "minibatch", config: MixConfig = MixConfig(),
-                 mini_batch_average: bool = True):
+                 mode: str = "minibatch", config: MixConfig = MixConfig()):
         self.hyper = hyper
         self.dims = dims
         self.mesh = mesh if mesh is not None else make_mesh()
@@ -45,11 +44,7 @@ class FMMixTrainer:
         self.config = config
         self.axis = config.axis_name
 
-        # mini_batch_average passes through to the local step (sum/count
-        # averaged application vs raw sums — see make_fm_step), same knob the
-        # sharded trainers expose
-        local_step = make_fm_step(hyper, mode,
-                                  mini_batch_average=mini_batch_average)
+        local_step = make_fm_step(hyper, mode)
         # make_fm_step returns a jitted fn; jitted fns compose fine inside
         # shard_map (they inline at trace time)
 

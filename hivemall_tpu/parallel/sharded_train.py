@@ -151,7 +151,7 @@ class ShardedTrainer:
 
     def __init__(self, rule: Rule, hyper: dict, dims: int,
                  mesh: Optional[Mesh] = None, mode: str = "minibatch",
-                 mini_batch_average: bool = True, dtype=None):
+                 dtype=None):
         self.rule = rule
         self.hyper = hyper
         self.dims = dims
@@ -167,7 +167,6 @@ class ShardedTrainer:
         self.dtype = dtype
 
         body_fn = make_train_fn(rule, hyper, mode=mode,
-                                mini_batch_average=mini_batch_average,
                                 feature_shard=(self.axis, self.stripe))
         state_shape = jax.eval_shape(self._init_one)
         # [D] leaves stripe along the feature dim; scalars replicate
@@ -278,7 +277,7 @@ class FMShardedTrainer:
     Arbitrary dims pad up to stripe * n_devices."""
 
     def __init__(self, hyper, dims: int, mesh: Optional[Mesh] = None,
-                 mode: str = "minibatch", mini_batch_average: bool = True):
+                 mode: str = "minibatch"):
         from ..models.fm import FMHyper, init_fm_state, make_fm_step
 
         assert isinstance(hyper, FMHyper)
@@ -289,7 +288,7 @@ class FMShardedTrainer:
         self.dims_padded = self.stripe * n
         self._init_fn = lambda: init_fm_state(self.dims_padded, hyper)
 
-        body = make_fm_step(hyper, mode, mini_batch_average=mini_batch_average,
+        body = make_fm_step(hyper, mode,
                             feature_shard=(self.axis, self.stripe))
         state_shape = jax.eval_shape(self._init_fn)
         dp = self.dims_padded
@@ -625,8 +624,7 @@ class Sharded2DTrainer:
                  mesh: Optional[Mesh] = None,
                  n_replicas: Optional[int] = None,
                  n_shards: Optional[int] = None,
-                 config: MixConfig = MixConfig(), mode: str = "minibatch",
-                 mini_batch_average: bool = True):
+                 config: MixConfig = MixConfig(), mode: str = "minibatch"):
         self.rule = rule
         self.hyper = hyper
         self.dims = dims
@@ -652,7 +650,6 @@ class Sharded2DTrainer:
         self.reduction = reduction
 
         local_fn = make_train_fn(rule, hyper, mode=mode,
-                                 mini_batch_average=mini_batch_average,
                                  track_deltas=True,
                                  feature_shard=(self.shard_axis, self.stripe))
         mix = make_linear_mix(self.reduction, self.replica_axis)

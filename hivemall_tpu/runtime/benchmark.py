@@ -25,9 +25,10 @@ _PERMS: dict = {}
 
 def make_workload_ids(rng, shape, dims: int):
     """Benchmark feature ids: log-uniform (heavy-tailed) FREQUENCY with
-    hash-UNIFORM placement — the north-star workload shape shared by
-    bench.py, every scripts/bench_*.py, and diag_scan_perf.py (same id
-    distribution as the e2e generator's hashed CTR traffic).
+    hash-UNIFORM placement — the workload shape shared by every
+    scripts/bench_*.py, diag_scan_perf.py and chip_smoke.py (the
+    benchmark's `datagen.py` keeps the distribution without the host
+    permutation).
 
     Two deliberate properties, both measured to matter (round 4):
     - Frequency: zipf(1.3) (rounds 1-3) is TOO head-heavy — 2M draws touch
@@ -38,8 +39,7 @@ def make_workload_ids(rng, shape, dims: int):
       cache lines — a contiguity gift real murmur-hashed features never
       give. A fixed permutation spreads them uniformly, preserving the
       duplicate multiset (same TPU scatter collisions; TPU measured
-      placement-insensitive — diag micro uniform-placed rows in
-      PERF_TPU_r04.jsonl)."""
+      placement-insensitive — docs/perf_history.md, round 4)."""
     import numpy as np
 
     if dims not in _PERMS:
@@ -113,8 +113,8 @@ def measure_reference_rowloops(idx, val, lab, dims: int, k: int = 5,
                                budget_s: float = 2.0) -> dict:
     """Time the C transliterations of the reference's per-row hot loops
     (native hm_arow_reference_rowloop / hm_fm_reference_rowloop) on the
-    given host arrays — the measured vs_baseline anchor denominators shared
-    by bench.py and scripts/bench_ctr_e2e.py. Parse/boxing costs are
+    given host arrays — the measured vs_baseline anchor denominators of
+    scripts/bench_ctr_e2e.py. Parse/boxing costs are
     excluded (flatters the reference). Returns {} when the native library
     is missing or predates the anchor symbols (a probe call returning None
     — never time no-op calls)."""

@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache, placed from outside.
 
-Every entry point that compiles on the device (chip_smoke.py, bench.py,
+Every entry point that compiles on the device (chip_smoke.py,
 scripts/bench_*.py, ModelRegistry, runtime/launch.py) calls
 ``enable_compile_cache()`` before its first jit. A chip machine starts each
 command with no compiled code, and this repo compiles many small programs

@@ -61,8 +61,8 @@ LATENCY_BUCKETS = (0.0002, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
 # (the gathered window promotes to f32 inside the dot product), and int8
 # tables serve through the _q8_* scorers below, which gather the int8 rows,
 # widen ONLY that [B, K] window, and fold the per-block absmax scale into
-# the f32 accumulation — the full table is never dequantized (G019; the
-# per-window cast pattern of ops/mxu_scatter.py).
+# the f32 accumulation — the full table is never dequantized (G019: cast
+# the gathered window, never the table).
 
 
 _QUANT_JIT: dict = {}

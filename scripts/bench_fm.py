@@ -1,5 +1,5 @@
 """FM training throughput at the CTR shape (2^22 dims, k=5, 32 nnz/row),
-HBM-staged blocks — the train_fm counterpart of bench.py's AROW headline.
+HBM-staged blocks, one epoch a dispatch.
 
 Run (real chip): python scripts/bench_fm.py
 Run (CPU):       JAX_PLATFORMS=cpu python scripts/bench_fm.py
@@ -49,35 +49,24 @@ def main() -> None:
     # + on-device epoch replay, mirroring FactorizationMachineUDTF.java:521);
     # timing is chunked + step-counter-verified (runtime/benchmark.py) so
     # enqueued-but-unexecuted work cannot inflate the rate
-    import traceback
+    fn = make_fm_step(hyper, mode="minibatch", jit=False)
+    epoch = make_epoch(lambda s, bi, bv, bl: fn(s, bi, bv, bl, va_d))
+    state = init_fm_state(dims, hyper)
+    state, losses = epoch(state, idx_d, val_d, lab_d)
+    jax.block_until_ready(losses)
 
-    for variant, backend in (("", "xla"), ("mxu_", "mxu")):
-      # fenced per variant: an experimental-backend failure must not kill
-      # the variants measured before it
-      try:
-        fn = make_fm_step(hyper, mode="minibatch", jit=False,
-                          update_backend=backend)
-        epoch = make_epoch(lambda s, bi, bv, bl: fn(s, bi, bv, bl, va_d))
-        state = init_fm_state(dims, hyper)
-        state, losses = epoch(state, idx_d, val_d, lab_d)
-        jax.block_until_ready(losses)
-
-        iters, dt, state = honest_timed_loop(
-            lambda s: epoch(s, idx_d, val_d, lab_d)[0], state,
-            lambda s: float(s.step), budget_s=6.0,
-            expect_probe_delta=n_blocks * batch)
-        rows_per_sec = iters * n_blocks * batch / dt
-        print(json.dumps({
-            "metric": f"fm_train_throughput_2^22dims_k5_{width}nnz_"
-                      f"{variant}device_scan_{platform}",
-            "value": round(rows_per_sec, 1),
-            "unit": "rows/sec",
-            "ms_per_step": round(1e3 * dt / (iters * n_blocks), 3),
-        }), flush=True)
-        del state
-      except Exception:  # noqa: BLE001
-        traceback.print_exc()
-
+    iters, dt, state = honest_timed_loop(
+        lambda s: epoch(s, idx_d, val_d, lab_d)[0], state,
+        lambda s: float(s.step), budget_s=6.0,
+        expect_probe_delta=n_blocks * batch)
+    rows_per_sec = iters * n_blocks * batch / dt
+    print(json.dumps({
+        "metric": f"fm_train_throughput_2^22dims_k5_{width}nnz_"
+                  f"device_scan_{platform}",
+        "value": round(rows_per_sec, 1),
+        "unit": "rows/sec",
+        "ms_per_step": round(1e3 * dt / (iters * n_blocks), 3),
+    }), flush=True)
 
 if __name__ == "__main__":
     from hivemall_tpu.runtime.compile_cache import enable_compile_cache

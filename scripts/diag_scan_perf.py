@@ -15,7 +15,7 @@ Sections:
      vs [k,D], the minibatch-average counts pattern, plus sort cost.
      These give the true TPU cost model for the engine's hot ops.
   B. AROW engine epoch (8/128 blocks, donate/no-donate, jit/AOT).
-  C. FM epoch variants (k, averaged vs raw, w-only vs V-only).
+  C. FM epoch variants (k, w-only vs V-only).
 
 Prints one JSON line per variant. Run:
     python scripts/diag_scan_perf.py [--budget S] [--only PREFIX]
@@ -205,116 +205,10 @@ def main():
           lambda: jnp.zeros((5, DIMS), jnp.float32),
           jax.jit(gath_perk, donate_argnums=(0,)), dup_idx)
 
-    # the dedup path (ops/scatter.py): sort + segment-sum + unique scatter
-    from hivemall_tpu.ops.scatter import (dedup_counts, dedup_scatter_add,
-                                          make_dedup_plan)
-
-    micro("micro_dedup_scatter_dup", t1,
-          jax.jit(lambda v, i, u: dedup_scatter_add(
-              v, make_dedup_plan(i, DIMS), u), donate_argnums=(0,)),
-          dup_idx, upd)
-    micro("micro_dedup_scatter_v5_dup",
-          lambda: jnp.zeros((DIMS, 5), jnp.float32),
-          jax.jit(lambda v, i, u: dedup_scatter_add(
-              v, make_dedup_plan(i, DIMS), u), donate_argnums=(0,)),
-          dup_idx, upd5)
-    micro("micro_dedup_avg_scatter_dup", t1,
-          jax.jit(lambda v, i, u: (lambda p: dedup_scatter_add(
-              v, p, u, denom=dedup_counts(p, jnp.ones_like(u))))(
-                  make_dedup_plan(i, DIMS)), donate_argnums=(0,)),
-          dup_idx, upd)
-
-    # ---- mxu: the sorted-window matmul gather/scatter (ops/mxu_scatter.py)
-    # at the bench workload shape. plan cost is charged inside every variant
-    # (the engine rebuilds it per block); the *_planless pair isolates it.
-    from hivemall_tpu.ops import mxu_scatter as mxs
-
-    bench_idx = None
-    if want("mxu_"):
-        from hivemall_tpu.runtime.benchmark import make_workload_ids
-
-        bench_idx = jnp.asarray(make_workload_ids(rng, (N_UPD,), DIMS))
-
-    def mxu_micro(name, init, f, *fargs, probe=None):
-        if not want(name):
-            return
-        fj = jax.jit(f, donate_argnums=(0,))
-        st = fj(init(), *fargs)
-        jax.block_until_ready(st)
-        iters, secs, st = honest_timed_loop(
-            lambda s: fj(s, *fargs), st,
-            probe or (lambda s: float(jnp.reshape(s, (-1,))[0])),
-            budget_s=args.budget)
-        emit(name, iters, secs, N_UPD, "updates/sec")
-        del st
-
-    if want("mxu_"):
-        mxu_micro("mxu_plan_sort", t1,
-                  lambda v, i: v.at[0].add(
-                      jnp.sum(mxs.make_plan(i, DIMS).sid[:2] *
-                              jnp.float32(1e-9))),
-                  bench_idx)
-        mxu_micro("mxu_gather_pair", lambda: jnp.zeros((DIMS, 2),
-                                                       jnp.float32),
-                  lambda v, i: v.at[0, 0].add(jnp.sum(
-                      mxs.gather(v, mxs.make_plan(i, DIMS)))),
-                  bench_idx)
-        mxu_micro("mxu_scatter_c4", lambda: jnp.zeros((DIMS, 4),
-                                                      jnp.float32),
-                  lambda v, i, u: mxs.scatter_add(
-                      v, i, u, mxs.make_plan(i, DIMS)),
-                  bench_idx, jnp.asarray(rng.randn(N_UPD, 4)
-                                         .astype(np.float32)))
-        mxu_micro("mxu_gather_v8", lambda: jnp.zeros((DIMS, 8),
-                                                     jnp.float32),
-                  lambda v, i: v.at[0, 0].add(jnp.sum(
-                      mxs.gather(v, mxs.make_plan(i, DIMS)))),
-                  bench_idx)
-        mxu_micro("mxu_scatter_v8_kl7", lambda: jnp.zeros((DIMS, 8),
-                                                          jnp.float32),
-                  lambda v, i, u: mxs.scatter_add(
-                      v, i, u, mxs.make_plan(i, DIMS)),
-                  bench_idx, jnp.asarray(rng.randn(N_UPD, 7)
-                                         .astype(np.float32)))
-        # window-size tuning curve: MXU volume scales with W (N*W*128 MACs)
-        # while the residual risk shrinks — capture both ends in the same
-        # run the auto default is judged in
-        for wr in (256, 1024):
-            mxu_micro(f"mxu_gather_pair_w{wr}",
-                      lambda: jnp.zeros((DIMS, 2), jnp.float32),
-                      lambda v, i, wr=wr: v.at[0, 0].add(jnp.sum(
-                          mxs.gather(v, mxs.make_plan(i, DIMS),
-                                     window_rows=wr))),
-                      bench_idx)
-        # precision curve: HIGH = 3-pass bf16 (<= 1-ulp f32), HIGHEST
-        # (the default) = 6-pass exact — prices the exactness premium
-        mxu_micro("mxu_gather_pair_prec_high",
-                  lambda: jnp.zeros((DIMS, 2), jnp.float32),
-                  lambda v, i: v.at[0, 0].add(jnp.sum(
-                      mxs.gather(v, mxs.make_plan(i, DIMS),
-                                 precision="high"))),
-                  bench_idx)
-        mxu_micro("mxu_scatter_c4_prec_high",
-                  lambda: jnp.zeros((DIMS, 4), jnp.float32),
-                  lambda v, i, u: mxs.scatter_add(
-                      v, i, u, mxs.make_plan(i, DIMS), precision="high"),
-                  bench_idx, jnp.asarray(rng.randn(N_UPD, 4)
-                                         .astype(np.float32)))
-        # XLA reference points on the SAME workload ids for direct division
-        mxu_micro("mxu_ref_xla_gather_pair",
-                  lambda: jnp.zeros((DIMS, 2), jnp.float32),
-                  lambda v, i: v.at[0, 0].add(jnp.sum(
-                      v.at[i].get(mode="fill", fill_value=0.0))),
-                  bench_idx)
-        mxu_micro("mxu_ref_xla_scatter_c1", t1,
-                  lambda v, i, u: v.at[i].add(u, mode="drop"),
-                  bench_idx, upd)
-
     # ---------------- B/C. engine epochs ---------------------------------
     def blocks(n):
-        # the headline workload shape (bench.make_ids): log-uniform
-        # frequency, hash-uniform placement — so section B/C epoch numbers
-        # transfer to what bench.py actually times
+        # the shared workload shape (runtime/benchmark.make_workload_ids):
+        # log-uniform frequency, hash-uniform placement
         from hivemall_tpu.runtime.benchmark import make_workload_ids as make_ids
 
         idx = make_ids(rng, (n, BATCH, WIDTH), dims=DIMS)
@@ -358,13 +252,6 @@ def main():
     epoch_bench("arow_scan8_donate", 8, arow_state,
                 lambda s: ep_don(s, idx8, val8, lab8)[0])
 
-    # non-averaged minibatch (raw scatter-add, no counts pattern)
-    fn_noavg = make_train_fn(AROW, {"r": 0.1}, mode="minibatch",
-                             mini_batch_average=False)
-    ep_noavg = make_epoch(fn_noavg)
-    epoch_bench("arow_scan8_noavg", 8, arow_state,
-                lambda s: ep_noavg(s, idx8, val8, lab8)[0])
-
     if want("arow_scan128_donate") or want("arow_scan128_aot_closure"):
         idx128, val128, lab128 = blocks(128)
         epoch_bench("arow_scan128_donate", 128, arow_state,
@@ -378,11 +265,9 @@ def main():
 
     va = jnp.zeros((BATCH,), jnp.float32)
 
-    for tag, k, avg in (("fm_k5_avg", 5, True), ("fm_k5_noavg", 5, False),
-                        ("fm_k4_avg", 4, True)):
+    for tag, k in (("fm_k5_avg", 5), ("fm_k4_avg", 4)):
         hyper = FMHyper(factors=k, classification=True)
-        fm_fn = make_fm_step(hyper, mode="minibatch",
-                             mini_batch_average=avg, jit=False)
+        fm_fn = make_fm_step(hyper, mode="minibatch", jit=False)
         ep = make_epoch(lambda s, bi, bv, bl, _f=fm_fn: _f(s, bi, bv, bl, va))
         epoch_bench(tag, 8, lambda _h=hyper: init_fm_state(DIMS, _h),
                     lambda s, _e=ep: _e(s, idx8, val8, lab8)[0])

@@ -2,7 +2,8 @@
 # Run the test suite on a simulated 8-device CPU mesh.
 #
 # Every gate runs with JAX_PLATFORMS=cpu — tests are CPU-only by design; the
-# chip is reached through the chip tool (chip_smoke.py first, then bench.py).
+# chip is reached through the chip tool (chip_smoke.py first, then
+# benchmark/run.py).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -138,19 +139,8 @@ env JAX_PLATFORMS=cpu \
   python scripts/bench_serving.py --overload --smoke
 gate_time "overload-smoke"
 
-# tier-1 gate 8: batched-backend smoke — the segment-sum batch path
-# (-batch B, core/batch_update.py) must beat the row-serial JAX scan on
-# this host by >= 1.5x AND hold the holdout-logloss parity tolerance at
-# the smoke batch size; the native half additionally requires the
-# -native_apply backend (core/native_batch.py) to beat the XLA batch
-# path >= 1.2x AND the measured C row loop >= 1.0x at the standard
-# 2^22-dim regime with its own logloss parity pin — skipped loudly
-# (reason in the JSON) only when no .so exists and no compiler can
-# build one (docs/execution_backends.md; prints one BENCH-style JSON
-# line)
-env JAX_PLATFORMS=cpu \
-  python bench.py --batch-smoke
-gate_time "batch-smoke"
+# (gate 8, a CPU timing threshold on the -batch backends, left with
+# bench.py; the later gates keep the numbers the docs know them by)
 
 # tier-1 gate 9: continuous-training pipeline smoke — the stream ->
 # freeze -> eval gate -> hot-swap loop must land >= 3 gated publishes

@@ -59,10 +59,9 @@ def test_serve_stage_checks_hold(meter, trained):
 
 
 def test_kernels_stage_checks_hold():
-    rep = chip_smoke.stage_kernels(DIMS, 1 << 10, WIDTH, MINI_BATCH,
+    rep = chip_smoke.stage_kernels(DIMS, 1 << 10, WIDTH,
                                    pallas_interpret=True)
     assert rep["pallas"]["matches_scan"] and rep["pallas"]["interpret"]
-    assert rep["mxu_scatter"]["matches_xla"]
     # at the real smoke width the kernel is refused in words, off-chip too
     from hivemall_tpu.kernels.linear_scan import vmem_resident_reason
     from hivemall_tpu.models.classifier import AROW

@@ -94,36 +94,112 @@ def test_fm_convergence_check_stops_early():
     assert int(model.state.step) < 100 * 300
 
 
-def test_fm_packed_w_lane_exact_vs_split():
-    """The borrowed-lane packing (w rides a V pad lane through one row
-    gather and one flat row scatter) must reproduce the split-table path
-    exactly, with and without mini-batch averaging."""
+def _plain_accumulator_step(st, hyper, idx, val, y, va):
+    """One `-mini_batch` block of FM in numpy float64, the reference's
+    FloatAccumulator rule (RegressionBaseUDTF.java:281-295): every row
+    against the block-start parameters, each parameter's deltas summed and
+    divided by the number of training rows that carry it (w0: by the number
+    of training rows), validation rows (`va` 1) adapting the lambdas only."""
+    dims, k = st.w.shape[0], hyper.factors
+    w0, w = float(st.w0), np.asarray(st.w, np.float64)
+    v = np.asarray(st.v, np.float64)[:, :k]
+    lam_w0, lam_w = float(st.lambda_w0), float(st.lambda_w)
+    lam_v = np.asarray(st.lambda_v, np.float64)[:k]
+    sum_w, sum_v, count = np.zeros(dims), np.zeros((dims, k)), np.zeros(dims)
+    sum_w0 = loss = 0.0
+    touched = np.asarray(st.touched).copy()
+    dl = [0.0, 0.0, np.zeros(k)]
+    for r in range(len(y)):
+        live = idx[r] < dims
+        ids, x = idx[r][live], val[r][live].astype(np.float64)
+        eta = hyper.eta.eta0 / (int(st.step) + 1 + r) ** hyper.eta.power_t
+        wg, vg = w[ids], v[ids]
+        sum_f = (vg * x[:, None]).sum(0)
+        p = w0 + (wg * x).sum() + 0.5 * (
+            sum_f ** 2 - ((vg * x[:, None]) ** 2).sum(0)).sum()
+        if hyper.classification:
+            z = p * y[r]
+            g = (1.0 / (1.0 + np.exp(-z)) - 1.0) * y[r]
+            row_loss = np.logaddexp(0.0, -z)
+        else:
+            g = p - y[r]
+            row_loss = 0.5 * g * g
+        grad_v = x[:, None] * sum_f[None, :] - vg * (x * x)[:, None]
+        dv = -eta * (g * grad_v + 2.0 * lam_v[None, :] * vg)
+        if va[r]:
+            v_dash = vg + dv
+            f_dash = (x[:, None] * v_dash).sum(0)
+            f_dash_f = (x[:, None] ** 2 * v_dash * vg).sum(0)
+            dl[0] += 2.0 * eta * eta * g * w0
+            dl[1] += 2.0 * eta * eta * g * (wg * x).sum()
+            dl[2] += 2.0 * eta * eta * g * (f_dash * sum_f - f_dash_f)
+            continue
+        loss += row_loss
+        sum_w0 += -eta * (g + 2.0 * lam_w0 * w0)
+        np.add.at(sum_w, ids, -eta * (g * x + 2.0 * lam_w * wg))
+        np.add.at(sum_v, ids, dv)
+        np.add.at(count, ids, 1.0)
+        touched[ids] = 1
+    denom = np.maximum(count, 1.0)
+    out = {"w0": w0 + sum_w0 / max(float((1 - va).sum()), 1.0),
+           "w": w + sum_w / denom, "v": v + sum_v / denom[:, None],
+           "touched": touched, "loss": loss,
+           "lambda_w0": lam_w0, "lambda_w": lam_w, "lambda_v": lam_v}
+    if hyper.adareg:
+        out.update(lambda_w0=max(0.0, lam_w0 + dl[0]),
+                   lambda_w=max(0.0, lam_w + dl[1]),
+                   lambda_v=np.maximum(0.0, lam_v + dl[2]))
+    return out
+
+
+@pytest.mark.parametrize("adareg", [False, True], ids=["plain", "adareg"])
+@pytest.mark.parametrize("classification", [True, False],
+                         ids=["classification", "regression"])
+@pytest.mark.parametrize("factors,arm", [(8, "split"), (10, "packed")])
+def test_minibatch_step_equals_plain_accumulator_reference(
+        factors, arm, classification, adareg):
+    """Both arms of the mini-batch step, each reached by its shape (k = 8
+    has no pad lane: w and V are gathered and scattered apart; k = 10 pads
+    to 16 lanes and w rides the first pad lane), against the accumulator
+    written out above: duplicates within and across rows, pad lanes, two
+    blocks in a row so that the second starts from non-zero w and counters."""
     import jax
     import jax.numpy as jnp
 
-    from hivemall_tpu.models.fm import (FMHyper, init_fm_state, make_fm_step)
+    from hivemall_tpu.models.fm import FMHyper, init_fm_state, make_fm_step
 
+    hyper = FMHyper(factors=factors, classification=classification,
+                    adareg=adareg)
+    assert (hyper.padded_factors > hyper.factors) == (arm == "packed")
     rng = np.random.RandomState(11)
-    b, k_nnz, dims = 64, 6, 512
-    idx = jnp.asarray(rng.randint(0, dims, (b, k_nnz)), jnp.int32)
-    val = jnp.asarray(rng.randn(b, k_nnz).astype(np.float32))
-    lab = jnp.asarray(rng.choice([-1.0, 1.0], b).astype(np.float32))
-    va = jnp.zeros((b,), jnp.float32)
-
-    hyper = FMHyper(factors=5, classification=True)
-    # pin the precondition: a pad lane must be free or both sides would
-    # silently run the split path and the comparison would be vacuous
-    assert hyper.padded_factors > hyper.factors
-    for avg in (True, False):
-        split = make_fm_step(hyper, "minibatch", mini_batch_average=avg,
-                             pack_w=False)
-        packed = make_fm_step(hyper, "minibatch", mini_batch_average=avg)
-        s1, l1 = split(init_fm_state(dims, hyper), idx, val, lab, va)
-        s2, l2 = packed(init_fm_state(dims, hyper), idx, val, lab, va)
-        assert float(l1) == pytest.approx(float(l2), rel=1e-6)
-        h1, h2 = jax.device_get(s1), jax.device_get(s2)
-        np.testing.assert_allclose(h2.w, h1.w, rtol=1e-6, atol=1e-8)
-        np.testing.assert_allclose(h2.v, h1.v, rtol=1e-6, atol=1e-8)
-        assert float(h2.w0) == pytest.approx(float(h1.w0), abs=1e-8)
-        # the borrowed lane is provably restored to zero
-        assert (h2.v[:, hyper.factors:] == 0).all()
+    b, lanes, dims = 48, 6, 512
+    step = make_fm_step(hyper, "minibatch")
+    state = init_fm_state(dims, hyper)
+    for _ in range(2):
+        idx = rng.choice(40, size=(b, lanes)).astype(np.int32) * 11
+        idx[rng.rand(b, lanes) < 0.15] = dims
+        val = rng.randn(b, lanes).astype(np.float32)
+        val[idx == dims] = 0.0
+        y = rng.choice([-1.0, 1.0], b).astype(np.float32) if classification \
+            else rng.randn(b).astype(np.float32)
+        va = (rng.rand(b) < 0.25).astype(np.float32) if adareg \
+            else np.zeros(b, np.float32)
+        before = jax.device_get(state)
+        want = _plain_accumulator_step(before, hyper, idx, val, y, va)
+        state, loss = step(state, jnp.asarray(idx), jnp.asarray(val),
+                           jnp.asarray(y), jnp.asarray(va))
+        got = jax.device_get(state)
+        assert int(got.step) == int(before.step) + b
+        assert float(loss) == pytest.approx(want["loss"], rel=1e-5)
+        assert float(got.w0) == pytest.approx(want["w0"], rel=1e-5, abs=1e-7)
+        np.testing.assert_allclose(got.w, want["w"], rtol=1e-5, atol=1e-7)
+        np.testing.assert_allclose(got.v[:, :factors], want["v"], rtol=1e-5,
+                                   atol=1e-7)
+        # pad lanes, the borrowed one too, are zero again after the block
+        assert (got.v[:, factors:] == 0).all()
+        np.testing.assert_array_equal(got.touched, want["touched"])
+        for name in ("lambda_w0", "lambda_w"):
+            assert float(getattr(got, name)) == pytest.approx(
+                want[name], rel=1e-5, abs=1e-9)
+        np.testing.assert_allclose(got.lambda_v[:factors], want["lambda_v"],
+                                   rtol=1e-5, atol=1e-9)

@@ -33,6 +33,9 @@ CASES = {
     "arow_f32": (C.AROW, {"r": 0.1}, jnp.float32, False, True),
     "arow_bf16": (C.AROW, {"r": 0.1}, jnp.bfloat16, False, True),
     "scw1": (C.SCW1, {"phi": 1.0, "c": 1.0}, jnp.float32, False, True),
+    "scw1_track_deltas": (C.SCW1, {"phi": 1.0, "c": 1.0}, jnp.float32, True,
+                          True),
+    "pa1": (C.PA1, {"c": 1.0}, jnp.float32, False, True),
     "pa1_regr": (R.PA1_REGR, {"c": 1.0, "epsilon": 0.01}, jnp.float32, False,
                  False),
     "adagrad_regr": (R.ADAGRAD_REGR, {"eta": 1.0, "eps": 1.0, "scale": 100.0},
@@ -40,6 +43,9 @@ CASES = {
     "adagrad_rda": (C.ADAGRAD_RDA,
                     {"eta": 0.1, "lambda": 1e-6, "scale": 100.0},
                     jnp.float32, False, True),
+    "adagrad_rda_track_deltas": (C.ADAGRAD_RDA,
+                                 {"eta": 0.1, "lambda": 1e-6, "scale": 100.0},
+                                 jnp.float32, True, True),
     "pa1a_regr_globals": (R.PA1A_REGR, {"c": 1.0, "epsilon": 0.01},
                           jnp.float32, False, False),
     "arow_track_deltas": (C.AROW, {"r": 0.1}, jnp.float32, True, True),
@@ -329,8 +335,12 @@ def test_strategy_is_a_function_of_shapes(dims, lanes, want):
     assert apply_strategy(dims, lanes) == want
 
 
-@pytest.mark.parametrize("case", ["arow_f32", "arow_bf16", "pa1_regr",
-                                  "arow_track_deltas"])
+@pytest.mark.parametrize("case", [
+    "arow_f32", "arow_bf16", "pa1_regr", "arow_track_deltas",
+    # covariance + hyper, a plain classifier, derived weights with and
+    # without the delta clock, running label statistics (`pre_batch`)
+    "scw1", "scw1_track_deltas", "pa1", "adagrad_rda",
+    "adagrad_rda_track_deltas", "pa1a_regr_globals"])
 def test_the_two_strategies_agree_on_the_same_rows(case):
     """The same rows once as they are and once with as many pad lanes again
     as make the block too wide for the table: the dense strategy runs on

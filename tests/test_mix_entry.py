@@ -270,8 +270,6 @@ REFUSALS = [
     ("train_arow", "-dims 64 -mix h -batch 16", "does not compose with -batch"),
     ("train_arow", "-dims 64 -mix h -native_scan",
      "does not compose with -native_scan"),
-    ("train_arow", "-dims 64 -mix h -mini_batch 8 -mxu_scatter",
-     "does not compose with -mxu_scatter"),
     ("train_arow", "-dims 64 -mix h -mini_batch 8 -mix_threshold 0",
      "-mix_threshold in 1..127"),
     ("train_arow", "-dims 64 -mix h -mini_batch 8 -mix_threshold 128",

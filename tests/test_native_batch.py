@@ -10,8 +10,8 @@
   multi-chunk blocks and warm starts;
 - the refusal/fallback matrix: unsupported rule and missing .so fall
   back LOUDLY (warning naming the reason) to the XLA batch path;
-  -native_apply without -batch and the -mxu_scatter combo refuse with
-  ValueError; a present-but-unloadable .so is reported, never swallowed.
+  -native_apply without -batch refuses with ValueError; a
+  present-but-unloadable .so is reported, never swallowed.
 """
 
 import warnings
@@ -211,18 +211,17 @@ def _rows(n=24, d=64, seed=4):
     return idx_rows, val_rows, labels
 
 
-def test_native_apply_refuses_without_batch_and_with_mxu():
+def test_native_apply_refuses_without_batch():
     idx_rows, val_rows, labels = _rows()
     for bad in ("-native_apply",
                 "-native_apply -mini_batch 4",
-                "-native_apply -mxu_scatter -mini_batch 4",
                 "-native_apply -native_scan"):
         with pytest.raises(ValueError, match="rides the -batch backend"):
             C.train_arow((idx_rows, val_rows), labels, f"-dims 64 {bad}")
-    # with -batch, the existing backend-exclusivity refusal covers mxu
+    # with -batch, the backend-exclusivity refusal covers the other flags
     with pytest.raises(ValueError, match="does not compose"):
         C.train_arow((idx_rows, val_rows), labels,
-                     "-dims 64 -batch 8 -native_apply -mxu_scatter")
+                     "-dims 64 -batch 8 -native_apply -pallas")
 
 
 def test_unsupported_rule_falls_back_loudly():

@@ -72,3 +72,18 @@ def test_list_functions_size():
 
 def test_version_function():
     assert "tpu" in get_function("hivemall_version")()
+
+
+@pytest.mark.parametrize("entry,rows", [
+    ("train_arow", [["1:1.0", "2:0.5"]]),
+    ("train_fm", [["1:1.0", "2:0.5"]]),
+    ("train_ffm", [["1:1:1.0", "2:2:0.5"]]),
+])
+def test_removed_backend_flag_is_refused_as_unknown(entry, rows):
+    """`-mxu_scatter` left with its backend (docs/migration.md): a call that
+    still carries it is refused like any other unknown option, in words,
+    before a row is staged."""
+    from hivemall_tpu.utils.options import OptionError
+
+    with pytest.raises(OptionError, match="unknown option '-mxu_scatter'"):
+        get_function(entry)(rows, [1.0], "-mini_batch 4 -mxu_scatter")
