@@ -202,8 +202,8 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
             else:
                 # v+gg interleaved [Dv, k+1]: ONE [K,K]-row gather yields
                 # both — the separate scalar gg gather (K^2 scalars/row)
-                # rides the V row gather for free (same borrowed-lane
-                # pattern as FM; v5e cost model in docs/perf_history.md round 4c)
+                # rides the V row gather for free (a borrowed lane; v5e
+                # cost model in docs/perf_history.md round 4c)
                 keys = _row_pair_keys(idx, fields, hyper.v_dims)
                 pg = packed[keys]  # [K, K, k+1]
                 Vg, gg = pg[..., :-1], pg[..., -1]

@@ -35,13 +35,13 @@ from ..constants import DEFAULT_NUM_FEATURES
 from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
 from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
-from ..ops.scatter import scatter_rows_flat
 from ..ops.eta import EtaEstimator, get_eta
+from ..ops.scatter import reduce_block_runs, scatter_rows_flat, write_runs
 from ..runtime.metrics import REGISTRY
 from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
-                               SCOPE_PACK_TABLES, SCOPE_REDUCE, SCOPE_RULE,
-                               SCOPE_TOUCHED, SPAN_CALL, SPAN_EMIT, SPAN_EPOCH,
-                               SPAN_SYNC, TRACER)
+                               SCOPE_REDUCE, SCOPE_RULE, SCOPE_TOUCHED,
+                               SPAN_CALL, SPAN_EMIT, SPAN_EPOCH, SPAN_SYNC,
+                               TRACER)
 from ..utils.options import Options
 from .base import (FeatureRows, _stage_rows, base_options, dispatch_step,
                    init_state_spanned, prepared_blocks, stage_training_rows)
@@ -81,18 +81,18 @@ class FMHyper:
     @property
     def padded_factors(self) -> int:
         """Physical lane count of the V table: k rounded up to a multiple
-        of 8 when k > 4 (TPU f32 sublane granularity). Hardware note: the
-        round-4b hypothesis that lane alignment rescues the [N,k]-ROW
-        scatter was refuted on v5e (diag micro2: v8pad row scatter 69ms ==
-        v5 row scatter 71ms per 512k rows) — the V update now scatters
-        scalars into the flat [D*kp] view instead (ops/scatter.
-        scatter_rows_flat, ~2x the row form on unaligned tables), touching
-        only the logical k lanes. Padding is kept for tile-aligned
-        storage/gather at zero measured cost (row gather 28.5M/s == padded
-        28.2M/s). Pad lanes init to 0 and provably stay 0 (their grad
-        terms are products with their own zero V entries and their
-        lambda_v is 0), so every k-width result is bit-identical;
-        model_rows / codecs slice back to the logical k."""
+        of 8 when k > 4 (TPU f32 sublane granularity), for tile-aligned
+        storage and row gathers at zero measured cost (r4: row gather
+        28.5M/s == padded 28.2M/s). Pad lanes init to 0 and stay 0: their
+        grad terms are products with their own zero V entries, their
+        lambda_v is 0, and the mini-batch step writes a row's pad lanes as
+        the zeros they are; model_rows / codecs slice back to the logical k.
+        What the padding costs the step on a v5e, as the chip has V
+        (`{0,1:T(8,128)}`, a row's lanes 32 MiB apart at 2^23 dims): the
+        sorted in-place row scatter of a [1024, 64] block's 65,536 rows
+        writes all 16 lanes, 6.2 ms of an 11.7 ms step at k = 10 (PERF.md
+        section 6, PR 31); whether 10 lanes would cost ten sixteenths of
+        that has not been measured."""
         k = self.factors
         if k > 4 and k % 8:
             return k + (8 - k % 8)
@@ -184,6 +184,16 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     per-row-only, so averaging is the documented bridge semantic, same as
     core/engine.py's minibatch mode).
 
+    The mini-batch step applies a block in the block's own index space
+    (`apply_block`, as core/engine.py's `batch_local` strategy does): what
+    it costs follows rows x lanes x factors, not the table. On a v5e with a
+    [1024, 64] block and k = 10 in 16 lanes it takes 11.5 ms at 2^23 dims,
+    5.9 at 2^20, 3.0 at 2^16, where the step it replaced (zeroed [D,16]
+    delta tables through the flat view, w riding a pad lane of a packed
+    copy of V, one divide-add pass over the table) took 113, 19.0 and 7.5;
+    no table is short enough for that plan to win, so there is one (PERF.md
+    section 6, PR 31).
+
     `feature_shard=(axis_name, stripe)` runs the same step on a [D/stripe]
     model stripe inside shard_map — the FM analog of the engine's
     feature-sharded training (the V table is the framework's largest model
@@ -192,48 +202,33 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
     (linear term, sumVfX[k], sumV2X2[k]) psum over the stripe axis, and the
     lane updates — functions of (global g, global sumVfX, lane-local w/V) —
     scatter into the local stripe only. Exact up to psum order. adareg is
-    not supported sharded (its lambda updates need cross-stripe v' sums)."""
+    not supported sharded (its lambda updates need cross-stripe v' sums).
+    A stripe keeps the dense apply (`apply_stripe`: zeroed delta tables and
+    one pass over each): its prediction needs a psum a row, no benchmark
+    cell runs it, and it has not been measured against the block-local one."""
     if feature_shard is not None and hyper.adareg:
         raise ValueError("adareg is not supported with feature_shard")
 
-    # Borrowed-lane packing (minibatch local path): when V is lane-padded
-    # (kp > k), the first pad lane carries w for the block — ONE [K,kp]
-    # row gather replaces the separate w gather, and dw rides the same
-    # flat row scatter as dv (one ~0.1ms full-table lane write each way
-    # vs a ~13ms gather + ~7ms scatter saved per 512k-update block on
-    # v5e). The pad-lane-zero invariant holds on the canonical state: the
-    # lane is zeroed again at unpack.
-    w_lane = hyper.factors
-    # packing requires a free pad lane (kp > k) and the local (unsharded)
-    # path; without either the step runs split
-    use_packed = (feature_shard is None
-                  and hyper.padded_factors > hyper.factors)
-
     if feature_shard is None:
-        def gather_and_predict(state: FMState, idx, val, packed=None):
-            if packed is not None:
-                pg = packed.at[idx].get(mode="fill", fill_value=0.0)
-                wg = pg[:, w_lane]
-                vg = pg.at[:, w_lane].set(0.0)  # restore the pad-lane zero
-            else:
-                wg = state.w.at[idx].get(mode="fill", fill_value=0.0)
-                vg = state.v.at[idx].get(mode="fill", fill_value=0.0)
+        def gather_and_predict(state: FMState, idx, val):
+            wg = state.w.at[idx].get(mode="fill", fill_value=0.0)
+            vg = state.v.at[idx].get(mode="fill", fill_value=0.0)
             p, sum_vfx = _row_predict(state.w0, wg, vg, val)
             return wg, vg, val, idx, p, sum_vfx
     else:
         shard_axis, stripe = feature_shard
 
-        def gather_and_predict(state: FMState, idx, val, packed=None):
+        def gather_and_predict(state: FMState, idx, val):
             wg, vg, vmask, lidx, p, sum_vfx = sharded_gather_predict(
                 state.w, state.v, state.w0, idx, val, shard_axis, stripe)
             return wg, vg, vmask, lidx, p, sum_vfx
 
-    def row_deltas(state: FMState, idx, val, y, t, packed=None):
+    def row_deltas(state: FMState, idx, val, y, t):
         with jax.named_scope(SCOPE_RULE):
             eta = hyper.eta.eta(t)
         with jax.named_scope(SCOPE_GATHER):
             wg, vg, eff_val, sidx, p, sum_vfx = gather_and_predict(
-                state, idx, val, packed)
+                state, idx, val)
         with jax.named_scope(SCOPE_LOSS):
             g, loss = _dloss_and_loss(p, y, hyper)
         with jax.named_scope(SCOPE_RULE):
@@ -286,85 +281,106 @@ def make_fm_step(hyper: FMHyper, mode: str = "minibatch",
         state, losses = jax.lax.scan(body, state, (indices, values, labels, va_mask))
         return state, jnp.sum(losses)
 
-    def minibatch_step(state: FMState, indices, values, labels, va_mask):
-        b = indices.shape[0]
-        ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
-        with jax.named_scope(SCOPE_PACK_TABLES):
-            packed = (state.v.at[:, w_lane].set(state.w) if use_packed
-                      else None)
+    def averaged_w0(state: FMState, theta, dw0):
+        return state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
+            jnp.sum(theta), 1.0)
 
-        def per_row(idx, val, y, t):
-            return row_deltas(state, idx, val, y, t, packed)
-
-        dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx = \
-            jax.vmap(per_row)(indices, values, labels, ts)
-        theta = (1.0 - va_mask)  # [B]
-
-        def scatter_v(v_table, upd):
-            # Flat-scalar V scatter (ops/scatter.scatter_rows_flat — ~2x the
-            # [B,K]-row form on v5e). Only the logical k lanes carry nonzero
-            # grads (pad-lane grads are products with their own zero V
-            # entries), so scatter those and pad lanes stay provably zero.
-            return scatter_rows_flat(v_table, sidx, upd[..., : hyper.factors])
-
+    def apply_block(state: FMState, sidx, theta, dw0, dw, dv, wg, vg):
+        """(w0, w, V, touched) after the block, written in the block's own
+        index space as the linear step writes (core/engine.py): the lanes
+        sorted by feature id, with count, dw and dv's k logical columns
+        summed over each run of equal ids and the gathered old w and V row
+        riding the sort as payload (11 more sorts: 2 ms a step cheaper on a
+        v5e than gathering both again at the sorted ids); every lane of a
+        run then writes `old + sum / max(count, 1)` at its sorted id, in
+        place. V is written as whole rows, pad lanes as the zeros they are:
+        the flat `[D * kp]` view is a relayout of the whole table on the
+        chip, and a flat lane id is not ascending across a repeated
+        feature's lanes. Nothing but the three writes is as long as the
+        table."""
+        k = hyper.factors
         # accumulate in f32 even if the tables ever go compact (same
         # store-compact/accumulate-wide policy as core/engine.py)
+        acc = jnp.promote_types(state.v.dtype, jnp.float32)
+        flat = lambda a: a.reshape(-1).astype(acc)
+        cols = lambda a: [flat(a[..., j]) for j in range(k)]
+        lane_theta = jnp.broadcast_to(theta[:, None], sidx.shape)
+        with jax.named_scope(SCOPE_REDUCE):
+            runs = reduce_block_runs(
+                sidx.reshape(-1), state.w.shape[0],
+                {"count": flat(lane_theta), "w": flat(lane_theta * dw),
+                 "v": cols(theta[:, None, None] * dv)},
+                {"w": flat(wg), "v": cols(vg)})
+        with jax.named_scope(SCOPE_APPLY):
+            count = runs.sums["count"]
+            denom = jnp.maximum(count, 1.0)
+            new_w = write_runs(state.w, runs,
+                               runs.carried["w"] + runs.sums["w"] / denom)
+            v_rows = jnp.stack(
+                [old + total / denom for old, total
+                 in zip(runs.carried["v"], runs.sums["v"])], axis=-1)
+            new_v = write_runs(state.v, runs, jnp.pad(
+                v_rows, ((0, 0), (0, state.v.shape[1] - k))))
+            new_w0 = averaged_w0(state, theta, dw0)
+        with jax.named_scope(SCOPE_TOUCHED):
+            touched = write_runs(state.touched, runs, count > 0, "max")
+        return new_w0, new_w, new_v, touched
+
+    def apply_stripe(state: FMState, sidx, theta, dw0, dw, dv):
+        """The same (w0, w, V, touched) on a `feature_shard` stripe: delta
+        sums and counts scattered into zeroed tables, one divide-add pass
+        over each table."""
+        # accumulate in f32 even if the tables ever go compact
         acc_w = jnp.promote_types(state.w.dtype, jnp.float32)
         acc_v = jnp.promote_types(state.v.dtype, jnp.float32)
-        # FloatAccumulator denominators (shared by the packed and split
-        # apply below): per-feature touch counts, w0 by the effective batch
-        # size
+        # FloatAccumulator denominators: per-feature touch counts, w0 by
+        # the effective batch size
         with jax.named_scope(SCOPE_REDUCE):
             counts = jnp.zeros((state.w.shape[0],), jnp.float32) \
                 .at[sidx].add(jnp.broadcast_to(theta[:, None], sidx.shape),
                               mode="drop")
         with jax.named_scope(SCOPE_APPLY):
             denom = jnp.maximum(counts, 1.0)
-
-        if use_packed:
-            # dw rides lane w_lane of the same flat row scatter as dv
-            k_log = hyper.factors
-            with jax.named_scope(SCOPE_REDUCE):
-                upd = jnp.concatenate([dv[..., :k_log], dw[..., None]],
-                                      axis=-1)
-                acc = scatter_rows_flat(jnp.zeros(state.v.shape, acc_v),
-                                        sidx,
-                                        theta[:, None, None]
-                                        * upd.astype(acc_v))
-            with jax.named_scope(SCOPE_APPLY):
-                new_w = (state.w.astype(acc_v)
-                         + acc[:, w_lane] / denom).astype(state.w.dtype)
-                new_v = (state.v.astype(acc_v)
-                         + acc.at[:, w_lane].set(0.0) / denom[:, None]) \
-                    .astype(state.v.dtype)
-                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                    jnp.sum(theta), 1.0)
-        else:
-            # FloatAccumulator semantics via full-table delta temporaries +
-            # one elementwise apply: scattering counts and delta SUMS then
-            # dividing table-wide costs ~0.5ms of HBM streaming, vs ~13ms
-            # for the per-lane denominator GATHER the pre-divided variant
-            # needs (diag micro gather rate on v5e) — same math, the
-            # denominators just divide at the table instead of the lanes.
-            with jax.named_scope(SCOPE_REDUCE):
-                dw_sum = jnp.zeros(state.w.shape, acc_w).at[sidx].add(
-                    theta[:, None] * dw.astype(acc_w), mode="drop")
-            with jax.named_scope(SCOPE_APPLY):
-                new_w = (state.w.astype(acc_w) + dw_sum / denom) \
-                    .astype(state.w.dtype)
-            with jax.named_scope(SCOPE_REDUCE):
-                dv_sum = scatter_v(jnp.zeros(state.v.shape, acc_v),
-                                   theta[:, None, None] * dv.astype(acc_v))
-            with jax.named_scope(SCOPE_APPLY):
-                new_v = (state.v.astype(acc_v) + dv_sum / denom[:, None]) \
-                    .astype(state.v.dtype)
-                new_w0 = state.w0 + jnp.sum(theta * dw0) / jnp.maximum(
-                    jnp.sum(theta), 1.0)
+        with jax.named_scope(SCOPE_REDUCE):
+            dw_sum = jnp.zeros(state.w.shape, acc_w).at[sidx].add(
+                theta[:, None] * dw.astype(acc_w), mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            new_w = (state.w.astype(acc_w) + dw_sum / denom) \
+                .astype(state.w.dtype)
+        with jax.named_scope(SCOPE_REDUCE):
+            # Only the logical k lanes carry nonzero grads (pad-lane grads
+            # are products with their own zero V entries), so scatter those
+            # and pad lanes stay provably zero.
+            dv_sum = scatter_rows_flat(
+                jnp.zeros(state.v.shape, acc_v), sidx,
+                (theta[:, None, None] * dv.astype(acc_v))[..., :hyper.factors])
+        with jax.named_scope(SCOPE_APPLY):
+            new_v = (state.v.astype(acc_v) + dv_sum / denom[:, None]) \
+                .astype(state.v.dtype)
+            new_w0 = averaged_w0(state, theta, dw0)
         with jax.named_scope(SCOPE_TOUCHED):
             touched = state.touched.at[sidx].max(
                 jnp.broadcast_to((theta > 0).astype(jnp.int8)[:, None],
                                  sidx.shape),
                 mode="drop")
+        return new_w0, new_w, new_v, touched
+
+    def minibatch_step(state: FMState, indices, values, labels, va_mask):
+        b = indices.shape[0]
+        ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
+
+        def per_row(idx, val, y, t):
+            return row_deltas(state, idx, val, y, t)
+
+        dw0, dw, dv, loss, g, p, sum_vfx, wg, vg, eta, sidx = \
+            jax.vmap(per_row)(indices, values, labels, ts)
+        theta = (1.0 - va_mask)  # [B]
+        if feature_shard is None:
+            new_w0, new_w, new_v, touched = apply_block(
+                state, sidx, theta, dw0, dw, dv, wg, vg)
+        else:
+            new_w0, new_w, new_v, touched = apply_stripe(
+                state, sidx, theta, dw0, dw, dv)
         new_state = state.replace(
             w0=new_w0,
             w=new_w,
@@ -495,6 +511,9 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
     block = mini_batch if mode == "minibatch" else cl.get_int("block_size", 4096)
     iters = cl.get_int("iters", 1)
     call.set(dims=dims, rows=n, mini_batch=mini_batch, mode=mode)
+    if mode == "minibatch":
+        # the one plan `make_fm_step` has off a `feature_shard` stripe
+        call.set(apply="batch_local")
     if cl.has("native_scan"):
         return _train_fm_native_scan(cl, hyper, dims, idx_rows, val_rows,
                                      targets, width, block, mode, iters)

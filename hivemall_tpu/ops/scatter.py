@@ -5,12 +5,13 @@ update at a time.
 
 Two forms of the idea live here. `BlockRuns` (`reduce_block_runs`,
 `write_runs`) works on the device in the block's own index space and is the
-`-mini_batch` step's reduction (core/engine.py); `StagedDedupPlan` is built
-on the host at staging time and is what the `-batch` / `-native_apply`
-backends and the frozen C ABI take (core/batch_update.py). Both give
-identical sums up to float reduction order (a duplicate-index scatter-add
-has no defined application order either). `scatter_rows_flat` is the
-FM/FFM row scatter through the flat scalar view.
+`-mini_batch` step's reduction (core/engine.py, and models/fm.py since
+PR 31); `StagedDedupPlan` is built on the host at staging time and is what
+the `-batch` / `-native_apply` backends and the frozen C ABI take
+(core/batch_update.py). Both give identical sums up to float reduction
+order (a duplicate-index scatter-add has no defined application order
+either). `scatter_rows_flat` is the row scatter-add through the flat scalar
+view that FFM and FM's `feature_shard` stripes keep.
 """
 
 from __future__ import annotations
@@ -104,7 +105,9 @@ def reduce_block_runs(idx_flat: jnp.ndarray, dims: int, summed,
 def write_runs(table: jnp.ndarray, runs: BlockRuns, values: jnp.ndarray,
                op: str = "set") -> jnp.ndarray:
     """`table[id] = values` (or `max(table[id], values)`) at the block's
-    ids, in place. `values` [N] must be equal on all lanes of one id, as
+    ids, in place. `values`, [N] for a `[D]` table or [N, k] rows for a
+    `[D, k]` one (FM's V: one sorted row scatter, 6.2 ms per 65,536 rows of
+    16 lanes into 2^23 on a v5e), must be equal on all lanes of one id, as
     anything computed from `runs.sums` and `runs.carried` is."""
     at = table.at[runs.ids]
     return getattr(at, op)(values.astype(table.dtype), mode="drop",
@@ -355,15 +358,21 @@ def staged_touch_max(table: jnp.ndarray, plan: StagedDedupPlan,
 def scatter_rows_flat(table: jnp.ndarray, keys: jnp.ndarray,
                       upd: jnp.ndarray,
                       _flat_limit: int = 2**31) -> jnp.ndarray:
-    """Row scatter-add via the flat scalar view.
+    """Row scatter-add via the flat scalar view, for UNSORTED keys with
+    duplicates into a table the caller has just zeroed (FFM's step, FM's
+    `feature_shard` stripe).
 
-    A [N,k]-row scatter into [E,k] measured ~2x slower on v5e than the same
-    updates scattered as scalars into the flat [E*k] view (diag micro2
-    scatter_v5_flat 36.9ms vs scatter_v5_rows 71.2ms per 512k rows; 8-lane
-    padding does NOT rescue the row form — v8pad 69.1ms). `upd`'s last dim
-    may carry fewer lanes than the table (k_logical <= k, e.g. FM's padded
-    V): only those lanes are scattered, so pad lanes stay untouched. Drop
-    semantics are preserved: pad keys (>= E) flatten to >= E*k.
+    r4's micro on a v5e read such a scatter at 36.9 ms per 512k rows
+    through the flat [E*k] view against 71.2 ms as [N,k] rows (8-lane
+    padding did not rescue the row form: 69.1 ms). What it left out is the
+    view itself: on the chip `table.reshape(-1)` of a `[2^23, 16]` f32
+    table is a relayout of the whole table each way (three of the ten
+    longest ops of FM's old 113 ms step), so the flat form is for short
+    tables. FM's unsharded step left it in PR 31 for a sorted in-place row
+    scatter (`write_runs`). `upd`'s last dim may carry fewer lanes than the
+    table (k_logical <= k, e.g. FM's padded V): only those lanes are
+    scattered, so pad lanes stay untouched. Drop semantics are preserved:
+    pad keys (>= E) flatten to >= E*k.
 
     Falls back to the row form when E*k would overflow the int32 flat-index
     space (the flat product wraps negative and mode="drop" would silently

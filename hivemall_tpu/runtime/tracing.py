@@ -113,7 +113,8 @@ SCOPE_MIX_APPLY = "apply"             # hm.mix/apply: the mixed values' write
 TRAINER_MIX = "mix_dp"
 LINEAR_SCOPES = (SCOPE_PACK_TABLES, SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE,
                  SCOPE_APPLY, SCOPE_TOUCHED)
-FM_SCOPES = LINEAR_SCOPES + (SCOPE_LOSS,)
+FM_SCOPES = (SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE, SCOPE_APPLY,
+             SCOPE_TOUCHED, SCOPE_LOSS)   # FM packs nothing since PR 31
 
 _ID_COUNTER = itertools.count(1)  # __next__ is GIL-atomic: no lock needed
 

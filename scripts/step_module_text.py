@@ -89,15 +89,15 @@ def _variants():
             rule, hyper, mode="scan", track_deltas=True)).lower(
                 lin_state(rule, 1 << 20, jnp.float32, True), *block(256, 64))
 
-    # FM: the cell's packed step (k=10 -> 16 lanes), the split step (k=8),
-    # the scan, with regression and adareg
+    # FM: the cell's step (k=10 in 16 lanes), k=8 (no pad lane), the scan,
+    # with regression and adareg
     va = S((1024,), jnp.float32)
-    for k, arm in ((10, "packed"), (8, "split")):
+    for k in (10, 8):
         for cls in (True, False):
             for adareg in (False, True):
                 h = FMHyper(factors=k, classification=cls, adareg=adareg)
                 st = jax.eval_shape(lambda: init_fm_state(1 << 23, h))
-                tag = f"k{k}_{arm}.{'c' if cls else 'r'}" \
+                tag = f"k{k}.{'c' if cls else 'r'}" \
                     f"{'.adareg' if adareg else ''}"
                 out[f"fm.minibatch.{tag}"] = make_fm_step(
                     h, "minibatch").lower(st, *block(1024, 64), va)

@@ -110,8 +110,11 @@ def _plain_accumulator_step(st, hyper, idx, val, y, va):
     touched = np.asarray(st.touched).copy()
     dl = [0.0, 0.0, np.zeros(k)]
     for r in range(len(y)):
-        live = idx[r] < dims
-        ids, x = idx[r][live], val[r][live].astype(np.float64)
+        # as `.at[]` reads an id: a negative one counts from the table's
+        # end, and what is still outside the table is dropped
+        wrapped = np.where(idx[r] < 0, idx[r] + dims, idx[r])
+        live = (wrapped >= 0) & (wrapped < dims)
+        ids, x = wrapped[live], val[r][live].astype(np.float64)
         eta = hyper.eta.eta0 / (int(st.step) + 1 + r) ** hyper.eta.power_t
         wg, vg = w[ids], v[ids]
         sum_f = (vg * x[:, None]).sum(0)
@@ -155,14 +158,14 @@ def _plain_accumulator_step(st, hyper, idx, val, y, va):
 @pytest.mark.parametrize("adareg", [False, True], ids=["plain", "adareg"])
 @pytest.mark.parametrize("classification", [True, False],
                          ids=["classification", "regression"])
-@pytest.mark.parametrize("factors,arm", [(8, "split"), (10, "packed")])
+@pytest.mark.parametrize("factors,lanes_of_v", [(4, 4), (5, 8), (8, 8),
+                                                (10, 16)])
 def test_minibatch_step_equals_plain_accumulator_reference(
-        factors, arm, classification, adareg):
-    """Both arms of the mini-batch step, each reached by its shape (k = 8
-    has no pad lane: w and V are gathered and scattered apart; k = 10 pads
-    to 16 lanes and w rides the first pad lane), against the accumulator
-    written out above: duplicates within and across rows, pad lanes, two
-    blocks in a row so that the second starts from non-zero w and counters."""
+        factors, lanes_of_v, classification, adareg):
+    """The mini-batch step with V unpadded (k = 4, 8) and padded to the
+    next multiple of 8 lanes (k = 5, 10), against the accumulator written
+    out above: duplicates within and across rows, pad lanes, two blocks in
+    a row so that the second starts from non-zero w and counters."""
     import jax
     import jax.numpy as jnp
 
@@ -170,7 +173,7 @@ def test_minibatch_step_equals_plain_accumulator_reference(
 
     hyper = FMHyper(factors=factors, classification=classification,
                     adareg=adareg)
-    assert (hyper.padded_factors > hyper.factors) == (arm == "packed")
+    assert hyper.padded_factors == lanes_of_v
     rng = np.random.RandomState(11)
     b, lanes, dims = 48, 6, 512
     step = make_fm_step(hyper, "minibatch")
@@ -195,7 +198,7 @@ def test_minibatch_step_equals_plain_accumulator_reference(
         np.testing.assert_allclose(got.w, want["w"], rtol=1e-5, atol=1e-7)
         np.testing.assert_allclose(got.v[:, :factors], want["v"], rtol=1e-5,
                                    atol=1e-7)
-        # pad lanes, the borrowed one too, are zero again after the block
+        # V's pad lanes are written as the zeros they are
         assert (got.v[:, factors:] == 0).all()
         np.testing.assert_array_equal(got.touched, want["touched"])
         for name in ("lambda_w0", "lambda_w"):
@@ -203,3 +206,136 @@ def test_minibatch_step_equals_plain_accumulator_reference(
                 want[name], rel=1e-5, abs=1e-9)
         np.testing.assert_allclose(got.lambda_v[:factors], want["lambda_v"],
                                    rtol=1e-5, atol=1e-9)
+
+
+# name -> (rows, lanes, ids drawn from, share of pad lanes, a feature on
+# every row, share of negative ids)
+BLOCKS = {
+    # one feature sits on every row and most other lanes repeat: the runs
+    # of equal ids whose lanes must all write one value
+    "one_feature_on_every_row": (64, 8, 5, 0.0, True, 0.0),
+    "pad_lanes": (32, 8, 60, 0.4, False, 0.0),
+    # `.at[]` counts a negative id from the table's end and drops what is
+    # still outside; so must the block's own index space
+    "negative_ids": (40, 6, 25, 0.15, True, 0.2),
+    "one_row": (1, 8, 60, 0.25, False, 0.0),
+}
+DIMS = 4096
+
+
+def _block(kind, adareg, seed=5):
+    rows, lanes, distinct, pad, everywhere, negative = BLOCKS[kind]
+    rng = np.random.RandomState(seed)
+    pool = rng.choice(DIMS, size=distinct, replace=False)
+    idx = rng.choice(pool, size=(rows, lanes)).astype(np.int32)
+    if everywhere:
+        idx[:, 0] = pool[0]
+    neg = rng.rand(rows, lanes) < negative
+    # in range from the end (-1 .. -dims) and beyond it (dropped)
+    idx[neg] = -rng.randint(1, 2 * DIMS, size=int(neg.sum()))
+    idx[rng.rand(rows, lanes) < pad] = DIMS
+    val = rng.randn(rows, lanes).astype(np.float32)
+    # a lane outside the table carries no value, as staging pads it
+    val[(idx >= DIMS) | (idx < -DIMS)] = 0.0
+    y = rng.choice([-1.0, 1.0], rows).astype(np.float32)
+    va = (rng.rand(rows) < 0.3).astype(np.float32) if adareg \
+        else np.zeros(rows, np.float32)
+    return idx, val, y, va
+
+
+def _mid_training_state(hyper, seed=9):
+    """Non-zero w, some entries touched, the counter past zero."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    st = FM.init_fm_state(DIMS, hyper)
+    w = (rng.randn(DIMS) * 0.2).astype(np.float32)
+    w[rng.rand(DIMS) < 0.5] = 0.0
+    return st.replace(w=jnp.asarray(w), w0=jnp.asarray(0.03, jnp.float32),
+                      touched=jnp.asarray((w != 0).astype(np.int8)),
+                      step=jnp.asarray(17, jnp.int32))
+
+
+def _step_once(hyper, block):
+    import jax
+    import jax.numpy as jnp
+
+    before = jax.device_get(_mid_training_state(hyper))
+    state, loss = FM.make_fm_step(hyper, "minibatch")(
+        _mid_training_state(hyper), *map(jnp.asarray, block))
+    return before, jax.device_get(state), float(loss)
+
+
+@pytest.mark.parametrize("adareg", [False, True], ids=["plain", "adareg"])
+@pytest.mark.parametrize("factors", [8, 10])
+@pytest.mark.parametrize("kind", sorted(BLOCKS))
+def test_block_kinds_equal_the_plain_accumulator(kind, factors, adareg):
+    """What the sort, the scans and the in-place writes must get right,
+    block kind by block kind, against the accumulator over whole tables:
+    the same entries touched, pad lanes and ids outside the table dropped
+    as `.at[]` drops them, V's pad lanes exactly zero, and nothing outside
+    the block's own entries moved by a bit."""
+    hyper = FM.FMHyper(factors=factors, classification=True, adareg=adareg)
+    block = _block(kind, adareg)
+    before, got, loss = _step_once(hyper, block)
+    want = _plain_accumulator_step(before, hyper, *block)
+    np.testing.assert_array_equal(got.touched, want["touched"])
+    assert int(got.step) == int(before.step) + len(block[2])
+    assert loss == pytest.approx(want["loss"], rel=1e-5)
+    assert float(got.w0) == pytest.approx(want["w0"], rel=1e-5, abs=1e-7)
+    np.testing.assert_allclose(got.w, want["w"], rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(got.v[:, :factors], want["v"], rtol=1e-5,
+                               atol=1e-7)
+    assert (got.v[:, factors:] == 0).all()
+    for name in ("lambda_w0", "lambda_w"):
+        assert float(getattr(got, name)) == pytest.approx(
+            want[name], rel=1e-5, abs=1e-9)
+    np.testing.assert_allclose(got.lambda_v[:factors], want["lambda_v"],
+                               rtol=1e-5, atol=1e-9)
+    ids = np.where(block[0] < 0, block[0] + DIMS, block[0])
+    quiet = np.setdiff1d(np.arange(DIMS), ids[(ids >= 0) & (ids < DIMS)])
+    np.testing.assert_array_equal(got.w[quiet], before.w[quiet])
+    np.testing.assert_array_equal(got.v[quiet], before.v[quiet])
+    np.testing.assert_array_equal(got.touched[quiet], before.touched[quiet])
+
+
+def test_validation_rows_leave_the_tables_alone():
+    """Under -adareg a validation row adapts the lambdas only: a feature
+    that validation rows alone carry keeps `touched`, w and its V row bit
+    for bit, also where training rows of the same block write beside it."""
+    hyper = FM.FMHyper(factors=10, classification=True, adareg=True)
+    idx, val, y, _ = _block("pad_lanes", False)
+    va = np.zeros(len(y), np.float32)
+    va[::2] = 1.0
+    # ids 1000.. on validation rows only, never on a training row
+    idx[::2, :3] = 1000 + np.arange(3)
+    idx[1::2][np.isin(idx[1::2], 1000 + np.arange(3))] = 7
+    val[idx != DIMS] = 0.5
+    before, got, _ = _step_once(hyper, (idx, val, y, va))
+    only_va = np.setdiff1d(idx[::2][idx[::2] < DIMS],
+                           idx[1::2][idx[1::2] < DIMS])
+    assert set(1000 + np.arange(3)) <= set(only_va.tolist())
+    np.testing.assert_array_equal(got.touched[only_va], before.touched[only_va])
+    np.testing.assert_array_equal(got.w[only_va], before.w[only_va])
+    np.testing.assert_array_equal(got.v[only_va], before.v[only_va])
+    trained = np.unique(idx[1::2][idx[1::2] < DIMS])
+    assert (got.touched[trained] == 1).all()
+    assert float(got.lambda_w) != float(before.lambda_w)
+
+
+@pytest.mark.parametrize("options,want", [
+    ("-dims 262144 -mini_batch 4", "batch_local"),
+    ("-dims 512 -mini_batch 64", "batch_local"),   # one plan, any shape
+    ("-dims 4096", None),                          # the scan has no such stage
+])
+def test_train_call_span_says_which_plan_ran(options, want):
+    from hivemall_tpu.runtime.tracing import SPAN_CALL, TRACER
+
+    rng = np.random.default_rng(0)
+    idx = rng.integers(0, 512, size=(128, 5))
+    FM.train_fm((idx, np.ones(idx.shape, np.float32)),
+                np.sign(rng.normal(size=128)), "-c -factor 10 " + options)
+    call = next(sp for sp in TRACER.traces()[-1]["spans"]
+                if sp["name"] == SPAN_CALL)
+    assert call["args"]["entry"] == "fm"
+    assert call["args"].get("apply") == want

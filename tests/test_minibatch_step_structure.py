@@ -5,6 +5,8 @@ is as long as the table but the in-place writes of the touched entries.
 (a) on the CPU, by walking the step's jaxpr; (b) for a described v5e, by
 compiling the benchmark's AROW step (2^28 dims, bfloat16 tables, a
 [1024, 64] block, donated state) and reading the compiler's own account.
+The FM step (models/fm.py, one plan at every shape) is held to the same, at
+the benchmark's 2^23 dims and at the reference's default 2^24.
 Nothing runs in (b): on-chip-measurement guide, section 2. The topology is
 described inside a module-scoped fixture, never at import.
 """
@@ -20,6 +22,7 @@ from hivemall_tpu.core.engine import (DELTA_SLOT, apply_strategy,
                                       make_train_fn)
 from hivemall_tpu.core.state import init_linear_state
 from hivemall_tpu.models import classifier as C
+from hivemall_tpu.models import fm as FM
 from hivemall_tpu.models import regression as R
 
 DIMS = 1 << 20
@@ -83,6 +86,33 @@ def test_only_the_table_writes_are_table_long(name):
     tables = 2 + bool(rule.use_covariance) + len(rule.slot_names) + track
     assert sorted(p for p, _ in long) \
         == ["scatter"] * (tables - 1) + ["scatter-max"], long
+
+
+FM_STEPS = {
+    # name -> FMHyper arguments
+    "fm_k10": dict(factors=10, classification=True),
+    "fm_k8_regression": dict(factors=8),
+    "fm_k10_adareg": dict(factors=10, classification=True, adareg=True),
+}
+
+
+def _fm_args(hyper, dims, rows, width):
+    return (jax.eval_shape(lambda: FM.init_fm_state(dims, hyper)),
+            *_block(rows, width), jax.ShapeDtypeStruct((rows,), jnp.float32))
+
+
+@pytest.mark.parametrize("name", sorted(FM_STEPS))
+def test_fm_only_the_table_writes_are_table_long(name):
+    """w, V (as rows) and touched are written in place; the two gathers
+    read the state's own tables (operands, not results); nothing else the
+    step computes has a `dims`-long axis."""
+    hyper = FM.FMHyper(**FM_STEPS[name])
+    step = FM.make_fm_step(hyper, "minibatch", jit=False)
+    jaxpr = jax.make_jaxpr(step)(*_fm_args(hyper, DIMS, 32, 8))
+    long = _table_long_equations(jaxpr.jaxpr, DIMS)
+    assert sorted(long) == [
+        ("scatter", (DIMS,)), ("scatter", (DIMS, hyper.padded_factors)),
+        ("scatter-max", (DIMS,))], long
 
 
 def test_the_walk_sees_a_dense_pass():
@@ -161,6 +191,20 @@ def _compile_uncached(lowered):
         jax.config.update("jax_enable_compilation_cache", was)
 
 
+# instructions that only name or pass on a table do no pass over it
+PASSES_ON = {"parameter", "tuple", "get-tuple-element", "bitcast"}
+
+
+def _instructions(text):
+    """(result type, opcode, line) of every instruction of a compiled
+    module: `%name = type[shape]{layout} opcode(operands), ...`."""
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(",
+                     line)
+        if m:
+            yield m.group(1), m.group(2), line.strip()
+
+
 def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
     dims, rows, width = 1 << 28, 1024, 64
     assert apply_strategy(dims, rows * width) == "batch_local"
@@ -172,20 +216,65 @@ def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
         on(_state_shape(C.AROW, dims, jnp.bfloat16)),
         *on(_block(rows, width))))
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
-    # an instruction is `%name = type[shape]{layout} opcode(operands), ...`;
-    # those that only name or pass on a table do no pass over it
-    passes_on = {"parameter", "tuple", "get-tuple-element", "bitcast"}
-    long = []
-    for line in compiled.as_text().splitlines():
-        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\(?[^=]*?\)?) ([\w\-]+)\(",
-                     line)
-        if m and f"[{dims}]" in m.group(1) and m.group(2) not in passes_on:
-            long.append((m.group(2), line.strip()))
+    long = [(opcode, line)
+            for result, opcode, line in _instructions(compiled.as_text())
+            if f"[{dims}]" in result and opcode not in PASSES_ON]
     assert long, "the step writes three tables"
     for opcode, line in long:
         assert opcode in ("scatter", "fusion"), line[:200]
         if opcode == "fusion":   # the fusion that holds a scatter, alone
             assert re.search(r'op_name="[^"]*/scatter(-max)?"', line), line[:200]
+
+
+def _compile_fm_cell_step(one_chip, dims):
+    rows, width = 1024, 64
+    hyper = FM.FMHyper(factors=10, classification=True)
+    args = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        _fm_args(hyper, dims, rows, width))
+    return _compile_uncached(FM.make_fm_step(hyper, "minibatch").lower(*args))
+
+
+def test_compiled_fm_cell_step_has_no_table_long_scratch(one_chip):
+    """The benchmark's FM step (2^23 dims, k = 10 in 16 lanes, a [1024, 64]
+    block, donated state): 5 MB of temporaries where the dense plan held
+    9.16 GB, V touched by its row gather and ONE in-place scatter, never
+    through the flat `[dims * 16]` view (a relayout of the whole table)."""
+    dims, lanes = 1 << 23, 16
+    compiled = _compile_fm_cell_step(one_chip, dims)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    text = compiled.as_text()
+    assert "hm.pack_tables" not in text
+    # the compiler's own moves of the [dims] w and touched tables into its
+    # faster memory and back, around their scatters: 40 MiB, no pass over V
+    moves = {"slice-start", "slice-done", "copy-start", "copy-done",
+             "custom-call"}
+    v_writes = []
+    for result, opcode, line in _instructions(text):
+        if opcode in PASSES_ON:
+            continue
+        scatter = opcode == "scatter" or (opcode == "fusion" and re.search(
+            r'op_name="[^"]*/scatter(-max)?"', line))
+        if f"[{dims},{lanes}]" in result or f"[{dims * lanes}]" in result:
+            assert scatter, line[:200]
+            v_writes.append(opcode)
+        elif f"[{dims}]" in result:
+            assert scatter or opcode in moves, line[:200]
+    assert v_writes.count("fusion") == 1, v_writes
+
+
+def test_fm_step_compiles_at_the_reference_default_dims(one_chip):
+    """`train_fm -mini_batch` at 2^24 dims, the reference's default
+    capacity: refused before (17.1 GiB of 15.75), now step and state with a
+    spare state beside them stay under three quarters of the chip."""
+    dims = 1 << 24
+    m = _compile_fm_cell_step(one_chip, dims).memory_analysis()
+    assert m.temp_size_in_bytes < 64 << 20
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    state_bytes = dims * (16 * 4 + 4 + 1)
+    assert peak >= state_bytes
+    assert peak + state_bytes <= 0.75 * 15.75 * 2 ** 30, (peak, state_bytes)
 
 
 # close()'s emission at the cell's sizes (core/emission.py): the mask's

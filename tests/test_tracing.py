@@ -766,8 +766,9 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
         "entry": "fm" if fm else "arow", "dims": 256, "rows": 64,
         "mini_batch": 16, "epochs": epochs, "mode": "minibatch",
         "table_dtype": "float32",
-        # the linear step's way with a block: a 256-entry table is small
-        **({} if fm else {"apply": "dense"})}
+        # the step's way with a block: a 256-entry table is small for the
+        # linear step's shape rule; FM has one plan at every shape
+        "apply": "batch_local" if fm else "dense"}
     nnz = sum(len(r) for r in idx)
     (stage,) = by_name["train.stage"]
     assert stage["args"] == {"form": form, "rows": 64, "nnz": nnz}
@@ -850,10 +851,12 @@ def test_each_call_compiles_on_its_first_step_and_says_so():
         assert counted["train.jit_compiles"] == 1
 
 
-# gather and scatter ops in each step's lowered text at the parent commit
-# (61d9a95, before any scope): a scope is metadata and adds no op
+# gather and scatter ops in each step's lowered text: the linear steps' at
+# the commit before any scope (61d9a95), the FM step's at the commit that
+# made it block-local (three in-place writes). A scope is metadata and adds
+# no op
 PARENT_OP_COUNTS = {"arow_minibatch": (2, 6), "arow_scan": (4, 6),
-                    "fm_minibatch": (2, 12)}
+                    "fm_minibatch": (4, 6)}
 
 
 @pytest.mark.parametrize("family", sorted(PARENT_OP_COUNTS))
