@@ -67,11 +67,13 @@ def iter_model_rows(model) -> Tuple[List[str], Iterable[tuple]]:
         def ffm_rows():
             from ..tools import base91
 
-            feats, w, w0 = model.model_rows()
+            # the linear rows as rows; the V entries ride the blob row
+            emitted = model.model_rows()
+            w0, feats, w = emitted[:3]
             yield (-1, float(w0), None)
             for f, wi in zip(feats, w):
                 yield (int(f), float(wi), None)
-            yield (-2, None, base91(model.to_blob()))
+            yield (-2, None, base91(model.to_blob(rows=emitted)))
 
         return cols, ffm_rows()
 

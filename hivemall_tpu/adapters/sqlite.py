@@ -360,7 +360,8 @@ def _materialize_ffm(q, model, model_table: str) -> None:
     (ref: FFMPredictionModel.java:46-200 + FFMPredictUDF). Score in SQL
     with `ffm_predict(blob, features)` — full pairwise parity with the
     framework's predict, V included."""
-    feats, w, w0 = model.model_rows()
+    emitted = model.model_rows()
+    w0, feats, w = emitted[:3]
     q.execute(f"CREATE TABLE {model_table} "
               "(feature INTEGER PRIMARY KEY, wi REAL)")
     q.execute(f"INSERT INTO {model_table} VALUES (-1, ?)", (float(w0),))
@@ -369,7 +370,7 @@ def _materialize_ffm(q, model, model_table: str) -> None:
     q.execute(f"DROP TABLE IF EXISTS {model_table}_blob")
     q.execute(f"CREATE TABLE {model_table}_blob (model BLOB)")
     q.execute(f"INSERT INTO {model_table}_blob VALUES (?)",
-              (model.to_blob(),))
+              (model.to_blob(rows=emitted),))
 
 
 def _materialize_forest(q, model, model_table: str) -> None:

@@ -116,15 +116,18 @@ def _stage_rows(features: FeatureRows, dims: int) -> ArrayRows:
     return parse_features_batch(features, dims)
 
 
-def stage_training_rows(features: FeatureRows, dims: int, replicas: int = 1):
+def stage_training_rows(features: FeatureRows, dims: int, replicas: int = 1,
+                        stage=None):
     """(idx_rows, val_rows, block width) of a training call's rows, under a
-    `train.stage` span (text rows open `train.parse` inside it). With
+    `train.stage` span (text rows open `train.parse` inside it). `stage`
+    takes `_stage_rows`' place where a family's text rows are not
+    `"<id>:<value>"` (FFM's carry a field). With
     `replicas` > 1 (`-mix`) the rows are dealt inside it, under
     `train.shard_rows`: idx_rows and val_rows are then one list a replica,
     its contiguous share (parallel/mix.py::deal_rows)."""
     with TRACER.span(SPAN_STAGE, args={
             "form": "arrays" if _is_array_rows(features) else "text"}) as sp:
-        idx_rows, val_rows = _stage_rows(features, dims)
+        idx_rows, val_rows = (stage or _stage_rows)(features, dims)
         lens = [len(r) for r in idx_rows]
         sp.set(rows=len(lens), nnz=sum(lens))
         if replicas > 1:

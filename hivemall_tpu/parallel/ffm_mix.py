@@ -98,7 +98,7 @@ class FFMMixTrainer:
 
     def final_state(self, state) -> FFMState:
         """Collapse the device axis: w/z/n/v/w0 are identical across replicas
-        after the trailing mix; touched unions; the AdaGrad-V accumulator
+        after the trailing mix; touched and v_touched union; the AdaGrad-V accumulator
         v_gg — an additive sum of squared gradients over each replica's
         disjoint shard — merges by summing (the union stream's total), so a
         warm restart resumes with the full-stream curvature instead of one
@@ -108,6 +108,7 @@ class FFMMixTrainer:
         step_all = np.asarray(host.step)
         return merged.replace(
             touched=np.max(np.asarray(host.touched), axis=0),
+            v_touched=np.max(np.asarray(host.v_touched), axis=0),
             v_gg=np.asarray(host.v_gg).sum(axis=0),
             step=step_all.sum().astype(step_all.dtype),
         )

@@ -377,12 +377,15 @@ class FFMShardedTrainer:
     per chunk). Blocks replicate; both tables pad to their stripe grids.
 
     `init(from_state=...)` seeds from an (unsharded) host FFMState — the
-    parity/warm-start path; the default init draws V ~ N(0, sigma) at the
-    padded shape (same distribution as unsharded, different draw)."""
+    parity/warm-start path; the default init starts V at `initial_v`, the
+    entry's own value as `init_ffm_state` has it (the padding too, which
+    nothing addresses), so `final_state` is a state that `TrainedFFMModel`
+    emits, encodes and decodes like `train_ffm`'s."""
 
     def __init__(self, hyper, mesh: Optional[Mesh] = None,
                  mode: str = "minibatch", row_chunk: Optional[int] = None):
-        from ..models.ffm import FFMHyper, FFMState, make_ffm_step
+        from ..models.ffm import (FFMHyper, FFMState, initial_v,
+                                  make_ffm_step)
 
         assert isinstance(hyper, FFMHyper)
         self.hyper = hyper
@@ -393,16 +396,16 @@ class FFMShardedTrainer:
         self.dv_padded = self.stripe_v * n
 
         def init_one() -> FFMState:
-            key = jax.random.PRNGKey(hyper.seed)
             return FFMState(
                 w0=jnp.zeros(()),
                 w=jnp.zeros((self.nf_padded,)),
                 z=jnp.zeros((self.nf_padded,)),
                 n=jnp.zeros((self.nf_padded,)),
-                v=jax.random.normal(key, (self.dv_padded, hyper.factors))
-                * hyper.sigma,
+                v=initial_v(jnp.arange(self.dv_padded, dtype=jnp.uint32),
+                            hyper.factors, hyper.seed, hyper.sigma),
                 v_gg=jnp.zeros((self.dv_padded,)),
                 touched=jnp.zeros((self.nf_padded,), jnp.int8),
+                v_touched=jnp.zeros((self.dv_padded,), jnp.int8),
                 step=jnp.zeros((), jnp.int32),
             )
 
@@ -442,6 +445,8 @@ class FFMShardedTrainer:
             v_gg=_pad_initial(np.asarray(host.v_gg), self.dv_padded),
             touched=np.pad(np.asarray(host.touched),
                            (0, self.nf_padded - nf)),
+            v_touched=np.pad(np.asarray(host.v_touched),
+                             (0, self.dv_padded - dv)),
         )
         return jax.tree.map(
             lambda leaf, spec: jax.device_put(
@@ -498,6 +503,7 @@ class FFMShardedTrainer:
             touched=np.asarray(host.touched)[: nf],
             v=np.asarray(host.v)[: dv],
             v_gg=np.asarray(host.v_gg)[: dv],
+            v_touched=np.asarray(host.v_touched)[: dv],
         )
 
 

@@ -163,7 +163,9 @@ def _pack_ffm_state(host) -> dict:
         "w0": np.asarray(host.w0), "w": np_saveable(host.w),
         "z": np.asarray(host.z), "n": np.asarray(host.n),
         "v": np_saveable(host.v), "v_gg": np.asarray(host.v_gg),
-        "touched": np.asarray(host.touched), "step": np.asarray(host.step),
+        "touched": np.asarray(host.touched),
+        "v_touched": np.asarray(host.v_touched),
+        "step": np.asarray(host.step),
     }
 
 
@@ -172,6 +174,12 @@ def _unpack_ffm_state(arrays):
 
     from ..models.ffm import FFMState
 
+    if "v_touched" not in arrays:
+        raise ValueError(
+            "this FFM checkpoint was written before PR 32 (FFMState has no "
+            "v_touched table in it): its untouched V entries are another "
+            "draw than models/ffm.py::initial_v, so emission and blobs "
+            "could not reproduce it; train again (docs/migration.md)")
     f32 = jnp.float32
     return FFMState(
         w0=jnp.asarray(arrays["w0"], f32), w=jnp.asarray(arrays["w"], f32),
@@ -179,6 +187,7 @@ def _unpack_ffm_state(arrays):
         v=jnp.asarray(arrays["v"], f32),
         v_gg=jnp.asarray(arrays["v_gg"], f32),
         touched=jnp.asarray(arrays["touched"], jnp.int8),
+        v_touched=jnp.asarray(arrays["v_touched"], jnp.int8),
         step=jnp.asarray(arrays["step"], jnp.int32),
     )
 

@@ -79,7 +79,7 @@ from collections import deque
 from typing import Dict, Iterator, List, Optional, Tuple
 
 # The vocabulary of one `train_*` UDTF call (docs/observability.md, "The
-# training call's timeline"): host spans from fit_linear/train_fm down to
+# training call's timeline"): host spans from fit_linear/train_fm/train_ffm down to
 # model_rows(), and the device scope names inside the jitted steps. Readers
 # (benchmark/readers/, /trace consumers) match on these strings, so they stay
 # put across refactors. The per-step three are the names the multi-chip
@@ -115,6 +115,9 @@ LINEAR_SCOPES = (SCOPE_PACK_TABLES, SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE,
                  SCOPE_APPLY, SCOPE_TOUCHED)
 FM_SCOPES = (SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE, SCOPE_APPLY,
              SCOPE_TOUCHED, SCOPE_LOSS)   # FM packs nothing since PR 31
+# FFM's mini-batch step (models/ffm.py::block_step) carries the same six: the
+# pair block's and the linear lanes' gathers, the loss, the rule, the linear
+# lanes' run sums, the in-place adds and writes, both key spaces' flags
 
 _ID_COUNTER = itertools.count(1)  # __next__ is GIL-atomic: no lock needed
 

@@ -109,8 +109,8 @@ def _variants():
         jax.eval_shape(lambda: init_fm_state(1 << 23, h)),
         *block(4096, 64), S((4096,), jnp.float32))
 
-    # FFM: mini-batch with and without -row_chunk, on both sides of
-    # _want_pack's shape test, and the scan
+    # FFM: mini-batch with and without -row_chunk at two table sizes (the
+    # tags name the two arms the step had before PR 32), and the scan
     for tag, v_bits, rows in (("packed", 16, 256), ("split", 22, 16)):
         fh = FFMHyper(factors=4, num_features=1 << 16, num_fields=16,
                       v_dims=1 << v_bits)

@@ -337,6 +337,27 @@ def test_ffm_sharded_elastic_round_trip(tmp_path):
                                rtol=5e-3, atol=5e-4)
 
 
+def test_ffm_checkpoint_from_before_v_touched_is_refused_in_words(tmp_path):
+    """A checkpoint whose FFM state has no `v_touched` table (written
+    before PR 32) is refused with a message that says so, not a KeyError."""
+    from hivemall_tpu.models.ffm import FFMHyper
+    from hivemall_tpu.parallel import make_mesh
+    from hivemall_tpu.runtime import recovery
+
+    hyper = FFMHyper(num_features=67, v_dims=131, factors=4, num_fields=8,
+                     seed=5)
+    ck = str(tmp_path / "ffm.npz")
+    trainer, state = recovery.elastic_resume(
+        None, hyper, hyper.num_features, ck, mesh=make_mesh(2),
+        family="ffm_sharded")
+    arrays = recovery._pack_ffm_state(trainer.final_state(state))
+    assert "v_touched" in arrays
+    recovery._unpack_ffm_state(arrays)              # today's round trip
+    del arrays["v_touched"]
+    with pytest.raises(ValueError, match="before PR 32.*v_touched"):
+        recovery._unpack_ffm_state(arrays)
+
+
 def test_sharded_2d_elastic_resume(tmp_path):
     """The 2-D (replicas × stripes) family resumes across BOTH axes at
     once — (2×4) → (2×2) — with MixTrainer-grade additive-statistics

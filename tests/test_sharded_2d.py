@@ -208,6 +208,46 @@ def test_ffm_sharded_parity():
         np.testing.assert_allclose(scores, want, rtol=2e-5, atol=1e-5)
 
 
+def test_ffm_sharded_state_round_trips_through_the_blob():
+    """A sharded trainer's own `init` starts V at `initial_v`, as
+    `init_ffm_state` does, so its `final_state` wrapped in a
+    `TrainedFFMModel` encodes and decodes to the model that was trained:
+    `from_blob` refills every entry that is not flagged from `initial_v`,
+    and a row whose pairs no trained row addressed scores the same."""
+    from hivemall_tpu.models.ffm import (FFMHyper, TrainedFFMModel,
+                                         init_ffm_state)
+    from hivemall_tpu.parallel.sharded_train import FFMShardedTrainer
+
+    hyper = FFMHyper(factors=3, num_features=1001, v_dims=2003, num_fields=8,
+                     seed=6, eta0_v=0.05)
+    rng = np.random.RandomState(23)
+    B, K = 32, 6
+    # training rows carry features under 500, the unseen rows above
+    idx = rng.randint(0, 500, size=(2, B, K)).astype(np.int32)
+    val = rng.rand(2, B, K).astype(np.float32)
+    fld = rng.randint(0, 8, size=(2, B, K)).astype(np.int32)
+    lab = np.sign(rng.randn(2, B)).astype(np.float32)
+
+    trainer = FFMShardedTrainer(hyper, make_mesh(8))
+    state = trainer.init()
+    fresh = trainer.final_state(state)
+    np.testing.assert_array_equal(
+        np.asarray(fresh.v), np.asarray(init_ffm_state(hyper).v))
+    for b in range(2):
+        state, _ = trainer.step(state, idx[b], val[b], fld[b], lab[b])
+    model = TrainedFFMModel(state=trainer.final_state(state), hyper=hyper)
+    back = TrainedFFMModel.from_blob(model.to_blob(half_float=False))
+
+    seen = (list(idx[0]), list(val[0]), list(fld[0]))
+    unseen_idx = rng.randint(500, 1001, size=(B, K)).astype(np.int32)
+    unseen = (list(unseen_idx), list(val[1]), list(fld[1]))
+    for rows in (seen, unseen):
+        np.testing.assert_array_equal(back.predict(rows), model.predict(rows))
+    assert np.abs(model.predict(unseen)).max() > 0    # initial_v, not zeros
+    np.testing.assert_array_equal(np.asarray(back.state.v),
+                                  np.asarray(model.state.v))
+
+
 def test_mc_sharded_parity():
     """Feature-dim sharded multiclass == single-device step for step:
     weights, covars, touched, loss — covariance rule, non-divisible dims,

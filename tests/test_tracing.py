@@ -807,6 +807,119 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     assert d2h == 256 // 8 + 256 * row + (4 if fm else 0)
 
 
+def _ffm_rows(form, n=64, seed=0):
+    rng = np.random.RandomState(seed)
+    idx = rng.randint(0, 256, (n, 6))
+    val = rng.rand(n, 6).astype(np.float32) + 0.5
+    fld = np.broadcast_to(np.arange(6), idx.shape)
+    labels = rng.choice([-1, 1], n)
+    if form == "text":
+        return [[f"{f}:{i}:{v!r}" for f, i, v in zip(fr, ir, vr.tolist())]
+                for fr, ir, vr in zip(fld, idx, val)], labels, idx
+    return (idx, val, fld), labels, idx
+
+
+@pytest.mark.parametrize("form", ["text", "arrays"])
+def test_train_ffm_commits_the_vocabulary(form):
+    """`entry: ffm` speaks fit_linear's and train_fm's vocabulary, with the
+    pair block's own args and counters and a second key space emitted."""
+    from hivemall_tpu.runtime.metrics import REGISTRY
+    from hivemall_tpu.sql.registry import get_function
+
+    rows, labels, idx = _ffm_rows(form)
+    names = CALL_COUNTERS + ("train.pair_lanes", "train.pair_lanes_padded")
+    read = lambda: {k: REGISTRY.snapshot().get(k, 0.0) for k in names}
+    before = read()
+    TRACER.clear()
+    model = get_function("train_ffm")(
+        rows, labels, "-factor 4 -feature_hashing 18 -num_fields 6 -v_bits 12 "
+        "-mini_batch 16 -iters 2 -disable_cv")
+    w0, feats, w, v_keys, v = model.model_rows()
+    counted = {k: v_ - before[k] for k, v_ in read().items()}
+    call, emit = TRACER.traces()
+    want = dict(CALL_PARENTS)
+    if form == "arrays":
+        want.pop("train.parse")
+    assert _parents(call) == set(want.items())
+    assert _parents(emit) == set(EMIT_PARENTS.items())
+    by_name = {}
+    for s in call["spans"] + emit["spans"]:
+        by_name.setdefault(s["name"], []).append(s)
+    steps = 2 * 64 // 16
+    for per_step in ("train.data_prep", "train.compiled_step"):
+        assert len(by_name[per_step]) == steps
+    # the loss is fetched once an epoch, as fit_linear's is
+    assert len(by_name["train.sync"]) == 2
+    assert _sum(call, "train.sync", "fetches") == steps
+    (root,) = by_name["train.call"]
+    assert root["args"] == {
+        "entry": "ffm", "dims": 1 << 18, "rows": 64, "mini_batch": 16,
+        "epochs": 2, "mode": "minibatch", "table_dtype": "float32",
+        "fields": 6, "pairs_per_row": 30, "v_dims": 1 << 12,
+        "apply": "batch_local", "row_tile": 16}
+    (stage,) = by_name["train.stage"]
+    assert stage["args"] == {"form": form, "rows": 64, "nnz": 64 * 6}
+    if form == "text":
+        assert by_name["train.parse"][0]["args"] == {"tokens": 64 * 6,
+                                                     "native": False}
+    # a step is handed [16, 8] ids, values and fields and 16 labels
+    block = 16 * 8 * 4 * 3 + 16 * 4
+    assert {s["args"]["h2d_bytes"] for s in by_name["train.data_prep"]} \
+        == {block}
+    assert counted["train.h2d_bytes"] == steps * block
+    assert counted["train.parse_tokens"] == (64 * 6 if form == "text" else 0)
+    assert counted["train.jit_compiles"] == 1
+    # 30 real pair lanes a row; the step gathers the 8 x 8 block of its lanes
+    assert counted["train.pair_lanes"] == 2 * 64 * 30
+    assert counted["train.pair_lanes_padded"] == 2 * 64 * 64
+    assert [(s["args"]["pair_lanes"], s["args"]["pair_lanes_padded"])
+            for s in by_name["train.epoch"]] == [(64 * 30, 64 * 64)] * 2
+    # emission: the linear rows, then the V entries, each selected on the
+    # device from its own flags
+    assert set(feats) == {i for r in idx for i in r}
+    (emit_root,) = by_name["emit.model_rows"]
+    assert emit_root["args"]["rows_out"] == len(feats) + len(v_keys)
+    assert emit_root["args"]["v_rows_out"] == len(v_keys) > 0
+    assert [s["args"]["rows_out"] for s in by_name["emit.select"]] \
+        == [len(feats), len(v_keys)]
+    assert counted["emit.rows"] == len(feats) + len(v_keys)
+    d2h = _sum(emit, "emit.d2h", "bytes")
+    assert emit_root["args"]["d2h_bytes"] == d2h == counted["emit.d2h_bytes"]
+    assert [s["args"]["table"] for s in by_name["emit.d2h"]] \
+        == ["mask", "w", "mask", "v", "w0"]
+    assert emit_root["args"]["select"] == "device"
+    # both masks, one gather chunk a key space (a chunk is the table's
+    # length where that is under 2^19), and w0
+    assert d2h == (1 << 18) // 8 + (1 << 12) // 8 \
+        + (1 << 18) * 4 + (1 << 12) * 16 + 4
+
+
+def test_ffm_step_carries_every_scope_and_nothing_as_long_as_a_table():
+    import re
+
+    import jax.numpy as jnp
+
+    from hivemall_tpu.models.ffm import (FFMHyper, init_ffm_state,
+                                         make_ffm_step)
+    from hivemall_tpu.runtime import tracing
+
+    hyper = FFMHyper(factors=4, num_features=512, num_fields=8, v_dims=4096)
+    block = (jnp.zeros((16, 8), jnp.int32), jnp.ones((16, 8), jnp.float32),
+             jnp.zeros((16, 8), jnp.int32), jnp.ones((16,), jnp.float32))
+    lowered = make_ffm_step(hyper, "minibatch").lower(
+        init_ffm_state(hyper), *block)
+    text = lowered.as_text(debug_info=True)
+    assert set(re.findall(r"hm\.[a-z_]+", text)) == set(tracing.FM_SCOPES)
+    assert not re.search(r"hm\.", lowered.as_text())   # metadata only
+    # the pair block: V, gg and w gathered once; z and n beside them for the
+    # linear lanes; each table written once, in place
+    assert (len(re.findall('"stablehlo.gather"', text)),
+            len(re.findall('"stablehlo.scatter"', text))) == (6, 7)
+    # no tensor of the V table's length but the tables themselves
+    assert not re.search(r"tensor<4096x[0-9]+xf32>", text.replace(
+        "tensor<4096x4xf32>", ""))
+
+
 def test_root_self_time_is_what_the_children_leave():
     """Self time by the rule of benchmark/readers/_program_spans.py: a
     span's duration less the union of its children's intervals. The
