@@ -62,6 +62,20 @@ def bucket_rows(x, min_rows: int = 8):
     return np.concatenate([np.asarray(x), np.zeros(pad_shape, x.dtype)])
 
 
+def is_rect(rows) -> bool:
+    """Rows that are one 2-D array: every row of one length. Staging and
+    packing keep such rows an array (no Python-level work per row)."""
+    return isinstance(rows, np.ndarray) and rows.ndim == 2
+
+
+def longest_row(rows) -> int:
+    """The longest row's length (1 for no rows): a shape where the rows are
+    one array."""
+    if is_rect(rows):
+        return rows.shape[1] if rows.shape[0] else 1
+    return max((len(r) for r in rows), default=1)
+
+
 def pack_rows(
     idx_rows: Sequence[np.ndarray],
     val_rows: Sequence[np.ndarray],
@@ -76,13 +90,16 @@ def pack_rows(
     nnz; `pad_to_bucket(max_nnz)` is the default). If `batch_size` is given,
     the block is padded with empty rows up to it (their labels are 0 and all
     lanes are dropped, so they are true no-ops in every learner).
+
+    Rows given as two 2-D arrays (`is_rect`) are packed by one slice
+    assignment each, to the same block as the same rows in a list.
     """
     n = len(idx_rows)
-    max_nnz = max((len(r) for r in idx_rows), default=1)
     if width is None:
-        width = pad_to_bucket(max_nnz)
+        width = pad_to_bucket(longest_row(idx_rows))
     b = batch_size if batch_size is not None else n
-    if b == n and n > 0:
+    rect = is_rect(idx_rows) and is_rect(val_rows)
+    if b == n and n > 0 and not rect:
         from .. import native
 
         packed = native.pack_block(idx_rows, val_rows, width, dims)
@@ -94,12 +111,19 @@ def pack_rows(
     values = np.zeros((b, width), dtype=np.float32)
     labs = np.zeros((b,), dtype=np.float32)
     nnz = np.zeros((b,), dtype=np.int32)
-    for i in range(n):
-        k = min(len(idx_rows[i]), width)
-        indices[i, :k] = idx_rows[i][:k]
-        values[i, :k] = val_rows[i][:k]
-        labs[i] = labels[i]
-        nnz[i] = k
+    if rect:
+        k = min(idx_rows.shape[1], width)
+        indices[:n, :k] = idx_rows[:, :k]
+        values[:n, :k] = val_rows[:, :k]
+        labs[:n] = labels
+        nnz[:n] = k
+    else:
+        for i in range(n):
+            k = min(len(idx_rows[i]), width)
+            indices[i, :k] = idx_rows[i][:k]
+            values[i, :k] = val_rows[i][:k]
+            labs[i] = labels[i]
+            nnz[i] = k
     return FeatureBlock(indices, values, labs, nnz)
 
 
@@ -119,8 +143,7 @@ def iter_blocks(
     """
     n = len(idx_rows)
     if width is None:
-        max_nnz = max((len(r) for r in idx_rows), default=1)
-        width = pad_to_bucket(max_nnz)
+        width = pad_to_bucket(longest_row(idx_rows))
     for start in range(0, n, batch_size):
         end = min(start + batch_size, n)
         yield pack_rows(
@@ -166,6 +189,8 @@ def shuffle_rows(
     epoch-replay analog, ref: ftvec/amplify/RandomAmplifierUDTF.java:43-66)."""
     rng = np.random.RandomState(seed)
     perm = rng.permutation(len(idx_rows))
+    if is_rect(idx_rows) and is_rect(val_rows):
+        return idx_rows[perm], val_rows[perm], np.asarray(labels)[perm]
     return (
         [idx_rows[i] for i in perm],
         [val_rows[i] for i in perm],

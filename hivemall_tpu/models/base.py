@@ -26,8 +26,8 @@ import jax
 import numpy as np
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import (iter_blocks, pack_rows, pad_to_bucket,
-                          shuffle_rows)
+from ..core.batch import (is_rect, iter_blocks, longest_row, pack_rows,
+                          pad_to_bucket, shuffle_rows)
 from ..core.engine import (Rule, apply_strategy, make_predict,
                            make_train_step)
 from ..core.state import LinearState, init_linear_state, model_rows
@@ -100,7 +100,12 @@ def base_options() -> Options:
     return o
 
 
-ArrayRows = Tuple[List[np.ndarray], List[np.ndarray]]
+# the pre-hashed row form: (idx_rows, val_rows), each a list of one array a
+# row, or each ONE `[n, lanes]` array where every row has as many lanes (a
+# rectangular pair: it is staged, dealt, shuffled and packed as an array)
+ArrayRows = Union[Tuple[List[np.ndarray], List[np.ndarray]],
+                  Tuple[np.ndarray, np.ndarray]]
+# a call's rows: `"<id>:<value>"` strings a row, or the pre-hashed form
 FeatureRows = Union[Sequence[Sequence[str]], ArrayRows]
 
 
@@ -109,7 +114,19 @@ def _is_array_rows(features: FeatureRows) -> bool:
 
 
 def _stage_rows(features: FeatureRows, dims: int) -> ArrayRows:
+    """(idx_rows, val_rows): ids int64 in [0, dims) by NumPy's floored
+    modulo, values float32. A rectangular pair comes back as two arrays
+    (the caller's own where they are int64 ids in range and float32 values:
+    nothing downstream writes to staged rows), any other input as two lists
+    of a row's arrays."""
     if _is_array_rows(features):
+        idx, val = features
+        if is_rect(idx) and is_rect(val) and idx.shape == val.shape:
+            idx = idx.astype(np.int64, copy=False)
+            # two reductions cost a tenth of the division they mostly save
+            if idx.size and (idx.min() < 0 or idx.max() >= dims):
+                idx = np.remainder(idx, dims)
+            return idx, np.asarray(val, dtype=np.float32)
         idx_rows = [np.asarray(r, dtype=np.int64) % dims for r in features[0]]
         val_rows = [np.asarray(v, dtype=np.float32) for v in features[1]]
         return idx_rows, val_rows
@@ -124,22 +141,31 @@ def stage_training_rows(features: FeatureRows, dims: int, replicas: int = 1,
     `"<id>:<value>"` (FFM's carry a field). With
     `replicas` > 1 (`-mix`) the rows are dealt inside it, under
     `train.shard_rows`: idx_rows and val_rows are then one list a replica,
-    its contiguous share (parallel/mix.py::deal_rows)."""
+    its contiguous share (parallel/mix.py::deal_rows). Rows that stayed
+    arrays (`layout: rect`, counted by `train.rows_staged_rect`) give their
+    lengths by shape and their shares as views."""
     with TRACER.span(SPAN_STAGE, args={
             "form": "arrays" if _is_array_rows(features) else "text"}) as sp:
         idx_rows, val_rows = (stage or _stage_rows)(features, dims)
-        lens = [len(r) for r in idx_rows]
-        sp.set(rows=len(lens), nnz=sum(lens))
+        rect = is_rect(idx_rows) and is_rect(val_rows)
+        if rect:
+            rows, nnz = len(idx_rows), idx_rows.size
+            longest = longest_row(idx_rows)
+            REGISTRY.counter("train", "rows_staged_rect").increment(rows)
+        else:
+            lens = [len(r) for r in idx_rows]
+            rows, nnz, longest = len(lens), sum(lens), max(lens, default=1)
+        sp.set(layout="rect" if rect else "rows", rows=rows, nnz=nnz)
         if replicas > 1:
             from ..parallel.mix import deal_rows
 
             with TRACER.span(SPAN_SHARD_ROWS,
                              args={"replicas": replicas}) as deal:
-                shares = deal_rows(len(lens), replicas)
+                shares = deal_rows(rows, replicas)
                 idx_rows = [idx_rows[lo:hi] for lo, hi in shares]
                 val_rows = [val_rows[lo:hi] for lo, hi in shares]
-                deal.set(rows=len(lens), rows_each=shares[0][1] - shares[0][0])
-    return idx_rows, val_rows, pad_to_bucket(max(lens, default=1))
+                deal.set(rows=rows, rows_each=shares[0][1] - shares[0][0])
+    return idx_rows, val_rows, pad_to_bucket(longest)
 
 
 def init_state_spanned(init, *args, **kw):
@@ -243,7 +269,7 @@ class TrainedLinearModel:
         gather-dot kernel (ref: SURVEY.md §3.5; tools/math/SigmoidGenericUDF.java)."""
         idx_rows, val_rows = _stage_rows(features, self.dims)
         n = len(idx_rows)
-        width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
+        width = pad_to_bucket(longest_row(idx_rows))
         want_var = return_variance and self.rule.use_covariance
         predict = make_predict(use_covariance=want_var)
         # keep per-block outputs on device so dispatch stays async across

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
-from ..core.batch import pad_to_bucket
+from ..core.batch import is_rect, longest_row, pack_rows, pad_to_bucket
 from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.eta import EtaEstimator, get_eta
@@ -58,8 +58,8 @@ from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
                                SPAN_SYNC, TRACER)
 from ..utils.feature import FMFeature
 from ..utils.options import Options
-from .base import (dispatch_step, init_state_spanned, prepared_blocks,
-                   stage_training_rows)
+from .base import (_stage_rows, dispatch_step, init_state_spanned,
+                   prepared_blocks, stage_training_rows)
 from .fm import _fm_options
 
 _MIX1 = 0x9E3779B1
@@ -755,7 +755,7 @@ def _parse_ffm_text(rows, hyper: FFMHyper):
 def _pack_fields(fld_rows, width: int, num_fields: int) -> np.ndarray:
     """[rows, width] int32 field lanes of one block (pad lane: field 0)."""
     out = np.zeros((len(fld_rows), width), np.int32)
-    if isinstance(fld_rows, np.ndarray) and fld_rows.ndim == 2:
+    if is_rect(fld_rows):
         k = min(width, fld_rows.shape[1])
         out[:, :k] = fld_rows[:, :k] % num_fields
         return out
@@ -769,15 +769,12 @@ def _stage_ffm_rows(rows, labels, hyper: FFMHyper):
     """Rows of either form as padded [B, K] arrays (pad lane: idx =
     num_features OOB, value 0, field 0) and their labels as the steps take
     them: `predict`'s staging, and a test's."""
-    from ..core.batch import pack_rows
-
     if _is_field_arrays(rows):
         idx_rows, val_rows, fld_rows = rows
     else:
         idx_rows, val_rows, fld_rows = _parse_ffm_text(rows, hyper)
-    idx_rows = [np.asarray(r, np.int64) % hyper.num_features for r in idx_rows]
-    val_rows = [np.asarray(v, np.float32) for v in val_rows]
-    width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
+    idx_rows, val_rows = _stage_rows((idx_rows, val_rows), hyper.num_features)
+    width = pad_to_bucket(longest_row(idx_rows))
     blk = pack_rows(idx_rows, val_rows, np.zeros(len(idx_rows)),
                     hyper.num_features, width=width)
     lab = None
@@ -878,7 +875,7 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
         idx_rows, val_rows, width = stage_training_rows(rows, dims,
                                                         stage=parse_text)
     n = len(idx_rows)
-    longest = max((len(r) for r in idx_rows), default=1)
+    longest = longest_row(idx_rows)
     pair_width = min(width, -(-longest // 8) * 8)
     mini_batch = cl.get_int("mini_batch", 1)
     mode = "minibatch" if mini_batch > 1 else "scan"
@@ -902,7 +899,8 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
         if block % row_chunk != 0:
             raise ValueError(
                 f"-mini_batch {block} not divisible by -row_chunk {row_chunk}")
-    pairs = sum(len(r) * (len(r) - 1) for r in idx_rows)
+    pairs = n * longest * (longest - 1) if is_rect(idx_rows) \
+        else sum(len(r) * (len(r) - 1) for r in idx_rows)
     call.set(dims=dims, rows=n, mini_batch=mini_batch, mode=mode,
              fields=longest, pairs_per_row=pairs // max(n, 1),
              v_dims=hyper.v_dims)

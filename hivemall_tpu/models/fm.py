@@ -32,7 +32,8 @@ import numpy as np
 from flax import struct
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import iter_blocks, pad_to_bucket, shuffle_rows
+from ..core.batch import (iter_blocks, longest_row, pad_to_bucket,
+                          shuffle_rows)
 from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.eta import EtaEstimator, get_eta
@@ -431,7 +432,7 @@ class TrainedFMModel:
     def predict(self, features: FeatureRows) -> np.ndarray:
         idx_rows, val_rows = _stage_rows(features, self.dims)
         n = len(idx_rows)
-        width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
+        width = pad_to_bucket(longest_row(idx_rows))
         out = []
         for blk in iter_blocks(idx_rows, val_rows, np.zeros(n), self.dims, 4096, width):
             out.append(np.asarray(_fm_scores(self.state, blk.indices, blk.values)))

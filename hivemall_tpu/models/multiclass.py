@@ -39,7 +39,7 @@ import numpy as np
 from flax import struct
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import iter_blocks, pad_to_bucket
+from ..core.batch import iter_blocks, longest_row, pad_to_bucket
 from ..utils.options import Options
 from .base import FeatureRows, _stage_rows, base_options
 from .classifier import _resolve_phi, _safe_div
@@ -357,7 +357,7 @@ class TrainedMulticlassModel:
     def scores(self, features: FeatureRows) -> np.ndarray:
         idx_rows, val_rows = _stage_rows(features, self.dims)
         n = len(idx_rows)
-        width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
+        width = pad_to_bucket(longest_row(idx_rows))
         out = []
         for blk in iter_blocks(idx_rows, val_rows, np.zeros(n), self.dims, 1024, width):
             out.append(np.asarray(_mc_scores(self.state.weights, blk.indices, blk.values)))
@@ -390,7 +390,7 @@ def _fit_multiclass(rule: MCRule, hyper: dict, cl, features: FeatureRows,
     lab2i = {l: i for i, l in enumerate(vocab)}
     y = np.array([lab2i[l] for l in labels], dtype=np.int32)
     idx_rows, val_rows = _stage_rows(features, dims)
-    width = pad_to_bucket(max((len(r) for r in idx_rows), default=1))
+    width = pad_to_bucket(longest_row(idx_rows))
     L = len(vocab)
     state = MulticlassState(
         weights=jnp.zeros((L, dims), dtype=jnp.float32),

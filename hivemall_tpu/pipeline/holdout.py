@@ -72,7 +72,9 @@ class RollingHoldout:
         labels: List[np.ndarray] = []
         for idx, val, lab in batches:
             # per-row arrays, int64 indices — the models.base._stage_rows
-            # pre-parsed convention the engines accept verbatim
+            # pre-parsed convention the engines accept verbatim (batches of
+            # unequal width, so a list; a rectangular pair of [n, lanes]
+            # arrays is accepted there too and stays an array)
             idx_rows.extend(np.asarray(idx, np.int64))
             val_rows.extend(np.asarray(val, np.float32))
             labels.append(np.asarray(lab, np.float32))

@@ -234,7 +234,9 @@ def _is_preparsed(instances) -> bool:
     a LIST is always rows to parse):
 
     - 2-TUPLE ``(idx_rows, val_rows)`` of per-row arrays — the
-      models.base._stage_rows convention;
+      models.base._stage_rows convention; a rectangular pair (two
+      ``[n, lanes]`` arrays) is such a tuple and stays an array through
+      chunking;
     - 3-TUPLE ``(flat_idx, flat_val, lens)`` — the same rows pre-packed
       into flat arrays with per-row lengths, so staging needs no
       per-request concatenate at all.
@@ -1216,7 +1218,8 @@ class ServingEngine:
         ``instances`` is a list of rows, or — for the sparse-row families
         ONLY (other families treat any tuple as a plain sequence of rows)
         — a pre-parsed tuple: ``(idx_rows, val_rows)`` per-row arrays (the
-        ``models.base._stage_rows`` convention) or the flat
+        ``models.base._stage_rows`` convention; two ``[n, lanes]`` arrays
+        are accepted as they are, and chunks of them are views) or the flat
         ``(flat_idx, flat_val, lens)`` packed form (see _is_preparsed)."""
         pre = (isinstance(self.servable, _SparseRowServable)
                and _is_preparsed(instances))

@@ -771,7 +771,9 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
         "apply": "batch_local" if fm else "dense"}
     nnz = sum(len(r) for r in idx)
     (stage,) = by_name["train.stage"]
-    assert stage["args"] == {"form": form, "rows": 64, "nnz": nnz}
+    # ragged rows and text take the row walk: `layout: rect` is a 2-D pair's
+    assert stage["args"] == {"form": form, "layout": "rows", "rows": 64,
+                             "nnz": nnz}
     if form == "text":
         (parse,) = by_name["train.parse"]
         assert parse["args"]["tokens"] == nnz
@@ -858,7 +860,10 @@ def test_train_ffm_commits_the_vocabulary(form):
         "fields": 6, "pairs_per_row": 30, "v_dims": 1 << 12,
         "apply": "batch_local", "row_tile": 16}
     (stage,) = by_name["train.stage"]
-    assert stage["args"] == {"form": form, "rows": 64, "nnz": 64 * 6}
+    # the three [64, 6] arrays stay arrays; the parser returns lists
+    assert stage["args"] == {
+        "form": form, "layout": "rect" if form == "arrays" else "rows",
+        "rows": 64, "nnz": 64 * 6}
     if form == "text":
         assert by_name["train.parse"][0]["args"] == {"tokens": 64 * 6,
                                                      "native": False}
