@@ -188,11 +188,13 @@ def make_batch_train_fn(
             covars = staged_scatter_add(covars, plan, sums[:, pos], counts)
             pos += 1
         new_slots = dict(slots)
-        slot_sums = {}
+        slot_sums = {k: sums[:, pos + i] for i, k in enumerate(scat_slots)}
+        if rule.block_slots is not None:
+            # derived weights: the block is one subgradient
+            # (core/engine.py, DERIVED_W_BLOCK_RULE)
+            slot_sums = rule.block_slots(slot_sums)
         for k in scat_slots:
-            slot_sums[k] = sums[:, pos]
             new_slots[k] = staged_scatter_add(slots[k], plan, slot_sums[k])
-            pos += 1
         if rule.derive_w is not None:
             # dual-averaging weights are a pure per-feature function of the
             # post-update slots — computed per UNIQUE slot, so the dense

@@ -78,6 +78,11 @@ class Rule:
     use_covariance: bool = False
     slot_names: Tuple[str, ...] = ()
     derive_w: Optional[Callable[[Dict[str, jnp.ndarray], jnp.ndarray, dict], jnp.ndarray]] = None
+    # `block_slots(sums) -> increments`: what ONE -mini_batch block adds to
+    # each slot, from the per-feature sums of its fired lanes' `dslots`
+    # (DERIVED_W_BLOCK_RULE, for a rule with `derive_w`). Without it a block
+    # adds the sums themselves.
+    block_slots: Optional[Callable[[Dict[str, jnp.ndarray]], Dict[str, jnp.ndarray]]] = None
     # Scalar running stats threaded through training (e.g. Welford target
     # variance, ref: regression/PassiveAggressiveRegressionUDTF.java preTrain).
     # `pre_row(globals, y) -> globals` runs before each row in scan mode;
@@ -147,6 +152,32 @@ DELTA_SLOT = "__delta_upd"  # per-feature update count since the last mix —
 # at 2^22 and 75% at 2^20, "batch_local" by 2.2x at 2^26 and 5.5x at 2^28
 # (PERF.md section 6, PR 27).
 DENSE_APPLY_BELOW = 256  # table entries per block lane
+
+
+# DERIVED_W_BLOCK_RULE. How a block of B rows updates a rule whose weights
+# are a function of its slots (`Rule.derive_w`: AdaGradRDA). Every row is
+# decided against the block's starting weights, and the block is ONE
+# subgradient: per feature, S = the sum of the block's fired lanes' deltas.
+# `Rule.block_slots` turns those sums into the block's one increment of each
+# slot (AdaGradRDA: u += S, G += S^2, where a sum of rows would give
+# G += sum of squares); w is derived from the updated slots where a fired
+# row carries the feature and kept elsewhere. It is mini-batch dual
+# averaging as published (the batch's mean gradient g = S / B is one step:
+# u += g, G += g^2, t += 1; Dekel, Gilad-Bachrach, Shamir, Xiao, JMLR 2012;
+# Duchi, Hazan, Singer, JMLR 2011) written in sums: multiply u by B, G by
+# B^2 and t by B and `derive_w` gives the same weight. So `derive_w` sees
+# the ROW counter at the block's end, `t0 + B`: the state's one counter
+# stays rows x epochs, lambda keeps its per-row meaning (|u| / rows against
+# lambda, as under the row rule), and B = 1 is upstream's row rule bit for
+# bit. A weight keeps the t of its last firing block. Summing the squares
+# instead (the step before PR 34) hands a feature that every row carries B
+# steps at once with no word from the loss in between: at B = 1024 the
+# model is a coin (PERF.md section 4). Upstream updates this learner a row
+# at a time, so results differ from upstream's for the same SQL wherever
+# B > 1. Slots of a rule without `block_slots` (adagrad_regr,
+# adadelta_regr: their dw is already meaned) and -mix's pending count keep
+# their sums. The same rule in core/batch_update.py (-batch) and, through
+# this step, in the stripes of parallel/sharded_train.py.
 
 
 def apply_strategy(dims: int, lanes: int) -> str:
@@ -363,8 +394,12 @@ def make_train_fn(
                 count = sums["count"]
                 denom = jnp.maximum(count, 1.0)
                 w_new = old["w"] + sums["w"] / denom
-                # optimizer slots are summed, not averaged, as in every mode
+                # optimizer slots take a block's sum; a rule whose weights
+                # are derived from them says what one block adds
+                # (DERIVED_W_BLOCK_RULE)
                 slot_sums = dict(sums["slots"])
+                if rule.block_slots is not None:
+                    slot_sums = rule.block_slots(slot_sums)
                 if track_deltas:
                     slot_sums[DELTA_SLOT] = count
                 sl_new = {k: v + slot_sums[k] if k in slot_sums else v
@@ -415,10 +450,22 @@ def make_train_fn(
                 covars = (covars.astype(acc) + dc_sum / denom) \
                     .astype(covars.dtype)
         with jax.named_scope(SCOPE_APPLY):
-            for k in rule.slot_names:
-                if k in outs.dslots:
-                    new_slots[k] = slots[k].at[sidx].add(
-                        outs.dslots[k].astype(slots[k].dtype), mode="drop")
+            if rule.block_slots is not None:
+                # the block's one increment from its per-feature sums
+                # (DERIVED_W_BLOCK_RULE)
+                ds = rule.block_slots({
+                    k: jnp.zeros(slots[k].shape, acc).at[sidx].add(
+                        d.astype(acc), mode="drop")
+                    for k, d in outs.dslots.items()})
+                for k, d in ds.items():
+                    new_slots[k] = (slots[k].astype(acc) + d) \
+                        .astype(slots[k].dtype)
+            else:
+                for k in rule.slot_names:
+                    if k in outs.dslots:
+                        new_slots[k] = slots[k].at[sidx].add(
+                            outs.dslots[k].astype(slots[k].dtype),
+                            mode="drop")
             if rule.derive_w is not None:
                 # Dual-averaging weights are a pure function of the
                 # *updated* accumulators: one more pass over the table,

@@ -83,6 +83,15 @@ def init_linear_state(
     )
 
 
+def linear_tables(state: LinearState) -> Dict[str, jnp.ndarray]:
+    """The state's `[D]` tables by name: `weights`, `covars` where the rule
+    has them, each optimizer slot, `touched`."""
+    tables = {"weights": state.weights}
+    if state.covars is not None:
+        tables["covars"] = state.covars
+    return {**tables, **state.slots, "touched": state.touched}
+
+
 def model_rows(state: LinearState, filter_zero: bool = False):
     """Dump the model as (feature, weight[, covar]) arrays over touched
     entries — the close() model emission (ref: BinaryOnlineClassifierUDTF.java:254-291).

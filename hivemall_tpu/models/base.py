@@ -30,7 +30,8 @@ from ..core.batch import (is_rect, iter_blocks, longest_row, pack_rows,
                           pad_to_bucket, shuffle_rows)
 from ..core.engine import (Rule, apply_strategy, make_predict,
                            make_train_step)
-from ..core.state import LinearState, init_linear_state, model_rows
+from ..core.state import (LinearState, init_linear_state, linear_tables,
+                          model_rows)
 from ..ops.convergence import ConversionState
 from ..runtime.metrics import REGISTRY, _jit_cache_size
 from ..runtime.tracing import (SPAN_CALL, SPAN_COMPILED_STEP, SPAN_DATA_PREP,
@@ -170,11 +171,19 @@ def stage_training_rows(features: FeatureRows, dims: int, replicas: int = 1,
 
 def init_state_spanned(init, *args, **kw):
     """`init(*args, **kw)` under a `train.init_state` span that carries the
-    new state's bytes."""
+    new state's bytes; a `LinearState`'s also by table
+    (`state_bytes_by_table`: `weights`, `covars`, each slot, `touched`), and
+    its slot tables' bytes go to the `train.slot_bytes` counter."""
     with TRACER.span(SPAN_INIT_STATE) as sp:
         state = init(*args, **kw)
         sp.set(state_bytes=sum(x.nbytes
                                for x in jax.tree_util.tree_leaves(state)))
+        if isinstance(state, LinearState):
+            by_table = {name: int(table.nbytes)
+                        for name, table in linear_tables(state).items()}
+            sp.set(state_bytes_by_table=by_table)
+            REGISTRY.counter("train", "slot_bytes").increment(
+                sum(by_table[k] for k in state.slots))
     return state
 
 
@@ -443,7 +452,9 @@ def fit_linear(
 
     The whole call is one `train.call` span (docs/observability.md): option
     reads and backend refusals are its self time, everything else a child."""
-    with TRACER.span(SPAN_CALL, args={"entry": rule.name}) as call:
+    with TRACER.span(SPAN_CALL, args={
+            "entry": rule.name, "slots": list(rule.slot_names),
+            "derive_w": rule.derive_w is not None}) as call:
         return _fit_linear(call, rule, hyper, cl, features, labels,
                            label_map, initial_weights, initial_covars,
                            default_dims, pallas_interpret)

@@ -280,11 +280,19 @@ def _adagrad_rda_derive_w(slots, t, hyper):
     return jnp.where(mog < 0.0, 0.0, w)
 
 
+def _adagrad_rda_block_slots(sums):
+    # a block is one subgradient, the sum of its fired rows': G takes its
+    # square (core/engine.py, DERIVED_W_BLOCK_RULE)
+    g = sums["sum_grad"]
+    return {"sum_grad": g, "sum_sqgrad": g * g}
+
+
 ADAGRAD_RDA = Rule(
     "adagrad_rda",
     _adagrad_rda_update,
     slot_names=("sum_grad", "sum_sqgrad"),
     derive_w=_adagrad_rda_derive_w,
+    block_slots=_adagrad_rda_block_slots,
     slot_merge=(("sum_grad", "sum"), ("sum_sqgrad", "sum")),
 )
 

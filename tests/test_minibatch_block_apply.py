@@ -7,7 +7,8 @@ payload, segmented scan, in-place writes of the touched entries:
 core/engine.py, ops/scatter.reduce_block_runs). The reference here is the
 dense formula, written on whole `[D]` arrays in numpy: per-feature sums and
 fired counts, `new = old + sum / max(count, 1)`, one rounding to the table's
-storage type. The rule's own arithmetic is not under test: the reference
+storage type (slots take the sum, or what the rule's `block_slots` makes of
+the sums). The rule's own arithmetic is not under test: the reference
 calls the same `rule.update` on rows it gathered itself. Every block here is
 small enough for its table that the block-local strategy runs; the last
 tests hold the two strategies against each other.
@@ -148,8 +149,14 @@ def _dense_reference(rule, hyper, state, idx, val, y, track):
     if rule.use_covariance and outs.dcov is not None:
         ref["covars"] = f64(state.covars) + _sum_at(idx, outs.dcov) / denom
     slots = {k: f64(v) for k, v in state.slots.items()}
-    for k, d in outs.dslots.items():
-        slots[k] = slots[k] + _sum_at(idx, d)
+    # slots take the block's sums; a rule that derives w from its slots says
+    # what one block adds (core/engine.py, DERIVED_W_BLOCK_RULE)
+    adds = {k: _sum_at(idx, d) for k, d in outs.dslots.items()}
+    if rule.block_slots is not None:
+        adds = {k: np.asarray(v, np.float64)
+                for k, v in rule.block_slots(adds).items()}
+    for k, d in adds.items():
+        slots[k] = slots[k] + d
     if track:
         slots[DELTA_SLOT] = slots[DELTA_SLOT] + counts
     if rule.derive_w is not None:

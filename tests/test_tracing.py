@@ -686,7 +686,11 @@ CALL_PARENTS = {
 EMIT_PARENTS = {"emit.model_rows": None, "emit.d2h": "emit.model_rows",
                 "emit.select": "emit.model_rows"}
 CALL_COUNTERS = ("train.h2d_bytes", "train.parse_tokens", "train.jit_compiles",
-                 "emit.d2h_bytes", "emit.rows")
+                 "train.slot_bytes", "emit.d2h_bytes", "emit.rows")
+# a linear entry's rule: its name on `train.call`, its slots, derived weights
+LINEAR_RULES = {"train_arow": ("arow", [], False),
+                "train_adagrad_rda": ("adagrad_rda",
+                                      ["sum_grad", "sum_sqgrad"], True)}
 
 
 def _rows(form, n=64, dims=256, seed=0):
@@ -738,6 +742,9 @@ def _sum(trace, name, key):
     ("train_arow", "-dims 256 -mini_batch 16", 1, 0),
     ("train_fm", "-c -factor 10 -dims 256 -mini_batch 16 -iters 2 -disable_cv",
      2, 1),
+    # a slot-carrying rule with derived weights: `slots`, `derive_w`, the
+    # state's bytes by table and the `train.slot_bytes` counter
+    ("train_adagrad_rda", "-dims 256 -mini_batch 16", 1, 0),
 ])
 def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
                                            form):
@@ -762,13 +769,18 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     assert len(by_name["train.sync"]) == (steps if fm else epochs)
     assert _sum(call, "train.sync", "fetches") == steps
     (root,) = by_name["train.call"]
-    assert root["args"] == {
-        "entry": "fm" if fm else "arow", "dims": 256, "rows": 64,
+    rule_name, slots, derived = ("fm", None, None) if fm \
+        else LINEAR_RULES[entry]
+    want_args = {
+        "entry": rule_name, "dims": 256, "rows": 64,
         "mini_batch": 16, "epochs": epochs, "mode": "minibatch",
         "table_dtype": "float32",
         # the step's way with a block: a 256-entry table is small for the
         # linear step's shape rule; FM has one plan at every shape
         "apply": "batch_local" if fm else "dense"}
+    if not fm:   # fit_linear says what the rule keeps beside its weights
+        want_args.update(slots=slots, derive_w=derived)
+    assert root["args"] == want_args
     nnz = sum(len(r) for r in idx)
     (stage,) = by_name["train.stage"]
     # ragged rows and text take the row walk: `layout: rect` is a 2-D pair's
@@ -777,7 +789,21 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     if form == "text":
         (parse,) = by_name["train.parse"]
         assert parse["args"]["tokens"] == nnz
-    assert by_name["train.init_state"][0]["args"]["state_bytes"] > 256 * 4
+    init_args = by_name["train.init_state"][0]["args"]
+    assert init_args["state_bytes"] > 256 * 4
+    if fm:
+        assert "state_bytes_by_table" not in init_args
+        assert counted["train.slot_bytes"] == 0
+    else:
+        # float32 tables of 256 entries, the int8 flags; AROW's covariances
+        tables = ["weights"] + (["covars"] if entry == "train_arow" else []) \
+            + slots
+        assert init_args["state_bytes_by_table"] == dict(
+            {t: 1024 for t in tables}, touched=256)
+        assert counted["train.slot_bytes"] == 1024 * len(slots)
+        # all of the state but its two scalars' few bytes
+        assert 0 <= init_args["state_bytes"] - sum(
+            init_args["state_bytes_by_table"].values()) <= 16
     # h2d_bytes is the nbytes of what each step was handed: [16, 8] int32
     # ids and float32 values, 16 labels, and FM's 16-row validation mask
     block = 16 * 8 * 4 * 2 + 16 * 4 + (16 * 4 if fm else 0)
@@ -800,12 +826,16 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     # the mask comes over, then the emitted entries' values a chunk at a
     # time (core/emission.py): never a whole table
     tables = {s["args"]["table"] for s in by_name["emit.d2h"]}
+    # close() emits the weights, and the covariances where the rule has
+    # them: a slot-carrying rule's sums stay on the device
+    cov = entry == "train_arow"
     assert tables == ({"mask", "w", "v", "w0"} if fm
-                      else {"mask", "weights", "covars"})
+                      else {"mask", "weights", "covars"} if cov
+                      else {"mask", "weights"})
     assert emit_root["args"]["select"] == "device"
     assert emit_root["args"]["chunks"] == 1
     assert emit_root["args"]["h2d_bytes"] == 256 * 4
-    row = 4 + 16 * 4 if fm else 4 + 4
+    row = 4 + 16 * 4 if fm else 4 + 4 if cov else 4
     assert d2h == 256 // 8 + 256 * row + (4 if fm else 0)
 
 
