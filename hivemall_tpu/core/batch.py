@@ -47,6 +47,16 @@ def pad_to_bucket(k: int, min_width: int = 8) -> int:
     return w
 
 
+def fillable_lanes(longest: int, width: int) -> int:
+    """The leading lanes of a `width`-lane block that a call's rows can
+    fill: the longest row rounded up to a multiple of 8 (`train_ffm`'s
+    `pair_width` ladder). `pack_rows` fills a row from lane 0, so the lanes
+    beyond hold the padding id in every row, and a `-mini_batch` step built
+    with this count cuts them off before it gathers (39 features on the
+    64-lane bucket: 40)."""
+    return min(width, max(8, -(-longest // 8) * 8))
+
+
 def bucket_rows(x, min_rows: int = 8):
     """Pad an array's leading (row) axis up to the bucket ladder
     (``pad_to_bucket``): the shape canonicalizer for feeding a

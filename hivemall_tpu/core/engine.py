@@ -519,6 +519,33 @@ def make_train_step(
     return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
+def make_cut_step(step, lanes: int):
+    """`step(state, indices, values, *rest)` as one jitted program that
+    cuts its `[B, K]` block to the `lanes` leading lanes first, so that
+    nothing of the step gathers, sorts, scans or writes a lane beyond them.
+
+    For a `-mini_batch` step whose call's rows fill only so many lanes of
+    the block's power-of-two bucket (core/batch.py::fillable_lanes: 39
+    features on 64 lanes work on 40): `pack_rows` fills a row from lane 0,
+    so the lanes beyond hold the padding id in every row and add no delta,
+    no count and no flag, while a gather costs a padding lane what it costs
+    a real one and XLA's sorted write pays for a dropped lane too (PERF.md
+    section 5). The slice is static and the step is traced inside it, so
+    `apply_strategy` and every shape in the step see the cut block; the
+    state is donated as the step's own jit donates it. A jitted `step` is
+    traced through its own function (`__wrapped__`), one flat module: a jit
+    called inside this one runs the same device program, and cost a
+    2^17-row `train_arow` call 50 ms more on the chip machine's host
+    (PERF.md section 6, PR 35).
+    """
+    inner = getattr(step, "__wrapped__", step)
+
+    def cut(state, indices, values, *rest):
+        return inner(state, indices[:, :lanes], values[:, :lanes], *rest)
+
+    return jax.jit(cut, donate_argnums=(0,))
+
+
 def make_epoch(step_fn, donate: bool = True):
     """Whole-epoch driver: ONE jitted `lax.scan` of `step_fn` over a stack of
     HBM-staged blocks — the framework's deployment shape (io/records.py

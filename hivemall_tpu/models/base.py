@@ -26,10 +26,10 @@ import jax
 import numpy as np
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import (is_rect, iter_blocks, longest_row, pack_rows,
-                          pad_to_bucket, shuffle_rows)
+from ..core.batch import (fillable_lanes, is_rect, iter_blocks, longest_row,
+                          pack_rows, pad_to_bucket, shuffle_rows)
 from ..core.engine import (Rule, apply_strategy, make_predict,
-                           make_train_step)
+                           make_train_step, make_cut_step)
 from ..core.state import (LinearState, init_linear_state, linear_tables,
                           model_rows)
 from ..ops.convergence import ConversionState
@@ -498,6 +498,7 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
 
     batch_b = cl.get_int("batch", 0) if cl.has("batch") else 0
     mode = "minibatch" if mini_batch > 1 else "scan"
+    lanes = width   # only the -mini_batch step cuts a block
     if cl.has("batch"):
         if batch_b < 1:
             raise ValueError(f"-batch must be >= 1: {batch_b}")
@@ -565,11 +566,16 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
         # guard when the first block is traced (vmem_resident_reason)
         step = make_pallas_scan_step(rule, hyper, interpret=pallas_interpret)
     else:
-        step = make_train_step(rule, hyper, mode=mode)
         if mode == "minibatch":
-            # which way the step applies a block: the same static test of
-            # shapes the step makes when it is traced
-            call.set(apply=apply_strategy(dims, block_size * width))
+            # the step works on the lanes this call's rows can fill; which
+            # way it applies a block is the same static test of shapes the
+            # step makes when it is traced
+            lanes = fillable_lanes(longest_row(idx_rows), width)
+            call.set(apply=apply_strategy(dims, block_size * lanes),
+                     width=width, lanes=lanes)
+        step = make_train_step(rule, hyper, mode=mode)
+        if lanes < width:
+            step = make_cut_step(step, lanes)
     state = init_state_spanned(
         init_linear_state,
         dims,
@@ -587,6 +593,7 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
     # (ref: UDTFWithOptions.java:59-88, FM iteration counter :529-543)
     iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
     row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
+    cut_counter = REGISTRY.counter("train", "lanes_cut")
     # -batch: plans are a pure function of each block's indices, so they
     # are staged on the host once and replayed every epoch (cleared when
     # -shuffle re-deals the rows)
@@ -621,6 +628,7 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
                 step_no += 1
                 epoch_losses.append(loss)
                 row_counter.increment(block[0].shape[0])
+                cut_counter.increment(block[0].shape[0] * (width - lanes))
             iter_counter.increment()
             with TRACER.span(SPAN_SYNC,
                              args={"fetches": len(epoch_losses)}):
@@ -672,17 +680,23 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
     if n == 0:
         raise ValueError("no training rows")
     label_shares = [labels[lo:hi] for lo, hi in deal_rows(n, replicas)]
+    lanes = fillable_lanes(max(longest_row(s) for s in idx_shares), width)
     trainer = MixedReplicas(rule, hyper, dims, table_dtype(dims, cl),
                             mix_devices())
+    # the replicas' blocks lie end to end along the rows: one cut serves all
+    step = make_cut_step(trainer.step, lanes) if lanes < width \
+        else trainer.step
     call.set(dims=dims, rows=n, mini_batch=mini_batch, mode="minibatch",
-             apply=apply_strategy(dims, mini_batch * width), replicas=replicas,
-             mix_every=mix_every, reduction=trainer.reduction)
+             apply=apply_strategy(dims, mini_batch * lanes), width=width,
+             lanes=lanes, replicas=replicas, mix_every=mix_every,
+             reduction=trainer.reduction)
     state = init_state_spanned(trainer.init, initial_weights, initial_covars)
     call.set(table_dtype=str(state.weights.dtype))
 
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
     row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
+    cut_counter = REGISTRY.counter("train", "lanes_cut")
     step_no = round_no = 0
     for it in range(max(1, iters)):
         with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
@@ -706,10 +720,12 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
             for block in prepared_replica_blocks(
                     idx_shares, val_shares, label_shares, dims, mini_batch,
                     width):
-                state, loss = dispatch_step(trainer.step, step_no, state, *block)
+                state, loss = dispatch_step(step, step_no, state, *block)
                 step_no += 1
                 losses.append(loss)
-                row_counter.increment(int(block[3].sum()))
+                real_rows = int(block[3].sum())
+                row_counter.increment(real_rows)
+                cut_counter.increment(real_rows * (width - lanes))
                 pending = (pending + 1) % mix_every
                 if not pending:
                     state = mix_round(state, trailing=False)

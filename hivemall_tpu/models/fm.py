@@ -32,9 +32,10 @@ import numpy as np
 from flax import struct
 
 from ..constants import DEFAULT_NUM_FEATURES
-from ..core.batch import (iter_blocks, longest_row, pad_to_bucket,
-                          shuffle_rows)
+from ..core.batch import (fillable_lanes, iter_blocks, longest_row,
+                          pad_to_bucket, shuffle_rows)
 from ..core.emission import select_rows, table_to_host
+from ..core.engine import make_cut_step
 from ..ops.convergence import ConversionState
 from ..ops.eta import EtaEstimator, get_eta
 from ..ops.scatter import reduce_block_runs, scatter_rows_flat, write_runs
@@ -512,13 +513,18 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
     block = mini_batch if mode == "minibatch" else cl.get_int("block_size", 4096)
     iters = cl.get_int("iters", 1)
     call.set(dims=dims, rows=n, mini_batch=mini_batch, mode=mode)
+    lanes = width   # the scan works on the block as it comes
     if mode == "minibatch":
-        # the one plan `make_fm_step` has off a `feature_shard` stripe
-        call.set(apply="batch_local")
+        # the one plan `make_fm_step` has off a `feature_shard` stripe, on
+        # the lanes of the block that this call's rows can fill
+        lanes = fillable_lanes(longest_row(idx_rows), width)
+        call.set(apply="batch_local", width=width, lanes=lanes)
     if cl.has("native_scan"):
         return _train_fm_native_scan(cl, hyper, dims, idx_rows, val_rows,
                                      targets, width, block, mode, iters)
     step = make_fm_step(hyper, mode)
+    if lanes < width:
+        step = make_cut_step(step, lanes)
     state = init_state_spanned(init_fm_state, dims, hyper)
     call.set(table_dtype=str(state.v.dtype))
     rng = np.random.RandomState(hyper.seed)
@@ -532,6 +538,7 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
     # progress counters, as fit_linear keeps them
     iter_counter = REGISTRY.counter("hivemall", "fm.iterations")
     row_counter = REGISTRY.counter("hivemall", "fm.examples")
+    cut_counter = REGISTRY.counter("train", "lanes_cut")
     step_no = 0
     for it in range(max(1, iters)):
         with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
@@ -548,6 +555,7 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
                 with TRACER.span(SPAN_SYNC, args={"fetches": 1}):
                     epoch_loss += float(loss)
                 row_counter.increment(blk[0].shape[0])
+                cut_counter.increment(blk[0].shape[0] * (width - lanes))
             iter_counter.increment()
             epoch.set(steps=steps)
             call.set(epochs=it + 1)

@@ -9,7 +9,11 @@ compared step by step: a refactor that claims "the same programs" shows it.
 
 Nothing runs and nothing is allocated: states are `jax.eval_shape` shapes, on
 the CPU backend with eight virtual devices (the mesh steps need four). Only
-arguments that every tree since PR 26 accepts are passed to the factories.
+arguments that every tree since PR 26 accepts are passed to the factories,
+but for the `.lanes40` variants: the step of a call whose longest row has 39
+features, which a tree since PR 35 runs inside `engine.make_cut_step(step,
+40)` and an older tree, which has no such function, runs as it is (so these
+differ across that line, and nothing else should).
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ def _variants():
     from jax.sharding import Mesh
     import numpy as np
 
+    from hivemall_tpu.core import engine
     from hivemall_tpu.core.engine import (DELTA_SLOT, make_train_fn,
                                           make_train_step)
     from hivemall_tpu.core.state import init_linear_state
@@ -49,6 +54,10 @@ def _variants():
         return jax.eval_shape(lambda: init_linear_state(
             dims, use_covariance=rule.use_covariance, slot_names=slots,
             global_names=rule.global_names, dtype=dtype))
+
+    def cut(step):   # what a call with 39 features a row does to its step
+        wrap = getattr(engine, "make_cut_step", None)
+        return wrap(step, 40) if wrap else step
 
     out = {}
 
@@ -88,6 +97,17 @@ def _variants():
         out[f"engine.scan.{name}.track_deltas"] = jax.jit(make_train_fn(
             rule, hyper, mode="scan", track_deltas=True)).lower(
                 lin_state(rule, 1 << 20, jnp.float32, True), *block(256, 64))
+    # the cells' steps on Criteo's 39 features: AROW at 2^28 (replay and
+    # text), AdaGradRDA at 2^29, and a small table, where the cut lane count
+    # moves the shape rule's choice (2^24: dense on 64 lanes, batch_local on 40)
+    for name, arm, dims, dtype in (
+            ("arow", "batch_local", 1 << 28, jnp.bfloat16),
+            ("adagrad_rda", "batch_local_2p29", 1 << 29, jnp.bfloat16),
+            ("arow", "f32_2p24", 1 << 24, jnp.float32)):
+        rule, hyper = linear[name]
+        out[f"engine.minibatch.{name}.{arm}.lanes40"] = cut(make_train_step(
+            rule, hyper, mode="minibatch")).lower(
+                lin_state(rule, dims, dtype), *block(1024, 64))
 
     # FM: the cell's step (k=10 in 16 lanes), k=8 (no pad lane), the scan,
     # with regression and adareg
@@ -108,6 +128,10 @@ def _variants():
     out["fm.scan.k10"] = make_fm_step(h, "scan").lower(
         jax.eval_shape(lambda: init_fm_state(1 << 23, h)),
         *block(4096, 64), S((4096,), jnp.float32))
+    out["fm.minibatch.k10.c.lanes40"] = cut(make_fm_step(
+        h, "minibatch")).lower(
+            jax.eval_shape(lambda: init_fm_state(1 << 23, h)),
+            *block(1024, 64), va)
 
     # FFM: mini-batch with and without -row_chunk at two table sizes (the
     # tags name the two arms the step had before PR 32), and the scan
@@ -133,6 +157,8 @@ def _variants():
                S((4 * 1024,), jnp.float32), S((4,), jnp.int32))
         out[f"mix.replica_step.{name}"] = mr.step.lower(state, *blk)
         out[f"mix.mix_round.{name}"] = mr.mix.lower(state)
+        out[f"mix.replica_step.{name}.lanes40"] = cut(mr.step).lower(
+            state, *blk)
 
     # feature_shard: one stripe step each of the engine and FM
     mesh = Mesh(np.array(devs), ("shard",))

@@ -777,7 +777,9 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
         "table_dtype": "float32",
         # the step's way with a block: a 256-entry table is small for the
         # linear step's shape rule; FM has one plan at every shape
-        "apply": "batch_local" if fm else "dense"}
+        "apply": "batch_local" if fm else "dense",
+        # rows of 3 to 7 features: the 8-lane bucket, and nothing to cut
+        "width": 8, "lanes": 8}
     if not fm:   # fit_linear says what the rule keeps beside its weights
         want_args.update(slots=slots, derive_w=derived)
     assert root["args"] == want_args
