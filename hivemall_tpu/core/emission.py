@@ -13,7 +13,8 @@ Three steps (`select_rows`), shared by `core.state.model_rows` and
    ones are expanded to the ascending ids, a thread a run of words.
 3. `_gather_rows` (device): each table read at the sorted ids, a chunk of
    `GATHER_CHUNK` ids a dispatch, every chunk dispatched before the first
-   is fetched.
+   is fetched (`emit.gather`), then each fetched and placed in the values
+   (`emit.assemble`, the copies its `emit.d2h` children).
 
 Shapes depend on `dims`, the tables' dtypes and `GATHER_CHUNK`, never on
 how many rows come out, so the first `model_rows()` of a process compiles
@@ -31,7 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..runtime.metrics import REGISTRY
-from ..runtime.tracing import SPAN_EMIT_D2H, SPAN_EMIT_SELECT, TRACER
+from ..runtime.tracing import (SPAN_EMIT_ASSEMBLE, SPAN_EMIT_D2H,
+                               SPAN_EMIT_GATHER, SPAN_EMIT_SELECT, TRACER)
 
 MASK_BITS = 32
 # Ids a gather dispatch. A sorted gather is 14 ns a lane on a v5e, so the
@@ -172,15 +174,22 @@ def select_rows(touched, tables: Sequence[Tuple[str, object]],
 
     # every chunk is dispatched before the first is fetched, so that the
     # copies overlap the gathers
-    pieces = [_gather_rows(arrays, ids_of(lo)) for lo in range(0, rows, chunk)]
-    for piece in pieces:
-        for value in piece:
-            value.copy_to_host_async()
-    values = [np.empty((rows,) + t.shape[1:], t.dtype) for t in arrays]
-    for k, piece in enumerate(pieces):
-        lo = k * chunk
-        for (name, _), out, value in zip(tables, values, piece):
-            out[lo:lo + chunk] = table_to_host(value, name, stats)[:rows - lo]
+    with TRACER.span(SPAN_EMIT_GATHER) as gather:
+        pieces = [_gather_rows(arrays, ids_of(lo))
+                  for lo in range(0, rows, chunk)]
+        for piece in pieces:
+            for value in piece:
+                value.copy_to_host_async()
+        h2d = len(pieces) * chunk * 4
+        gather.set(chunks=len(pieces), h2d_bytes=h2d)
+    with TRACER.span(SPAN_EMIT_ASSEMBLE) as assemble:
+        values = [np.empty((rows,) + t.shape[1:], t.dtype) for t in arrays]
+        for k, piece in enumerate(pieces):
+            lo = k * chunk
+            for (name, _), out, value in zip(tables, values, piece):
+                out[lo:lo + chunk] = table_to_host(value, name,
+                                                   stats)[:rows - lo]
+        assemble.set(bytes=sum(v.nbytes for v in values))
     REGISTRY.counter("emit", "gather_chunks").increment(len(pieces))
-    stats.update(chunks=len(pieces), h2d_bytes=len(pieces) * chunk * 4)
+    stats.update(chunks=len(pieces), h2d_bytes=h2d)
     return feats, values, stats

@@ -34,9 +34,10 @@ from ..core.state import (LinearState, init_linear_state, linear_tables,
                           model_rows)
 from ..ops.convergence import ConversionState
 from ..runtime.metrics import REGISTRY, _jit_cache_size
-from ..runtime.tracing import (SPAN_CALL, SPAN_COMPILED_STEP, SPAN_DATA_PREP,
-                               SPAN_EPOCH, SPAN_INIT_STATE, SPAN_MIX,
-                               SPAN_SHARD_ROWS, SPAN_STAGE, SPAN_SYNC, TRACER)
+from ..runtime.tracing import (SPAN_BUILD, SPAN_CALL, SPAN_COLLAPSE,
+                               SPAN_COMPILED_STEP, SPAN_DATA_PREP, SPAN_EPOCH,
+                               SPAN_INIT_STATE, SPAN_MIX, SPAN_SHARD_ROWS,
+                               SPAN_STAGE, SPAN_SYNC, TRACER)
 from ..utils.feature import parse_features_batch
 from ..utils.options import CommandLine, Options
 
@@ -252,7 +253,10 @@ def dispatch_step(step, step_no: int, *args):
 
 def dispatch_spanned(span: str, span_args: dict, program, *args):
     """`dispatch_step` under any span of the vocabulary (`-mix` dispatches
-    its mix rounds under `train.mix`)."""
+    its mix rounds under `train.mix` and its collapse under
+    `train.collapse`). Where the jit was fresh, the tracer's compile
+    listeners put the `train.jit_trace`, `train.jit_lower` and
+    `train.jit_compile` child spans inside."""
     with TRACER.span(span, args=span_args) as sp:
         before = _jit_cache_size(program)
         out = program(*args)
@@ -681,11 +685,15 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
         raise ValueError("no training rows")
     label_shares = [labels[lo:hi] for lo, hi in deal_rows(n, replicas)]
     lanes = fillable_lanes(max(longest_row(s) for s in idx_shares), width)
-    trainer = MixedReplicas(rule, hyper, dims, table_dtype(dims, cl),
-                            mix_devices())
-    # the replicas' blocks lie end to end along the rows: one cut serves all
-    step = make_cut_step(trainer.step, lanes) if lanes < width \
-        else trainer.step
+    # the replicas' programs, each a fresh jit: the step, the mix round and
+    # the collapse, and the cut around the step
+    with TRACER.span(SPAN_BUILD, args={"replicas": replicas}) as build:
+        trainer = MixedReplicas(rule, hyper, dims, table_dtype(dims, cl),
+                                mix_devices())
+        # the replicas' blocks lie end to end along the rows: one cut for all
+        step = make_cut_step(trainer.step, lanes) if lanes < width \
+            else trainer.step
+        build.set(jits=3 + (lanes < width))
     call.set(dims=dims, rows=n, mini_batch=mini_batch, mode="minibatch",
              apply=apply_strategy(dims, mini_batch * lanes), width=width,
              lanes=lanes, replicas=replicas, mix_every=mix_every,
@@ -749,8 +757,9 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
                 break
     # every epoch ended in a mix: weights and covariances are equal on every
     # replica, and one of them is the model
-    return TrainedLinearModel(state=trainer.collapse(state), rule=rule,
-                              dims=dims, block_width=width)
+    state = dispatch_spanned(SPAN_COLLAPSE, {}, trainer.collapse, state)
+    return TrainedLinearModel(state=state, rule=rule, dims=dims,
+                              block_width=width)
 
 
 def binary_label_map(labels: np.ndarray) -> np.ndarray:

@@ -596,16 +596,27 @@ class MixedReplicas:
         state_spec = jax.tree.map(lambda _: spec, one)
         merged_spec = jax.tree.map(lambda _: spec, merged_one)
         smap = partial(shard_map, mesh=self.mesh)
-        self._merged_one, self._state_spec = merged_one, state_spec
+        self._state_spec = state_spec
         self.step = jax.jit(
             smap(replica_step, in_specs=(state_spec, spec, spec, spec, spec),
                  out_specs=(state_spec, spec)), donate_argnums=(0,))
         self.mix = jax.jit(
             smap(mix_round, in_specs=(state_spec,),
                  out_specs=(state_spec, P())), donate_argnums=(0,))
-        self._collapse = jax.jit(
+        merge = jax.jit(
             smap(collapse_replicas, in_specs=(state_spec,),
                  out_specs=merged_spec), donate_argnums=(0,))
+
+        def collapse(state: LinearState) -> LinearState:
+            """One model, on the first device: see the class's description."""
+            return jax.tree.map(
+                lambda x, a: x.addressable_shards[0].data.reshape(a.shape),
+                merge(state), merged_one)
+
+        # a caller that spans the dispatch (models/base.py::dispatch_spanned)
+        # reads a fresh compile off the jit's own cache
+        collapse._cache_size = merge._cache_size
+        self.collapse = collapse
 
     def _init_one(self, delta_slot: bool = True, initial_weights=None,
                   initial_covars=None) -> LinearState:
@@ -630,13 +641,6 @@ class MixedReplicas:
         return jax.jit(shard_map(
             init_replicas, mesh=self.mesh, in_specs=(P(),) * len(warm),
             out_specs=self._state_spec))(*warm)
-
-    def collapse(self, state: LinearState) -> LinearState:
-        """One model, on the first device: see the class's description."""
-        merged = self._collapse(state)
-        return jax.tree.map(
-            lambda x, a: x.addressable_shards[0].data.reshape(a.shape),
-            merged, self._merged_one)
 
 
 def _replica_in(state, one):

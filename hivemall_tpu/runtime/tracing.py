@@ -91,13 +91,22 @@ SPAN_INIT_STATE = "train.init_state"
 SPAN_EPOCH = "train.epoch"
 SPAN_DATA_PREP = "train.data_prep"
 SPAN_COMPILED_STEP = "train.compiled_step"
+# a compile's phases, under whichever span dispatched the fresh jit: opened
+# and closed by jax.monitoring's callbacks (`_bind_compile_listeners`)
+SPAN_JIT_TRACE = "train.jit_trace"
+SPAN_JIT_LOWER = "train.jit_lower"
+SPAN_JIT_COMPILE = "train.jit_compile"
 SPAN_SYNC = "train.sync"
 # `-mix` (parallel/mix.py::MixedReplicas): one replica a local device
 SPAN_SHARD_ROWS = "train.shard_rows"  # under train.stage: rows dealt to replicas
 SPAN_MIX = "train.mix"                # under train.epoch: one mix round's dispatch
+SPAN_BUILD = "train.build"            # under train.call: the replicas' programs made
+SPAN_COLLAPSE = "train.collapse"      # under train.call: one model out of the replicas
 SPAN_EMIT = "emit.model_rows"
 SPAN_EMIT_D2H = "emit.d2h"
 SPAN_EMIT_SELECT = "emit.select"
+SPAN_EMIT_GATHER = "emit.gather"      # every chunk's ids sent up and gather dispatched
+SPAN_EMIT_ASSEMBLE = "emit.assemble"  # the chunks fetched (emit.d2h) and placed
 
 SCOPE_PACK_TABLES = "hm.pack_tables"  # small tables stacked for a paired gather
 SCOPE_GATHER = "hm.gather"            # table reads at the block's ids
@@ -122,16 +131,19 @@ FM_SCOPES = (SCOPE_GATHER, SCOPE_RULE, SCOPE_REDUCE, SCOPE_APPLY,
 _ID_COUNTER = itertools.count(1)  # __next__ is GIL-atomic: no lock needed
 
 _ANNOTATION = None  # jax.profiler.TraceAnnotation, bound at the first span
+_LISTENING = False  # jax.monitoring's listeners, bound with it, once a process
 
 
 def _annotation(name: str):
     """The profiler's host mark for a span's extent. jax is imported at the
     first span, not with this module: the tracer stays a leaf that adapters
-    import before any backend is chosen."""
+    import before any backend is chosen. A tracer that is disabled opens no
+    span, so it binds neither this nor the compile listeners."""
     global _ANNOTATION
     if _ANNOTATION is None:
         from jax.profiler import TraceAnnotation
 
+        _bind_compile_listeners()
         _ANNOTATION = TraceAnnotation
     return _ANNOTATION(name)
 
@@ -253,6 +265,116 @@ _current: contextvars.ContextVar = contextvars.ContextVar(
     "hivemall_tpu_current_span", default=None)
 
 _UNSET = object()
+
+
+# -- a fresh jit's compile, taken apart --------------------------------------
+# jax reports each phase of a compile through jax.monitoring
+# (jax._src.dispatch.log_elapsed_time): a scalar, the phase's start time,
+# when it starts, and a duration when it ends, both on the dispatching thread.
+# A steady-state dispatch fires neither.
+_PHASE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": SPAN_JIT_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": SPAN_JIT_LOWER,
+    "/jax/core/compile/backend_compile_duration": SPAN_JIT_COMPILE,
+}
+# plain events inside the backend's phase: the persistent cache served the
+# executable, or took the new one. Neither fires where no directory is set,
+# and `cache_misses` not where a compile is under the cache's minimum time
+# or size (nothing is written): both read `off`
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _OpenPhase(threading.local):
+    """The calling thread's open phase span. One at a time: the trace event
+    fires for every inner jitted function of the one being traced (about 90
+    times a step), and an eager op inside a trace compiles within it, so
+    `depth` counts the open phase's own event and no other event opens."""
+
+    span: Optional[Span] = None
+    event: Optional[str] = None
+    depth = 0
+    mark = None        # the span's TraceAnnotation, entered
+
+
+_PHASE = _OpenPhase()
+# `train.compile_cache_hits` / `_misses` by a closed phase's `cache`
+_COMPILE_CACHE_COUNTERS: Dict[str, object] = {}
+
+
+def _phase_started(event: str, value, **kw) -> None:
+    name = _PHASE_SPANS.get(event)
+    if name is None:
+        return
+    ph = _PHASE
+    if ph.span is not None:
+        if event == ph.event:
+            ph.depth += 1
+        return
+    parent = _current.get()
+    if parent is None or not parent.recording:
+        return
+    span = Span(name, parent._trace, parent.span_id, time.perf_counter_ns())
+    span.args["fn"] = kw.get("fun_name", "")
+    if name == SPAN_JIT_COMPILE:
+        span.args["cache"] = "off"   # until a cache event says otherwise
+    ph.span, ph.event, ph.depth = span, event, 1
+    ph.mark = _annotation(name)
+    ph.mark.__enter__()
+
+
+def _phase_ended(event: str, duration_secs: float, **kw) -> None:
+    ph = _PHASE
+    span = ph.span
+    if span is None:   # no recording span at its start, or bound mid-compile
+        return
+    if event != ph.event:
+        if event == _CACHE_RETRIEVAL and span.name == SPAN_JIT_COMPILE:
+            span.args["retrieval_ms"] = duration_secs * 1e3
+        return
+    ph.depth -= 1
+    if ph.depth:
+        return
+    ph.mark.__exit__(None, None, None)
+    span.end_ns = time.perf_counter_ns()
+    span._trace.spans.append(span)
+    ph.span = ph.event = ph.mark = None
+    counter = _COMPILE_CACHE_COUNTERS.get(span.args.get("cache"))
+    if counter is not None:   # `hit` or `miss`: a compile span's alone
+        counter.increment()
+
+
+def _cache_event(event: str, **kw) -> None:
+    cache = _CACHE_EVENTS.get(event)
+    span = _PHASE.span
+    if cache is not None and span is not None \
+            and span.name == SPAN_JIT_COMPILE:
+        span.args["cache"] = cache
+
+
+def _bind_compile_listeners() -> None:
+    """Make every compile under a recording span three child spans of it
+    (`train.jit_trace`, `train.jit_lower`, `train.jit_compile`), each also a
+    TraceAnnotation over the same extent. The callbacks touch the calling
+    thread's state alone: no tracer lock, no IO (the two counters'
+    increments are the only locks taken, once a compile)."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    _LISTENING = True
+    from jax import monitoring
+
+    from .metrics import REGISTRY
+
+    _COMPILE_CACHE_COUNTERS.update(
+        hit=REGISTRY.counter("train", "compile_cache_hits"),
+        miss=REGISTRY.counter("train", "compile_cache_misses"))
+    monitoring.register_scalar_listener(_phase_started)
+    monitoring.register_event_duration_secs_listener(_phase_ended)
+    monitoring.register_event_listener(_cache_event)
 
 
 class Tracer:

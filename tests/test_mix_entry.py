@@ -112,19 +112,24 @@ def test_replicas_are_equal_after_the_trailing_mix(replicas, monkeypatch,
                                                    dims, storage):
     """What lets `model_rows()` copy ONE model: pinned on the replicated
     state as it enters the collapse."""
+    from hivemall_tpu.models import base
+
     replicas(4)
     seen = {}
-    collapse = pmix.MixedReplicas.collapse
+    dispatch = base.dispatch_spanned
 
-    def watch(self, state):
-        seen["w"] = np.asarray(state.weights, np.float32).reshape(4, dims)
-        seen["cov"] = np.asarray(state.covars, np.float32).reshape(4, dims)
-        seen["touched"] = np.asarray(state.touched).reshape(4, dims)
-        seen["pending"] = np.asarray(state.slots["__delta_upd"])
-        seen["step"] = np.asarray(state.step)
-        return collapse(self, state)
+    def watch(span, span_args, program, *args):
+        if span == "train.collapse":
+            (state,) = args
+            seen["w"] = np.asarray(state.weights, np.float32).reshape(4, dims)
+            seen["cov"] = np.asarray(state.covars,
+                                     np.float32).reshape(4, dims)
+            seen["touched"] = np.asarray(state.touched).reshape(4, dims)
+            seen["pending"] = np.asarray(state.slots["__delta_upd"])
+            seen["step"] = np.asarray(state.step)
+        return dispatch(span, span_args, program, *args)
 
-    monkeypatch.setattr(pmix.MixedReplicas, "collapse", watch)
+    monkeypatch.setattr(base, "dispatch_spanned", watch)
     ids, vals, labels = rows(1000, dims, seed=3)
     model, _ = fit("train_arow", ids, vals, labels,
                    f"-dims {dims} -mini_batch 64 -mix h -mix_threshold 3")
@@ -235,7 +240,10 @@ def test_programs_do_not_depend_on_the_rows_and_compile_once_a_call(replicas):
                  if e["name"] == "jit_recompile"])
 
     steps, mixes, recompiles = flags(4 * 64 * 7)
-    assert recompiles == ["train.compiled_step", "train.mix"]
+    # each of the call's three programs on its first dispatch: the step, the
+    # round, and the collapse at the call's end
+    assert recompiles == ["train.compiled_step", "train.mix",
+                          "train.collapse"]
     assert steps == [True] + [False] * 6
     assert [m["compiled"] for m in mixes] == [True, False, False, False]
     assert [m["trailing"] for m in mixes] == [False, False, False, True]
@@ -263,6 +271,27 @@ def test_spans_of_a_mixed_call(replicas):
     assert names.count("train.mix") == 2 and names.count("train.sync") == 1
     sync = next(s for s in trace["spans"] if s["name"] == "train.sync")
     assert sync["args"]["fetches"] == 3 + 2     # losses and due counts, once
+    # the replicas' programs are made under `train.build` (rows of 8 lanes
+    # fill their bucket: no cut around the step), and the one model comes
+    # out under `train.collapse`, a dispatch of a fresh jit like the others
+    (build,) = [s for s in trace["spans"] if s["name"] == "train.build"]
+    (collapse,) = [s for s in trace["spans"] if s["name"] == "train.collapse"]
+    assert parent(build) == parent(collapse) == "train.call"
+    assert build["args"] == {"replicas": 2, "jits": 3}
+    assert collapse["args"] == {"compiled": True}
+    order = [s["name"] for s in sorted(
+        (s for s in trace["spans"] if parent_of(s, spans) == "train.call"),
+        key=lambda s: s["start_us"])]
+    assert order == ["train.stage", "train.build", "train.init_state",
+                     "train.epoch", "train.collapse"]
+    # what the call leaves bare is little beside them
+    (call,) = [s for s in trace["spans"] if s["name"] == "train.call"]
+    kids = [s for s in trace["spans"] if s["parent_id"] == call["span_id"]]
+    assert sum(k["dur_us"] for k in kids) > 0.9 * call["dur_us"]
+
+
+def parent_of(span, spans):
+    return spans[span["parent_id"]]["name"] if span["parent_id"] else None
 
 
 REFUSALS = [

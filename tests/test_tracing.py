@@ -681,10 +681,25 @@ CALL_PARENTS = {
     "train.epoch": "train.call",
     "train.data_prep": "train.epoch",
     "train.compiled_step": "train.epoch",
+    # the call's own fresh jit of its step, taken apart on its first dispatch
+    "train.jit_trace": "train.compiled_step",
+    "train.jit_lower": "train.compiled_step",
+    "train.jit_compile": "train.compiled_step",
     "train.sync": "train.epoch",
 }
-EMIT_PARENTS = {"emit.model_rows": None, "emit.d2h": "emit.model_rows",
-                "emit.select": "emit.model_rows"}
+# the mask's copy (and FM's w0) lies under the root, a chunk's under
+# `emit.assemble`
+EMIT_PARENTS = {("emit.model_rows", None), ("emit.d2h", "emit.model_rows"),
+                ("emit.select", "emit.model_rows"),
+                ("emit.gather", "emit.model_rows"),
+                ("emit.assemble", "emit.model_rows"),
+                ("emit.d2h", "emit.assemble")}
+JIT_PHASES = ("train.jit_trace", "train.jit_lower", "train.jit_compile")
+# spans under which a process compiles ONCE (the state's eager fills,
+# emission's two module-level programs, the collapse's scalar reshape): their
+# compile phases are there or not by what ran before in the process
+ONCE_A_PROCESS = ("train.init_state", "emit.model_rows", "emit.gather",
+                  "train.collapse")
 CALL_COUNTERS = ("train.h2d_bytes", "train.parse_tokens", "train.jit_compiles",
                  "train.slot_bytes", "emit.d2h_bytes", "emit.rows")
 # a linear entry's rule: its name on `train.call`, its slots, derived weights
@@ -729,8 +744,12 @@ def _traced_call(entry, options, form, **kw):
 
 
 def _parents(trace):
+    """(name, parent's name) of every span, but the compile phases of what a
+    process compiles once."""
     names = {s["span_id"]: s["name"] for s in trace["spans"]}
-    return {(s["name"], names.get(s["parent_id"])) for s in trace["spans"]}
+    pairs = {(s["name"], names.get(s["parent_id"])) for s in trace["spans"]}
+    return {(name, parent) for name, parent in pairs
+            if not (name in JIT_PHASES and parent in ONCE_A_PROCESS)}
 
 
 def _sum(trace, name, key):
@@ -753,7 +772,7 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     if form == "arrays":
         want.pop("train.parse")   # no parser on pre-hashed rows
     assert _parents(call) == set(want.items())
-    assert _parents(emit) == set(EMIT_PARENTS.items())
+    assert _parents(emit) == EMIT_PARENTS
     by_name = {}
     for s in call["spans"] + emit["spans"]:
         by_name.setdefault(s["name"], []).append(s)
@@ -839,6 +858,13 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
     assert emit_root["args"]["h2d_bytes"] == 256 * 4
     row = 4 + 16 * 4 if fm else 4 + 4 if cov else 4
     assert d2h == 256 // 8 + 256 * row + (4 if fm else 0)
+    # the one chunk's ids go up under `emit.gather`; its values are fetched
+    # and placed under `emit.assemble`, as many bytes as rows came out
+    assert [s["args"] for s in by_name["emit.gather"]] \
+        == [{"chunks": 1, "h2d_bytes": 256 * 4}]
+    assert [s["args"] for s in by_name["emit.assemble"]] \
+        == [{"bytes": len(feats) * row}]
+    _phases_lie_in_their_dispatch(call)
 
 
 def _ffm_rows(form, n=64, seed=0):
@@ -875,7 +901,7 @@ def test_train_ffm_commits_the_vocabulary(form):
     if form == "arrays":
         want.pop("train.parse")
     assert _parents(call) == set(want.items())
-    assert _parents(emit) == set(EMIT_PARENTS.items())
+    assert _parents(emit) == EMIT_PARENTS
     by_name = {}
     for s in call["spans"] + emit["spans"]:
         by_name.setdefault(s["name"], []).append(s)
@@ -929,6 +955,13 @@ def test_train_ffm_commits_the_vocabulary(form):
     # length where that is under 2^19), and w0
     assert d2h == (1 << 18) // 8 + (1 << 12) // 8 \
         + (1 << 18) * 4 + (1 << 12) * 16 + 4
+    # a key space at a time: its gather, then its values
+    assert [s["args"] for s in by_name["emit.gather"]] == [
+        {"chunks": 1, "h2d_bytes": (1 << 18) * 4},
+        {"chunks": 1, "h2d_bytes": (1 << 12) * 4}]
+    assert [s["args"] for s in by_name["emit.assemble"]] \
+        == [{"bytes": len(feats) * 4}, {"bytes": len(v_keys) * 16}]
+    _phases_lie_in_their_dispatch(call)
 
 
 def test_ffm_step_carries_every_scope_and_nothing_as_long_as_a_table():
@@ -999,6 +1032,298 @@ def test_each_call_compiles_on_its_first_step_and_says_so():
         assert steps[0]["events"][0]["args"] == {"guard": "train.call",
                                                  "compiles": 1}
         assert counted["train.jit_compiles"] == 1
+
+
+# -- a fresh jit's compile, taken apart ----------------------------------------
+# jax.monitoring's callbacks open and close `train.jit_trace`,
+# `train.jit_lower` and `train.jit_compile` under the span that dispatched the
+# fresh jit (runtime/tracing.py::_bind_compile_listeners).
+
+DISPATCH_SPANS = ("train.compiled_step", "train.mix", "train.collapse")
+
+
+def _phases_lie_in_their_dispatch(call):
+    """Every dispatch of a fresh jit (`compiled` true) holds the three
+    phases once each, in order, one after the other, inside it; a dispatch
+    that compiled nothing holds none."""
+    spans = call["spans"]
+    fresh = 0
+    for d in (s for s in spans if s["name"] in DISPATCH_SPANS):
+        kids = sorted((s for s in spans if s["parent_id"] == d["span_id"]
+                       and s["name"] in JIT_PHASES),
+                      key=lambda s: s["start_us"])
+        if not d["args"]["compiled"]:
+            assert kids == [], d
+            continue
+        fresh += 1
+        # but the collapse's scalars, reshaped by an eager op once a process
+        own = [k for k in kids if "reshape" not in k["args"]["fn"]]
+        assert [k["name"] for k in own] == list(JIT_PHASES), d
+        edge = d["start_us"]
+        for k in own:
+            assert edge <= k["start_us"]
+            edge = k["start_us"] + k["dur_us"]
+        assert edge <= d["start_us"] + d["dur_us"]
+        assert own[2]["args"]["cache"] in ("hit", "miss", "off")
+    assert fresh >= 1
+    return fresh
+
+
+class _Callbacks:
+    """Every jax.monitoring callback of its extent, counted."""
+
+    def __enter__(self):
+        from jax import monitoring
+
+        self.count = 0
+        self._regs = (
+            (monitoring.register_scalar_listener,
+             monitoring.unregister_scalar_listener),
+            (monitoring.register_event_duration_secs_listener,
+             monitoring.unregister_event_duration_listener),
+            (monitoring.register_event_listener,
+             monitoring.unregister_event_listener))
+        for register, _ in self._regs:
+            register(self._seen)
+        return self
+
+    def _seen(self, *args, **kw):
+        self.count += 1
+
+    def __exit__(self, *exc):
+        for _, unregister in self._regs:
+            unregister(self._seen)
+
+
+def _fresh_jit():
+    """A fresh closure over functions that are jitted themselves (`jnp.where`,
+    `jnp.linalg.norm`, ...): their traces fire the trace event too."""
+    import jax
+    import jax.numpy as jnp
+
+    def nested(x):
+        y = jnp.where(x > 0, jnp.sin(x), jnp.cos(x))
+        return jnp.sum(y) + jnp.linalg.norm(x) + jnp.max(jnp.cumsum(x))
+
+    return jax.jit(nested), jnp.ones((64,), jnp.float32)
+
+
+def _phase_spans(trace):
+    return sorted((s for s in trace["spans"] if s["name"] in JIT_PHASES),
+                  key=lambda s: s["start_us"])
+
+
+def test_a_fresh_jit_under_a_span_yields_the_three_phases_once():
+    fn, x = _fresh_jit()
+    t = Tracer(seed=0)
+    with _Callbacks() as first, t.span("outer"):
+        with t.span("dispatch") as d:
+            fn(x)
+    # the trace event alone fires for every inner jitted function
+    assert first.count > 3 * 2
+    with _Callbacks() as again, t.span("outer"):
+        with t.span("dispatch"):
+            for _ in range(100):
+                fn(x)
+    assert again.count == 0          # a warm jit runs no listener
+    cold, warm = t.traces()
+    assert _phase_spans(warm) == []
+    phases = _phase_spans(cold)
+    assert [p["name"] for p in phases] == list(JIT_PHASES)
+    (parent,) = [s for s in cold["spans"] if s["name"] == "dispatch"]
+    assert {p["parent_id"] for p in phases} == {parent["span_id"]}
+    assert [p["args"]["fn"] for p in phases] \
+        == ["nested", "jit(nested)", "jit(nested)"]
+    edge = parent["start_us"]
+    for p in phases:                 # one after the other, inside the parent
+        assert edge <= p["start_us"] and p["dur_us"] > 0
+        edge = p["start_us"] + p["dur_us"]
+    assert edge <= parent["start_us"] + parent["dur_us"]
+    assert sum(p["dur_us"] for p in phases) <= parent["dur_us"]
+    assert d.span_id == parent["span_id"]
+
+
+@pytest.mark.parametrize("how", ["no_span", "disabled", "unsampled_null"])
+def test_a_compile_outside_a_recording_span_yields_nothing(how):
+    from hivemall_tpu.runtime import tracing
+
+    fn, x = _fresh_jit()
+    t = Tracer(seed=0, enabled=how != "disabled")
+    before = len(TRACER.traces())
+    if how == "no_span":
+        fn(x)
+    elif how == "disabled":
+        with t.span("dispatch"):
+            fn(x)
+    else:
+        # a span another tracer's disabled state handed out: not recording
+        with t.span("outer"):
+            token = tracing._current.set(tracing.NULL_SPAN)
+            try:
+                fn(x)
+            finally:
+                tracing._current.reset(token)
+    assert tracing._PHASE.span is None
+    assert all(_phase_spans(tr) == [] for tr in t.traces())
+    assert len(TRACER.traces()) == before
+
+
+@pytest.mark.parametrize("case", ["nested", "foreign_inside", "end_alone",
+                                  "unknown_event"])
+def test_phase_callbacks_by_hand(case):
+    """The listeners on hand-fed events: nested events of the open phase
+    make one span, another phase's events inside it make none (an eager op
+    compiling inside a trace), an end with no start is ignored."""
+    from hivemall_tpu.runtime import tracing
+
+    tr, lo, be = tracing._PHASE_SPANS
+    t = Tracer(seed=0)
+    with t.span("dispatch"):
+        if case == "nested":
+            tracing._phase_started(tr, 0.0, fun_name="outer")
+            tracing._phase_started(tr, 0.0, fun_name="inner")
+            tracing._phase_ended(tr, 0.1, fun_name="inner")
+            assert tracing._PHASE.span is not None
+            tracing._phase_ended(tr, 0.2, fun_name="outer")
+            want = [("train.jit_trace", "outer")]
+        elif case == "foreign_inside":
+            tracing._phase_started(tr, 0.0, fun_name="outer")
+            for event in (tr, lo, be):
+                tracing._phase_started(event, 0.0, fun_name="eager")
+                tracing._cache_event("/jax/compilation_cache/cache_hits")
+                tracing._phase_ended(event, 0.1, fun_name="eager")
+            tracing._phase_ended(tr, 0.2, fun_name="outer")
+            want = [("train.jit_trace", "outer")]
+        elif case == "end_alone":
+            tracing._phase_ended(be, 0.1, fun_name="late")
+            tracing._phase_ended(tracing._CACHE_RETRIEVAL, 0.1)
+            tracing._cache_event("/jax/compilation_cache/cache_hits")
+            want = []
+        else:
+            tracing._phase_started("/jax/other/event", 1.0)
+            tracing._phase_ended("/jax/other/event", 1.0)
+            tracing._cache_event("/jax/other/event")
+            want = []
+        assert tracing._PHASE.span is None
+    (trace,) = t.traces()
+    assert [(s["name"], s["args"]["fn"]) for s in _phase_spans(trace)] == want
+    assert all("cache" not in s["args"] for s in _phase_spans(trace))
+
+
+def _cache_counters():
+    from hivemall_tpu.runtime.metrics import REGISTRY
+
+    snap = REGISTRY.snapshot()
+    return (snap.get("train.compile_cache_hits", 0.0),
+            snap.get("train.compile_cache_misses", 0.0))
+
+
+def test_compile_span_says_what_the_persistent_cache_did(tmp_path):
+    """`cache` reads `off` with no directory, `miss` on a directory's first
+    compile of a module and `hit` on its next fresh jit; the two counters
+    follow; a hit carries the read's time."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    def compile_once():
+        fn, x = _fresh_jit()
+        t = Tracer(seed=0)
+        with t.span("dispatch"):
+            fn(x)
+        (trace,) = t.traces()
+        (span,) = [s for s in trace["spans"]
+                   if s["name"] == "train.jit_compile"]
+        return span["args"]
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    start = _cache_counters()
+    try:
+        jax.config.update(keys[0], None)
+        cc.reset_cache()
+        off = compile_once()
+        assert off["cache"] == "off" and "retrieval_ms" not in off
+        assert _cache_counters() == start
+        jax.config.update(keys[0], str(tmp_path))
+        jax.config.update(keys[1], 0.0)
+        jax.config.update(keys[2], 0)
+        cc.reset_cache()
+        miss = compile_once()
+        assert miss["cache"] == "miss" and "retrieval_ms" not in miss
+        assert _cache_counters() == (start[0], start[1] + 1)
+        hit = compile_once()
+        assert hit["cache"] == "hit" and hit["retrieval_ms"] > 0
+        assert _cache_counters() == (start[0] + 1, start[1] + 1)
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+
+
+def test_a_disabled_tracer_registers_no_listener():
+    """`HIVEMALL_TPU_TRACE=0`: a whole `train_*` call and its emission bind
+    nothing to jax.monitoring; the default binds the three, once."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import numpy as np\n"
+        "from jax._src import monitoring as m\n"
+        "from hivemall_tpu.models.classifier import train_arow\n"
+        "count = lambda: (len(m.get_scalar_listeners()),"
+        " len(m.get_event_duration_listeners()), len(m.get_event_listeners()))\n"
+        "before = count()\n"
+        "for _ in range(2):\n"
+        "    train_arow([['1:1.0', '2:0.5']] * 8, np.ones(8, int),"
+        " '-dims 16').model_rows()\n"
+        "print([b - a for a, b in zip(before, count())])\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for switch, want in (("0", "[0, 0, 0]"), ("1", "[1, 1, 1]")):
+        env = dict(os.environ, HIVEMALL_TPU_TRACE=switch, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=root)
+        out = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                             capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        assert out.stdout.strip().splitlines()[-1] == want
+
+
+@pytest.mark.parametrize("entry,options", [
+    ("train_arow", "-dims 256 -mini_batch 16 -iters 2 -disable_cv"),
+    ("train_adagrad_rda", "-dims 256 -mini_batch 16"),
+    ("train_fm", "-c -factor 4 -dims 256 -mini_batch 16 -iters 2 "
+                 "-disable_cv"),
+    ("train_ffm", "-factor 4 -feature_hashing 18 -num_fields 6 -v_bits 12 "
+                  "-mini_batch 16 -iters 2 -disable_cv -eta0_V 0.1"),
+    ("train_arow", "-dims 256 -mini_batch 16 -mix h -mix_threshold 2"),
+])
+def test_every_fresh_dispatch_of_an_entry_has_the_three_phases(
+        entry, options, monkeypatch):
+    import jax
+
+    from hivemall_tpu.parallel import mix as pmix
+    from hivemall_tpu.sql.registry import get_function
+
+    mixed = "-mix " in options
+    if mixed:
+        monkeypatch.setattr(pmix, "mix_devices",
+                            lambda: jax.local_devices()[:2])
+    rows, labels, _ = _ffm_rows("arrays") if entry == "train_ffm" \
+        else _rows("arrays")
+    TRACER.clear()
+    with _Callbacks() as seen:
+        get_function(entry)(rows, labels, options)
+    assert seen.count > 0
+    (call,) = [t for t in TRACER.traces() if t["root"] == "train.call"]
+    # the step's jit; with `-mix` the round's and the collapse's too
+    assert _phases_lie_in_their_dispatch(call) == (3 if mixed else 1)
+    names = {s["span_id"]: s["name"] for s in call["spans"]}
+    under = {(s["name"], names[s["parent_id"]]) for s in call["spans"]
+             if s["name"] in ("train.build", "train.collapse")}
+    assert under == ({("train.build", "train.call"),
+                      ("train.collapse", "train.call")} if mixed else set())
 
 
 # gather and scatter ops in each step's lowered text: the linear steps' at
