@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
-from ..ops.scatter import reduce_block_runs, write_runs
+from ..ops.scatter import (RunWrite, reduce_block_runs,
+                           write_runs_together)
 from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_PACK_TABLES,
                                SCOPE_REDUCE, SCOPE_RULE, SCOPE_TOUCHED)
 from .state import LinearState
@@ -413,14 +414,24 @@ def make_train_fn(
                         count > 0,
                         rule.derive_w(sl_new, tf_end, hyper).astype(acc),
                         w_new)
-                weights = write_runs(weights, runs, w_new)
+                writes = {"w": RunWrite(weights, w_new, scope=SCOPE_APPLY)}
                 if has_cov:
-                    covars = write_runs(
-                        covars, runs, old["cov"] + sums["cov"] / denom)
+                    writes["cov"] = RunWrite(
+                        covars, old["cov"] + sums["cov"] / denom,
+                        scope=SCOPE_APPLY)
                 for k in slot_sums:
-                    new_slots[k] = write_runs(slots[k], runs, sl_new[k])
-            with jax.named_scope(SCOPE_TOUCHED):
-                touched = write_runs(state.touched, runs, count > 0, "max")
+                    writes["slot " + k] = RunWrite(slots[k], sl_new[k],
+                                                   scope=SCOPE_APPLY)
+                writes["touched"] = RunWrite(state.touched, count > 0, "max",
+                                             SCOPE_TOUCHED)
+            # each under its own scope down XLA's path; one walk of the
+            # block's ids for all the tables the kernel takes
+            written = dict(zip(writes,
+                               write_runs_together(runs, writes.values())))
+            weights, touched = written["w"], written["touched"]
+            covars = written.get("cov", covars)
+            for k in slot_sums:
+                new_slots[k] = written["slot " + k]
             new_state = state.replace(
                 weights=weights, covars=covars, slots=new_slots,
                 touched=touched, step=t0 + b, globals=gl)

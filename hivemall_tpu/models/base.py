@@ -28,6 +28,7 @@ import numpy as np
 from ..constants import DEFAULT_NUM_FEATURES
 from ..core.batch import (fillable_lanes, is_rect, iter_blocks, longest_row,
                           pack_rows, pad_to_bucket, shuffle_rows)
+from ..ops.scatter import kernel_written
 from ..core.engine import (Rule, apply_strategy, make_predict,
                            make_train_step, make_cut_step)
 from ..core.state import (LinearState, init_linear_state, linear_tables,
@@ -266,6 +267,19 @@ def dispatch_spanned(span: str, span_args: dict, program, *args):
             sp.event("jit_recompile", guard=SPAN_CALL, compiles=grew)
             REGISTRY.counter("train", "jit_compiles").increment(grew)
     return out
+
+
+def record_write_path(call, tables, dims: int, lanes: int) -> int:
+    """Put on `train.call` how a `-mini_batch` step writes the `[dims]`
+    tables it writes at a block's runs (`tables`, name -> table; none under
+    the `dense` plan), `lanes` lanes a block: `write` is `xla`, or `kernel:`
+    and the names of the tables that go through the run-write kernel
+    (`ops/scatter.py::write_path`, the test the step makes when it is
+    traced). Returns how many tables those are, for
+    `train.kernel_write_lanes`."""
+    through = kernel_written(tables, dims, lanes, jax.default_backend())
+    call.set(write="kernel:" + ",".join(through) if through else "xla")
+    return len(through)
 
 
 @dataclass
@@ -591,6 +605,12 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
         initial_covars=initial_covars,
     )
     call.set(table_dtype=str(state.weights.dtype))
+    kernel_tables = 0
+    if mode == "minibatch":
+        by_runs = apply_strategy(dims, block_size * lanes) == "batch_local"
+        kernel_tables = record_write_path(
+            call, linear_tables(state) if by_runs else {}, dims,
+            block_size * lanes)
 
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     # progress counters, the Hadoop Reporter/Counter analog
@@ -598,6 +618,7 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
     iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
     row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
     cut_counter = REGISTRY.counter("train", "lanes_cut")
+    kernel_counter = REGISTRY.counter("train", "kernel_write_lanes")
     # -batch: plans are a pure function of each block's indices, so they
     # are staged on the host once and replayed every epoch (cleared when
     # -shuffle re-deals the rows)
@@ -633,6 +654,8 @@ def _fit_linear(call, rule, hyper, cl, features, labels, label_map,
                 epoch_losses.append(loss)
                 row_counter.increment(block[0].shape[0])
                 cut_counter.increment(block[0].shape[0] * (width - lanes))
+                kernel_counter.increment(
+                    block[0].shape[0] * lanes * kernel_tables)
             iter_counter.increment()
             with TRACER.span(SPAN_SYNC,
                              args={"fetches": len(epoch_losses)}):
@@ -700,11 +723,17 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
              reduction=trainer.reduction)
     state = init_state_spanned(trainer.init, initial_weights, initial_covars)
     call.set(table_dtype=str(state.weights.dtype))
+    # a replica's step writes a replica's tables, `mini_batch` rows a block
+    by_runs = apply_strategy(dims, mini_batch * lanes) == "batch_local"
+    kernel_tables = record_write_path(
+        call, linear_tables(state) if by_runs else {}, dims,
+        mini_batch * lanes)
 
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     iter_counter = REGISTRY.counter("hivemall", f"{rule.name}.iterations")
     row_counter = REGISTRY.counter("hivemall", f"{rule.name}.examples")
     cut_counter = REGISTRY.counter("train", "lanes_cut")
+    kernel_counter = REGISTRY.counter("train", "kernel_write_lanes")
     step_no = round_no = 0
     for it in range(max(1, iters)):
         with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
@@ -734,6 +763,7 @@ def _fit_linear_mixed(call, rule, hyper, cl, features, labels, dims,
                 real_rows = int(block[3].sum())
                 row_counter.increment(real_rows)
                 cut_counter.increment(real_rows * (width - lanes))
+                kernel_counter.increment(real_rows * lanes * kernel_tables)
                 pending = (pending + 1) % mix_every
                 if not pending:
                     state = mix_round(state, trailing=False)

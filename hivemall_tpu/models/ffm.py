@@ -59,7 +59,7 @@ from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
 from ..utils.feature import FMFeature
 from ..utils.options import Options
 from .base import (_stage_rows, dispatch_step, init_state_spanned,
-                   prepared_blocks, stage_training_rows)
+                   prepared_blocks, record_write_path, stage_training_rows)
 from .fm import _fm_options
 
 _MIX1 = 0x9E3779B1
@@ -914,6 +914,15 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
                          pair_width=pair_width)
     state = init_state_spanned(init_ffm_state, hyper)
     call.set(table_dtype=str(state.v.dtype))
+    kernel_tables = 0
+    if mode == "minibatch" and hyper.linear_coeff:
+        # the linear term's tables are the step's writes at a block's runs
+        # (the pair block adds rows and sets its flag by XLA's scatters)
+        linear = {"w": state.w, "touched": state.touched}
+        if hyper.use_ftrl:
+            linear.update(z=state.z, n=state.n)
+        kernel_tables = record_write_path(call, linear, dims,
+                                          block * pair_width)
     iters = cl.get_int("iters", 1)
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     # progress counters, as fit_linear keeps them
@@ -921,6 +930,7 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
     row_counter = REGISTRY.counter("hivemall", "ffm.examples")
     real_lanes = REGISTRY.counter("train", "pair_lanes")
     padded_lanes = REGISTRY.counter("train", "pair_lanes_padded")
+    kernel_counter = REGISTRY.counter("train", "kernel_write_lanes")
     step_no = 0
     for it in range(max(1, iters)):
         with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
@@ -941,6 +951,8 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
                 epoch_losses.append(loss)
                 row_counter.increment(blk[0].shape[0])
                 padded_lanes.increment(blk[0].shape[0] * pair_width ** 2)
+                kernel_counter.increment(
+                    blk[0].shape[0] * pair_width * kernel_tables)
             iter_counter.increment()
             real_lanes.increment(pairs)
             with TRACER.span(SPAN_SYNC, args={"fetches": len(epoch_losses)}):

@@ -46,7 +46,8 @@ from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
                                TRACER)
 from ..utils.options import Options
 from .base import (FeatureRows, _stage_rows, base_options, dispatch_step,
-                   init_state_spanned, prepared_blocks, stage_training_rows)
+                   init_state_spanned, prepared_blocks, record_write_path,
+                   stage_training_rows)
 
 DOUBLE_MIN = -1.7976931348623157e308  # mirrors Double.MIN_VALUE default semantics:
 # the reference's minTarget default is Double.MIN_VALUE (smallest positive!),
@@ -527,6 +528,13 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
         step = make_cut_step(step, lanes)
     state = init_state_spanned(init_fm_state, dims, hyper)
     call.set(table_dtype=str(state.v.dtype))
+    kernel_tables = 0
+    if mode == "minibatch":
+        # V's rows stay on XLA's row scatter: `w` and the flag are the
+        # step's `[dims]` tables
+        kernel_tables = record_write_path(
+            call, {"w": state.w, "touched": state.touched}, dims,
+            block * lanes)
     rng = np.random.RandomState(hyper.seed)
 
     def va_mask(blk):
@@ -539,6 +547,7 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
     iter_counter = REGISTRY.counter("hivemall", "fm.iterations")
     row_counter = REGISTRY.counter("hivemall", "fm.examples")
     cut_counter = REGISTRY.counter("train", "lanes_cut")
+    kernel_counter = REGISTRY.counter("train", "kernel_write_lanes")
     step_no = 0
     for it in range(max(1, iters)):
         with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
@@ -556,6 +565,8 @@ def _train_fm(call, features, targets, options) -> TrainedFMModel:
                     epoch_loss += float(loss)
                 row_counter.increment(blk[0].shape[0])
                 cut_counter.increment(blk[0].shape[0] * (width - lanes))
+                kernel_counter.increment(
+                    blk[0].shape[0] * lanes * kernel_tables)
             iter_counter.increment()
             epoch.set(steps=steps)
             call.set(epochs=it + 1)

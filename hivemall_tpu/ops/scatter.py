@@ -16,6 +16,7 @@ view that FFM and FM's `feature_shard` stripes keep.
 
 from __future__ import annotations
 
+from contextlib import nullcontext
 from typing import NamedTuple
 
 import jax
@@ -102,16 +103,115 @@ def reduce_block_runs(idx_flat: jnp.ndarray, dims: int, summed,
                      carried=ride_tree.unflatten(list(cols[len(sum_cols):])))
 
 
+# A `[D]` table's runs are written down one of two paths, chosen when the
+# step is traced from what it can see there: the table's storage type and
+# length, the block's lanes, and the backend. "xla" is the sorted in-place
+# scatter, which on a v5e costs a lane part plus the table's bytes streamed
+# once each way whatever the lanes; "kernel" is
+# `kernels/run_write.py::write_runs_kernel`, which moves only the 1,024-entry
+# tiles that hold a touched entry, so its time follows the lanes and no
+# table's length. The two compute the same bits; only their cost differs
+# with D and N, and the rule is that cost model, its constants here and
+# nowhere else, each with the run it came from (PERF.md section 7 has both
+# tables):
+#   stream_ms  XLA's stream per 2^28 entries  (scripts/scatter_cost.py,
+#   xla_ns     XLA's part per lane             builder's chip run, PR 35)
+#   kernel_ns  the kernel's part per lane for ONE table alone, its sort
+#              and plan included (scripts/scatter_cost.py `write.kernel` at
+#              2^28 over 16,384 / 40,960 / 65,536 lanes, builder's chip
+#              run, PR 39; 2^29 reads 2% more, 2^24 a third less: shorter
+#              tables have fewer tiles to move)
+# A table that joins a state's other tables in one walk costs less than
+# alone (AdaGradRDA's four: 3.7 ms a step together, 6.3 as the sum of each
+# alone), so the rule errs toward XLA. A lane is what the step can count;
+# what the kernel pays for is a run head and its tile. The constants are
+# read on ids placed as the cells' are, 0.55 heads a lane, nearly each in a
+# tile of its own; a block whose ids all differ pays 1.8 times `kernel_ns`
+# a lane, which alone at 2^28 would put the bf16 and s8 writes behind XLA's
+# by a fifth (hashed CTR ids repeat heavily: a third of Criteo's lanes are
+# on every row).
+WRITE_COST = {
+    "int8": {"stream_ms": 1.597, "xla_ns": 8.45, "kernel_ns": 41.7},
+    "bfloat16": {"stream_ms": 2.117, "xla_ns": 8.75, "kernel_ns": 37.5},
+    "float32": {"stream_ms": 3.177, "xla_ns": 4.91, "kernel_ns": 29.6},
+}
+# what a kernel call costs before its first lane (0.07-0.09 ms: the launch,
+# the head sort and the plan, one chunk's two waits) over XLA's 0.034
+KERNEL_FIXED_MS = 0.05
+
+
+def write_path(dtype, dims: int, lanes: int, backend: str) -> str:
+    """"kernel" or "xla": how a block of `lanes` lanes is written
+    into a `[dims]` table of `dtype` on `backend`. The kernel is a Mosaic
+    program, so any backend but a TPU takes XLA's write."""
+    cost = WRITE_COST.get(jnp.dtype(dtype).name)
+    if backend != "tpu" or cost is None:
+        return "xla"
+    stream_ms = cost["stream_ms"] * dims / 2 ** 28
+    lanes_ms = lanes * (cost["kernel_ns"] - cost["xla_ns"]) * 1e-6
+    return "kernel" if stream_ms > KERNEL_FIXED_MS + lanes_ms else "xla"
+
+
+def kernel_written(tables, dims: int, lanes: int, backend: str) -> list:
+    """The names in `tables` (name -> a `[dims]` table, or a stack of them
+    a replica) that a step on `backend` writes through the kernel, `lanes`
+    lanes a block: `train.call`'s `write`."""
+    return [name for name, t in tables.items()
+            if write_path(t.dtype, dims, lanes, backend) == "kernel"]
+
+
+class RunWrite(NamedTuple):
+    """One table's write at a block's runs, for `write_runs_together`."""
+
+    table: jnp.ndarray  # [D], or [D, k] rows (FM's V)
+    values: jnp.ndarray  # [N] or [N, k]: equal on all lanes of one id, as
+    # anything computed from `runs.sums` and `runs.carried` is
+    op: str = "set"  # or "max": `max(table[id], values)`, the flag's
+    scope: str | None = None  # the `hm.*` scope XLA's write stands under
+
+
 def write_runs(table: jnp.ndarray, runs: BlockRuns, values: jnp.ndarray,
                op: str = "set") -> jnp.ndarray:
-    """`table[id] = values` (or `max(table[id], values)`) at the block's
-    ids, in place. `values`, [N] for a `[D]` table or [N, k] rows for a
-    `[D, k]` one (FM's V: one sorted row scatter, 6.2 ms per 65,536 rows of
-    16 lanes into 2^23 on a v5e), must be equal on all lanes of one id, as
-    anything computed from `runs.sums` and `runs.carried` is."""
-    at = table.at[runs.ids]
-    return getattr(at, op)(values.astype(table.dtype), mode="drop",
-                           indices_are_sorted=True)
+    """One table's `write_runs_together`."""
+    return write_runs_together(runs, [RunWrite(table, values, op)])[0]
+
+
+def write_runs_together(runs: BlockRuns, writes) -> list:
+    """Each `RunWrite`'s `table[id] = values` (or `max(table[id], values)`)
+    at the block's ids, in place, in the order given. Down XLA's path a
+    table is one sorted scatter (a `[D, k]` table one sorted row scatter:
+    6.2 ms per 65,536 rows of 16 lanes into 2^23 on a v5e). The `[D]`
+    tables that `write_path` sends there go through the run-write kernel
+    instead, all of one length in one walk of the ids (the same bits);
+    only then is the kernel's module, and Pallas, imported."""
+    writes = list(writes)
+    out = [w.table for w in writes]
+    lanes, backend = runs.ids.shape[0], jax.default_backend()
+    scoped = lambda w: jax.named_scope(w.scope) if w.scope else nullcontext()
+    walks = {}   # (table length, path) -> the positions of the kernel's
+    for at, w in enumerate(writes):
+        path = "xla" if w.table.ndim != 1 else write_path(
+            w.table.dtype, w.table.shape[0], lanes, backend)
+        if path != "xla":
+            walks.setdefault((w.table.shape[0], path), []).append(at)
+            continue
+        with scoped(w):
+            out[at] = getattr(w.table.at[runs.ids], w.op)(
+                w.values.astype(w.table.dtype), mode="drop",
+                indices_are_sorted=True)
+    for (_, path), walk in walks.items():
+        from ..kernels.run_write import write_runs_kernel
+
+        taken = [writes[at] for at in walk]
+        with scoped(taken[0]):
+            done = write_runs_kernel(
+                [w.table for w in taken], runs.ids,
+                [w.values for w in taken], [w.op for w in taken],
+                # a test's patched rule may say "interpret"
+                interpret=path == "interpret")
+        for at, table in zip(walk, done):
+            out[at] = table
+    return out
 
 
 # --------------------------------------------------------------------------

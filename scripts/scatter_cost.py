@@ -1,16 +1,18 @@
 #!/usr/bin/env python3
-"""What one gather and one sorted in-place write cost on the chip, by table
-length, storage type and lane count: the readings that size ROADMAP S2's
-kernel (PERF.md section 7 keeps the table). A chip tool; no benchmark cell
-runs it.
+"""What one gather and one in-place write of a block's runs cost on the
+chip, by table length, storage type and lane count, down each path the
+write has: XLA's sorted scatter and the Pallas run-write kernel
+(`kernels/run_write.py`). The readings behind `ops/scatter.py::WRITE_COST`
+(PERF.md section 7 keeps the table). A chip tool; no benchmark cell runs it.
 
     chiprun -- python3 scripts/scatter_cost.py --out chiprun_out/scatter_cost.jsonl
 
 For every storage type (`s8` written with `max` as the step's flag is,
 `bf16` and `f32` with `set`), table length 2^24 ... 2^29 and lane count
 16,384 / 40,960 / 65,536 (a 1,024-row block of 16, 40 and 64 lanes), ids
-drawn as the benchmark's rows draw them (a third of a row's lanes on ids
-every row carries, the rest log-uniform over the table):
+drawn as the benchmark's rows draw them (`block_ids`: a third of a row's
+lanes on ids every row carries, the rest log-uniform by rank and placed by
+a hash over the table):
 
 - `write.full`: `table.at[sorted ids].set(values, mode="drop",
   indices_are_sorted=True)`, every lane a real id: `ops/scatter.write_runs`.
@@ -19,6 +21,9 @@ every row carries, the rest log-uniform over the table):
   dropped lane is free where the two read alike).
 - `write.heads`: run heads only, under `unique_indices=True`: every lane
   that repeats its left neighbour's id gets an out-of-range id of its own.
+- `write.kernel`: `write_runs_kernel` on `write.full`'s operands, its head
+  sort and plan included; first held to `write.full`'s program on a random
+  table, bit for bit (`matches` on the line).
 - `gather.full` / `gather.tail`: `table.at[ids].get(mode="fill")` in block
   order, as the step gathers, every lane real or 3/8 of each row padding.
 
@@ -43,17 +48,27 @@ ROWS = 1024
 
 
 def block_ids(rng, dims: int, lanes: int, real: int):
-    """[ROWS, lanes] ids: the leading `real` lanes of each row carry
-    features (a third of them ids that every row carries, the rest
-    log-uniform over `dims`), the lanes after them the padding id."""
+    """[ROWS, lanes] ids as the benchmark's generator makes a block's
+    (`benchmark/datagen.py`): the leading `real` lanes of each row carry
+    features, a third of them ids that every row carries, the rest drawn
+    log-uniform by rank and PLACED BY A HASH of (rank, lane), so that hot
+    ids lie spread over the table as murmur-hashed names do; the lanes
+    after them carry the padding id. (Until PR 39 the ranks were the ids:
+    hot ids lay side by side at the table's start. XLA's writes and
+    gathers read the same either way; the kernel moves a tile for every
+    id that lies alone, so unplaced ranks flatter it by half: ledger,
+    PR 38.)"""
     import numpy as np
+
+    from benchmark.datagen import log_uniform_ranks, numeric_ids, place
 
     ids = np.full((ROWS, lanes), dims, np.int64)
     fixed = real // 3
-    ids[:, :fixed] = np.arange(fixed) * 7919 % dims
-    u = rng.random((ROWS, real - fixed))
-    ids[:, fixed:real] = np.minimum(np.exp(u * np.log(dims)).astype(np.int64),
-                                    dims - 1)
+    ids[:, :fixed] = numeric_ids(fixed, dims)
+    fields = np.broadcast_to(np.arange(real - fixed), (ROWS, real - fixed))
+    ids[:, fixed:real] = place(
+        log_uniform_ranks(rng.random((ROWS, real - fixed)), dims), fields,
+        dims)
     return ids.astype(np.int32)
 
 
@@ -76,6 +91,8 @@ def main() -> int:
     p.add_argument("--lanes", type=int, nargs="+", default=[16, 40, 64],
                    help="lanes a row; a block has 1,024 rows")
     p.add_argument("--dtypes", nargs="+", default=["s8", "bf16", "f32"])
+    p.add_argument("--cases", nargs="+", default=None,
+                   help="only these cases (default: all)")
     p.add_argument("--iters", type=int, default=30)
     p.add_argument("--seed", type=int, default=35)
     p.add_argument("--allow-cpu", action="store_true")
@@ -94,16 +111,37 @@ def main() -> int:
     dtypes = {"s8": jnp.int8, "bf16": jnp.bfloat16, "f32": jnp.float32}
     rng = np.random.default_rng(args.seed)
 
+    from hivemall_tpu.kernels.run_write import write_runs_kernel
+
+    def op_of(table):
+        return "max" if table.dtype == jnp.int8 else "set"
+
     def write(table, ids, values, unique=False):
-        at = table.at[ids]
-        op = at.max if table.dtype == jnp.int8 else at.set
-        return op(values, mode="drop", indices_are_sorted=True,
-                  unique_indices=unique)
+        return getattr(table.at[ids], op_of(table))(
+            values, mode="drop", indices_are_sorted=True,
+            unique_indices=unique)
 
     writes = {u: jax.jit(lambda t, i, v, u=u: write(t, i, v, u),
                          donate_argnums=(0,)) for u in (False, True)}
     gathers = jax.jit(
         lambda table, ids: table.at[ids].get(mode="fill", fill_value=0))
+    kernel = jax.jit(
+        lambda t, i, v: write_runs_kernel(
+            [t], i, [v], [op_of(t)], interpret=dev.platform != "tpu")[0],
+        donate_argnums=(0,))   # interpreted in the CPU rehearsal alone
+
+    def matches(dims, dtype, ids):
+        """Does the kernel leave a random table as XLA's sorted write
+        leaves it, bit for bit (run values: a function of the id)."""
+        key = jax.random.PRNGKey(dims % 1009)
+        if dtype == jnp.int8:
+            fresh = lambda: jax.random.randint(key, (dims,), -2, 3, dtype)
+            values = (ids % 5 - 2).astype(dtype)
+        else:
+            fresh = lambda: jax.random.normal(key, (dims,), dtype)
+            values = jnp.sin(ids.astype(jnp.float32)).astype(dtype)
+        want = writes[False](fresh(), ids, values)
+        return bool(jnp.array_equal(kernel(fresh(), ids, values), want))
 
     def timed(program, table, *operands):
         """Seconds a dispatch, and the table back (donated through)."""
@@ -138,12 +176,20 @@ def main() -> int:
                         ("write.tail", writes[False], np.sort(tail, None)),
                         ("write.heads", writes[True],
                          heads_only(np.sort(full, None), dims)),
+                        ("write.kernel", kernel, np.sort(full, None)),
                         ("gather.full", gathers, full.reshape(-1)),
                         ("gather.tail", gathers, tail.reshape(-1)),
                     ]
                     for case, program, ids in cases:
+                        if args.cases and case not in args.cases:
+                            continue
                         operands = (jnp.asarray(ids),) + (
                             (values,) if case.startswith("write") else ())
+                        same = None
+                        if case == "write.kernel":
+                            del table   # room for the two random tables
+                            same = matches(dims, dtype, operands[0])
+                            table = jnp.zeros((dims,), dtype)
                         sec, table = timed(program, table, *operands)
                         real = int((ids < dims).sum())
                         line = {
@@ -155,7 +201,7 @@ def main() -> int:
                             "table_gbps_if_streamed":
                                 2 * table.nbytes / sec / 1e9
                                 if case.startswith("write") else None,
-                            "device": device}
+                            "matches": same, "device": device}
                         lines.append(line)
                         f.write(json.dumps(line) + "\n")
                         f.flush()
@@ -172,7 +218,10 @@ def main() -> int:
                   if (ln["case"], ln["dtype"], ln["lanes"]) == key}
         print(f"| {key[0]} {key[1]} {key[2]} | " + " | ".join(
             f"{by_len[b]:.3f}" for b in args.log2) + " |")
-    print(json.dumps({"device": device, "cases": len(lines)}))
+    print(json.dumps({"device": device, "cases": len(lines),
+                      "kernel_mismatches": [
+                          (ln["dtype"], ln["log2_dims"], ln["lanes"])
+                          for ln in lines if ln["matches"] is False]}))
     return 0
 
 

@@ -9,6 +9,13 @@ The FM step (models/fm.py, one plan at every shape) is held to the same, at
 the benchmark's 2^23 dims and at the reference's default 2^24.
 Nothing runs in (b): on-chip-measurement guide, section 2. The topology is
 described inside a module-scoped fixture, never at import.
+
+Since PR 39 a table long against the block is written by the Pallas
+run-write kernel where the backend is a TPU (`ops/scatter.write_path`); (b)
+also compiles two cells' steps with the kernels in them (the rule asked as
+on a TPU: `jax.default_backend()` is the CPU's here) and holds them to the
+same: every table aliased to its result, no copy of one, nothing as long
+as a table but the kernels' calls.
 """
 
 import os
@@ -224,6 +231,68 @@ def test_compiled_cell_step_has_no_table_long_scratch(one_chip):
         assert opcode in ("scatter", "fusion"), line[:200]
         if opcode == "fusion":   # the fusion that holds a scatter, alone
             assert re.search(r'op_name="[^"]*/scatter(-max)?"', line), line[:200]
+
+
+# name -> (rule, hyper, dims, track_deltas): cells' steps on the 40 lanes
+# their rows fill, with every kind of table the kernel patches
+KERNEL_CELL_STEPS = {
+    "arow_mix_2^28": (C.AROW, {"r": 0.1}, 1 << 28, True),
+    "adagrad_rda_2^29": (C.ADAGRAD_RDA,
+                         {"eta": 0.1, "lambda": 1e-6, "scale": 100.0},
+                         1 << 29, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_CELL_STEPS))
+def test_compiled_cell_step_writes_in_place_through_the_kernel(
+        monkeypatch, one_chip, name):
+    """The cell's step compiled for the described v5e as a TPU process
+    traces it: one kernel call for the tables the rule sends there, every
+    table aliased to its result (the kernel's table operand is the state's own
+    buffer: no copy of a table, in or out), scratch far under a table, and
+    nothing else as long as a table but XLA's write of a table the rule
+    keeps."""
+    from hivemall_tpu.core.state import linear_tables
+    from hivemall_tpu.ops import scatter
+
+    rule, hyper, dims, track = KERNEL_CELL_STEPS[name]
+    rows, width = 1024, 40
+    by_rule = scatter.write_path
+    monkeypatch.setattr(scatter, "write_path",
+                        lambda dtype, d, n, _: by_rule(dtype, d, n, "tpu"))
+    on = lambda tree: jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        tree)
+    state = _state_shape(rule, dims, jnp.bfloat16, track)
+    step = make_train_fn(rule, hyper, mode="minibatch", track_deltas=track)
+    compiled = _compile_uncached(jax.jit(step, donate_argnums=(0,)).lower(
+        on(state), *on(_block(rows, width))))
+    m = compiled.memory_analysis()
+    tables = linear_tables(state)
+    assert m.alias_size_in_bytes >= sum(
+        t.size * t.dtype.itemsize for t in tables.values())
+    assert m.temp_size_in_bytes < 64 << 20
+    want = {}
+    for t in tables.values():
+        if by_rule(t.dtype, dims, rows * width, "tpu") == "kernel":
+            want[t.dtype.name] = want.get(t.dtype.name, 0) + 1
+    assert sum(want.values()) >= len(tables) - 1   # at most the flag stays
+    kernels, xla_writes = {}, 0
+    for result, opcode, line in _instructions(compiled.as_text()):
+        if f"[{dims}]" not in result or opcode in PASSES_ON:
+            continue
+        kernel = re.search(r"%run_scatter_write_((?:[a-z]+\d+_?)+)", line)
+        if opcode == "custom-call" and kernel:   # one call, a type a table
+            assert "tpu_custom_call" in line, line[:200]
+            for name in kernel.group(1).rstrip("_").split("_"):
+                kernels[name] = kernels.get(name, 0) + 1
+        else:   # XLA's write of a table the rule keeps, alone
+            assert opcode == "scatter" or (
+                opcode == "fusion" and re.search(
+                    r'op_name="[^"]*/scatter(-max)?"', line)), line[:200]
+            xla_writes += 1
+    assert kernels == want
+    assert xla_writes <= 2 * (len(tables) - sum(want.values()))
 
 
 def _compile_fm_cell_step(one_chip, dims):

@@ -797,6 +797,8 @@ def test_train_call_commits_the_vocabulary(entry, options, epochs, feats_at,
         # the step's way with a block: a 256-entry table is small for the
         # linear step's shape rule; FM has one plan at every shape
         "apply": "batch_local" if fm else "dense",
+        # which tables' runs the Pallas kernel writes: none off a TPU
+        "write": "xla",
         # rows of 3 to 7 features: the 8-lane bucket, and nothing to cut
         "width": 8, "lanes": 8}
     if not fm:   # fit_linear says what the rule keeps beside its weights
@@ -916,7 +918,7 @@ def test_train_ffm_commits_the_vocabulary(form):
         "entry": "ffm", "dims": 1 << 18, "rows": 64, "mini_batch": 16,
         "epochs": 2, "mode": "minibatch", "table_dtype": "float32",
         "fields": 6, "pairs_per_row": 30, "v_dims": 1 << 12,
-        "apply": "batch_local", "row_tile": 16}
+        "apply": "batch_local", "write": "xla", "row_tile": 16}
     (stage,) = by_name["train.stage"]
     # the three [64, 6] arrays stay arrays; the parser returns lists
     assert stage["args"] == {
