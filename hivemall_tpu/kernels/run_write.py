@@ -107,15 +107,15 @@ def run_heads(ids, dims: int):
     return lax.select(head, ids, lax.full(ids.shape, dims, jnp.int32))
 
 
-def chunk_plan(hid, dims: int):
-    """Per chunk of CHUNK heads: each head's slot (its tile's rank in the
-    chunk), the chunk's tile starts listed ascending at the chunk's front,
-    and (heads, tiles) counts `[2 * chunks]`."""
+def chunk_plan(hid, dims: int, tile: int = TILE):
+    """Per chunk of CHUNK heads (the row form's lanes: ids repeat): each
+    one's slot (its `tile`-long tile's rank in the chunk), the chunk's tile
+    starts listed ascending at its front, and counts `[2 * chunks]`."""
     lax = jax.lax
     n = hid.shape[0]
     g = n // CHUNK
     at = lax.rem(lax.iota(jnp.int32, n), jnp.int32(CHUNK))
-    start = hid - lax.rem(hid, jnp.int32(TILE))
+    start = hid - lax.rem(hid, jnp.int32(tile))
     before = lax.concatenate([start[:1], start[:-1]], 0)
     live = hid < dims
     opens = live & ((at == 0) | (before != start))
@@ -332,3 +332,140 @@ def _write_tails(tables, ids, vals, ops, whole: int) -> list:
             indices_are_sorted=True)
         out.append(jax.lax.dynamic_update_slice(table, tail, (whole,)))
     return out
+
+
+# ---- the row form: a block's `[k]` row deltas added into a `[D, k]` table ----
+#
+# XLA holds a `[D, 4]` f32 table (FFM's V) as `{0,1:T(4,128)}`: the bytes of
+# its `[4, D]` transpose, each (4, 128) tile (2 KiB) the four values of 128
+# rows. The kernel takes that transpose, a view of the same bytes, and walks
+# a block's lanes sorted by row: a grid step takes CHUNK lanes, copies every
+# tile they touch into VMEM, adds each lane's four values into its row's
+# column of its tile (a run's lanes one after another), and copies the
+# tiles back. No carried old values: the tile is read in any case.
+
+ROW_TILE = 128   # rows one copy moves: a (ROW_K, 128) tile of the view
+ROW_K = 4        # the row width served: four f32 values a row fill the
+# (4, 128) tile XLA gives the view; a wider row (FM's 16) sits in (8, 128)
+# tiles of a column-major table, which this kernel does not take
+
+
+def serves_rows(dtype, shape) -> bool:
+    """A `[D, 4]` f32 table of whole tiles of rows."""
+    return (len(shape) == 2 and shape[1] == ROW_K
+            and shape[0] % ROW_TILE == 0 and shape[0] > 0
+            and jnp.dtype(dtype) == jnp.float32)
+
+
+def _row_kernel(counts_ref, ids_ref, slot_ref, tiles_ref, *refs, chunks: int):
+    pl, pltpu = pallas_modules()
+    # then: a value column a factor, the table's [k, D] view, the result
+    # (aliased to it: copies go to and from the result); the tiles' buffer,
+    # the semaphores
+    vals, table = refs[:ROW_K], refs[ROW_K + 1]
+    buf, sems = refs[ROW_K + 2], refs[ROW_K + 3]
+    g = pl.program_id(0)
+    lanes, tiles = counts_ref[g], counts_ref[chunks + g]
+
+    def in_table(start):
+        return table.at[:, pl.ds(pl.multiple_of(start, ROW_TILE), ROW_TILE)]
+
+    def in_vmem(slot, k=1):   # k tiles of the buffer, from `slot`
+        return buf.at[:, pl.ds(pl.multiple_of(slot * ROW_TILE, ROW_TILE),
+                               k * ROW_TILE)]
+
+    def copy(way):
+        def body(p, carry):
+            there, here = in_table(tiles_ref[p]), in_vmem(p)
+            src, dst = (there, here) if way == 0 else (here, there)
+            pltpu.make_async_copy(src, dst, sems.at[way]).start()
+            return carry
+        return body
+
+    def wait_all(way):   # as `_kernel`'s: a wait stands for its ref's bytes
+        def wait(k):
+            def body(_, carry):
+                pltpu.make_async_copy(in_vmem(0, k), in_vmem(0, k),
+                                      sems.at[way]).wait()
+                return carry
+            return body
+        jax.lax.fori_loop(0, jax.lax.div(tiles, WAIT_GROUP),
+                          wait(WAIT_GROUP), 0)
+        jax.lax.fori_loop(0, jax.lax.rem(tiles, WAIT_GROUP), wait(1), 0)
+
+    shape = (ROW_K, ROW_TILE)
+    factor = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    column = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def patch(j, carry):
+        tile = in_vmem(slot_ref[j])
+        add = jnp.full(shape, vals[ROW_K - 1][j], jnp.float32)
+        for f in range(ROW_K - 2, -1, -1):
+            add = jax.lax.select(factor == f,
+                                 jnp.full(shape, vals[f][j], jnp.float32), add)
+        old = tile[...]
+        here = column == jax.lax.rem(ids_ref[j], ROW_TILE)
+        tile[...] = jax.lax.select(here, old + add, old)
+        return carry
+
+    jax.lax.fori_loop(0, tiles, copy(0), 0)
+    wait_all(0)
+    jax.lax.fori_loop(0, lanes, patch, 0)
+    jax.lax.fori_loop(0, tiles, copy(1), 0)
+    wait_all(1)
+
+
+def add_rows_kernel(table, ids: jnp.ndarray, cols, *,
+                    interpret: bool = False) -> jnp.ndarray:
+    """`table[ids] += values` in place, lane by lane: `table` a `[D, 4]` f32
+    table (`serves_rows`), `ids` [N] int32 ascending in `[0, D]` with every
+    dropped lane `== D` at the tail, `cols` the lanes' values as 4 columns
+    [N] in the same order. A row's lanes are added one after another in
+    lane order: XLA's `.at[ids].add` to float32 rounding, in another order.
+    `interpret` runs the kernel through Pallas' interpreter (tests, on the
+    CPU)."""
+    if not serves_rows(table.dtype, table.shape) or len(cols) != ROW_K:
+        raise ValueError(f"no run-write kernel for {table.dtype}"
+                         f"{list(table.shape)} with {len(cols)} columns")
+    dims, n = table.shape[0], ids.shape[0]
+    ids = ids.astype(jnp.int32)
+    cols = [c.astype(jnp.float32) for c in cols]
+    pad = -n % CHUNK   # whole chunks: the lanes added are dropped lanes
+    if pad:
+        ids = jnp.concatenate([ids, jnp.full((pad,), dims, jnp.int32)])
+        cols = [jnp.concatenate([c, jnp.zeros((pad,), c.dtype)])
+                for c in cols]
+    return _add_rows(table, ids, tuple(cols), interpret=interpret)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=("interpret",))
+def _add_rows(table, ids, cols, *, interpret):
+    """The plan and the kernel's call on the table's `[4, D]` view, traced
+    once a shape and a process (as `_place`)."""
+    pl, pltpu = pallas_modules()
+    chunks = ids.shape[0] // CHUNK
+    slot, tiles, counts = chunk_plan(ids, table.shape[0], ROW_TILE)
+    view = table.T   # the stored bytes: `{0,1}` of `[D, 4]` is `{1,0}` here
+    scalars = pl.BlockSpec((CHUNK,), lambda g, counts: (g,),
+                           memory_space=pltpu.SMEM)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(_row_kernel, chunks=chunks),
+        out_shape=jax.ShapeDtypeStruct(view.shape, view.dtype,
+                                       vma=jax.typeof(view).vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(chunks,),
+            in_specs=[scalars] * (3 + ROW_K) + [in_hbm],
+            out_specs=in_hbm,
+            scratch_shapes=[pltpu.VMEM((ROW_K, CHUNK * ROW_TILE),
+                                       jnp.float32),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        # operand 0 is the prefetched counts, then ids, slots, tiles, values
+        input_output_aliases={4 + ROW_K: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # `benchmark/xplane.classify` classes an op by the word `scatter`
+        name="run_scatter_add_rows_float32",
+    )(counts, ids, slot, tiles, *cols, view)
+    return out.T

@@ -269,16 +269,19 @@ def dispatch_spanned(span: str, span_args: dict, program, *args):
     return out
 
 
-def record_write_path(call, tables, dims: int, lanes: int) -> int:
+def record_write_path(call, tables, dims: int, lanes: int,
+                      rows: Sequence[str] = ()) -> int:
     """Put on `train.call` how a `-mini_batch` step writes the `[dims]`
     tables it writes at a block's runs (`tables`, name -> table; none under
     the `dense` plan), `lanes` lanes a block: `write` is `xla`, or `kernel:`
     and the names of the tables that go through the run-write kernel
     (`ops/scatter.py::write_path`, the test the step makes when it is
-    traced). Returns how many tables those are, for
+    traced), after `rows`, the row tables whose adds go through it (FFM's
+    V). Returns how many of `tables` those are, for
     `train.kernel_write_lanes`."""
     through = kernel_written(tables, dims, lanes, jax.default_backend())
-    call.set(write="kernel:" + ",".join(through) if through else "xla")
+    names = [*rows, *through]
+    call.set(write="kernel:" + ",".join(names) if names else "xla")
     return len(through)
 
 

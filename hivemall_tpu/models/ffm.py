@@ -50,6 +50,7 @@ from ..core.batch import is_rect, longest_row, pack_rows, pad_to_bucket
 from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.eta import EtaEstimator, get_eta
+from ..ops import scatter
 from ..ops.scatter import reduce_block_runs, write_runs
 from ..runtime.metrics import REGISTRY
 from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_LOSS,
@@ -449,16 +450,46 @@ def make_ffm_step(hyper: FFMHyper, mode: str = "scan",
     def apply_pairs(state: FFMState, keys, dV, dgg):
         """The block's V and gg deltas added at its keys ([N], [N, k], [N]:
         every lane of an entry adds, so an entry takes its lanes' sum), in
-        place; the keys' entries flagged. Plain scatter-adds: on a v5e the
-        compiler sorts the lanes of the two scalar scatters itself, and a
-        sort of ours before all three (with or without each run summed
-        first) made the step no faster, PERF.md section 6, PR 32."""
+        place; the keys' entries flagged. Where V's rows go through the
+        run-write kernel (`ops/scatter.py::row_write_path`), `sorted_pairs`;
+        elsewhere plain scatter-adds: on a v5e the compiler sorts the lanes
+        of the two scalar scatters itself, and a sort of ours before all
+        three (with or without each run summed first) made XLA's write no
+        faster (PERF.md section 5)."""
+        # a table that varies over a mesh axis (an `ffm_mix` replica) keeps
+        # XLA's add: no cell measures the kernel there
+        path = "xla" if jax.typeof(state.v).vma else scatter.row_write_path(
+            state.v.dtype, *state.v.shape, keys.shape[0],
+            jax.default_backend())
+        if path != "xla":   # a test's patched rule may say "interpret"
+            return sorted_pairs(state, keys, dV, dgg, path == "interpret")
         with jax.named_scope(SCOPE_APPLY):
             v = state.v.at[keys].add(dV, mode="drop")
             v_gg = state.v_gg.at[keys].add(dgg, mode="drop")
         with jax.named_scope(SCOPE_TOUCHED):
             v_touched = state.v_touched.at[keys].set(
                 jnp.ones(keys.shape, jnp.int8), mode="drop")
+        return state.replace(v=v, v_gg=v_gg, v_touched=v_touched)
+
+    def sorted_pairs(state: FFMState, keys, dV, dgg, interpret: bool):
+        """`apply_pairs` through the kernel: the lanes sorted by key once
+        with V's k columns and gg's riding, V's rows added lane by lane in
+        the 128-row tiles that hold a touched entry and nowhere else, gg
+        and the flag written by XLA's sorted in-place scatters at the same
+        keys. The sums of XLA's form in another order."""
+        from ..kernels.run_write import add_rows_kernel
+
+        with jax.named_scope(SCOPE_REDUCE):
+            ids, dgg = jax.lax.sort((keys, dgg), num_keys=1, is_stable=False)
+            dV = scatter.sort_rows(keys, dV.T)
+        with jax.named_scope(SCOPE_APPLY):
+            v = add_rows_kernel(state.v, ids, list(dV), interpret=interpret)
+            v_gg = state.v_gg.at[ids].add(dgg, mode="drop",
+                                          indices_are_sorted=True)
+        with jax.named_scope(SCOPE_TOUCHED):
+            v_touched = state.v_touched.at[ids].set(
+                jnp.ones(ids.shape, jnp.int8), mode="drop",
+                indices_are_sorted=True)
         return state.replace(v=v, v_gg=v_gg, v_touched=v_touched)
 
     def apply_linear(state: FFMState, idx, val, g, ts):
@@ -914,15 +945,24 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
                          pair_width=pair_width)
     state = init_state_spanned(init_ffm_state, hyper)
     call.set(table_dtype=str(state.v.dtype))
-    kernel_tables = 0
-    if mode == "minibatch" and hyper.linear_coeff:
-        # the linear term's tables are the step's writes at a block's runs
-        # (the pair block adds rows and sets its flag by XLA's scatters)
-        linear = {"w": state.w, "touched": state.touched}
-        if hyper.use_ftrl:
-            linear.update(z=state.z, n=state.n)
-        kernel_tables = record_write_path(call, linear, dims,
-                                          block * pair_width)
+    kernel_lanes = 0   # of a row's lanes, those the step hands the kernel
+    if mode == "minibatch":
+        # V's rows are added through the kernel where `row_write_path` says
+        # so (gg and the flag by XLA's scatters); the linear term's tables
+        # are the step's writes at a block's runs
+        linear = {}
+        if hyper.linear_coeff:
+            linear = {"w": state.w, "touched": state.touched}
+            if hyper.use_ftrl:
+                linear.update(z=state.z, n=state.n)
+        rows = ()
+        if scatter.row_write_path(state.v.dtype, *state.v.shape,
+                                  block * pair_width ** 2,
+                                  jax.default_backend()) != "xla":
+            rows = ("v",)
+            kernel_lanes = pair_width ** 2
+        kernel_lanes += pair_width * record_write_path(
+            call, linear, dims, block * pair_width, rows=rows)
     iters = cl.get_int("iters", 1)
     conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
     # progress counters, as fit_linear keeps them
@@ -951,8 +991,7 @@ def _train_ffm(call, rows, labels, options) -> TrainedFFMModel:
                 epoch_losses.append(loss)
                 row_counter.increment(blk[0].shape[0])
                 padded_lanes.increment(blk[0].shape[0] * pair_width ** 2)
-                kernel_counter.increment(
-                    blk[0].shape[0] * pair_width * kernel_tables)
+                kernel_counter.increment(blk[0].shape[0] * kernel_lanes)
             iter_counter.increment()
             real_lanes.increment(pairs)
             with TRACER.span(SPAN_SYNC, args={"fetches": len(epoch_losses)}):

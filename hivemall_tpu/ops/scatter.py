@@ -214,6 +214,59 @@ def write_runs_together(runs: BlockRuns, writes) -> list:
     return out
 
 
+# A `[D, k]` table's block of row deltas (FFM's V: every lane of an entry
+# adds) is added down one of two paths, chosen the same way. "xla" is XLA's
+# row scatter-add of the lanes as they come (the compiler sorts them): a
+# part per lane, and no part per table length that 2^20 ... 2^28 rows tell
+# apart. "kernel" is `kernels/run_write.py::add_rows_kernel` after one sort
+# of the lanes by row: a part per lane (the sorts, the plan, the add) plus
+# a part per 128-row tile it moves, and it moves at most the table's
+# `D / 128` tiles. The constants, fit to `scripts/scatter_cost.py --rows`
+# on a v5e (`[D, 4]` f32 at 2^20, 2^22, 2^24, 2^26 and 2^28 rows over
+# 409,600 / 819,200 / 1,638,400 lanes of pair keys as the FFM cell's rows
+# hash them, 15 readings a path, the fit within 0.7 ms of each; PERF.md
+# section 7 has them):
+#   xla_ns, stream_ms   XLA's part per lane (81.2-81.6 ns at every size),
+#                       and per 2^28 rows
+#   kernel_ns, tile_ns  the kernel's part per lane, and per tile it moves
+#   tiles_per_lane      the most tiles a lane opened where the table has
+#                       room (2^28 rows: 0.40 at 1,024 rows a block, 0.50
+#                       at 256; a third of a row's pairs are on the numeric
+#                       fields' ids, which every row carries)
+ROW_WRITE_COST = {
+    ("float32", 4): {"xla_ns": 81.3, "stream_ms": 0.0, "kernel_ns": 18.6,
+                     "tile_ns": 44.3, "tiles_per_lane": 0.50},
+}
+
+
+def row_write_path(dtype, dims: int, k: int, lanes: int, backend: str) -> str:
+    """"kernel" or "xla": how a block of `lanes` row deltas is added into a
+    `[dims, k]` table of `dtype` on `backend`."""
+    cost = ROW_WRITE_COST.get((jnp.dtype(dtype).name, k))
+    if backend != "tpu" or cost is None or dims % 128:
+        return "xla"
+    xla_ms = lanes * cost["xla_ns"] * 1e-6 + cost["stream_ms"] * dims / 2 ** 28
+    tiles = min(lanes * cost["tiles_per_lane"], dims / 128)
+    kernel_ms = KERNEL_FIXED_MS + (lanes * cost["kernel_ns"]
+                                   + tiles * cost["tile_ns"]) * 1e-6
+    return "kernel" if xla_ms > kernel_ms else "xla"
+
+
+def sort_rows(ids: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """`rows` [m, N] with each row reordered as `ids` [N] sorts, in the
+    rows' own buffer: each row through the same two-operand sort inside a
+    loop (as `_sort_columns`), written back where it was read. A row
+    reordered on its own is as good as together where the ids are a
+    block's keys: lanes of one id differ only in the order they add. No
+    second `[m, N]` is live (FFM's V deltas are 25 MiB a block)."""
+    def one(i, rows):
+        row = jax.lax.dynamic_index_in_dim(rows, i, keepdims=False)
+        row = jax.lax.sort((ids, row), num_keys=1, is_stable=False)[1]
+        return jax.lax.dynamic_update_index_in_dim(rows, row, i, 0)
+
+    return jax.lax.fori_loop(0, rows.shape[0], one, rows)
+
+
 # --------------------------------------------------------------------------
 # Staged plans: the sort moved to staging time, the scatter shrunk to the
 # unique slots.

@@ -31,6 +31,27 @@ One program a (kind, type, length, lanes), ids as arguments; each timed as
 `--iters` dispatches back to back on a donated table, ended by a value
 fetch. Fails without a TPU unless `--allow-cpu` (a rehearsal: its numbers
 are the CPU backend's and say nothing of the chip).
+
+`--rows` times the row form instead, the readings behind
+`ops/scatter.py::ROW_WRITE_COST`: a block's `[4]` f32 row deltas added
+into a `[D, 4]` table (FFM's V) at D = 2^20 ... 2^28 (`--log2`) over the
+pair lanes of 256 / 512 / 1,024 rows (`--blocks`), keys as the FFM cell's
+rows hash them (`pair_keys`: `benchmark/datagen.make_split` with
+`benchmark/configs/ffm_criteo1tb.json`'s data, `models/ffm.pair_hash` of
+each feature and its partner's field on 40 lanes, the diagonal and the
+40th lane the dropped key D):
+
+- `rows.xla`: `table.at[keys].add(deltas, mode="drop")`, the keys as they
+  come: `models/ffm.py::apply_pairs` where XLA adds.
+- `rows.kernel`: the keys sorted once with the deltas' four rows riding,
+  then `add_rows_kernel`: `apply_pairs`' V where the kernel adds. First
+  held to `rows.xla` on a random table (`gap`: the largest difference over
+  the largest entry, float32 rounding of another order of the sums).
+
+Each line also counts the block's real lanes, distinct keys and the
+128-row tiles they touch.
+
+    python3 scripts/scatter_cost.py --rows --out rows.jsonl   # on a TPU host
 """
 
 from __future__ import annotations
@@ -83,11 +104,117 @@ def heads_only(sorted_ids, dims: int):
     return np.sort(out).astype(np.int32)
 
 
+def pair_keys(rows: int, dims: int, seed: int):
+    """[rows * 40 * 40] keys of the FFM cell's pair block: entry
+    `pair_hash(feature_i, field_j)` on lane (i, j), the dropped key `dims`
+    on the diagonal and on the 40th lane (39 fields a row)."""
+    import numpy as np
+
+    from benchmark.datagen import make_split
+    from hivemall_tpu.models.ffm import pair_hash
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                        "benchmark", "configs", "ffm_criteo1tb.json")
+    with open(path, encoding="utf-8") as f:
+        cfg = json.load(f)
+    ids = make_split(cfg["data"], cfg["num_features"], rows, seed, 0).ids
+    lanes = 40
+    idx = np.zeros((rows, lanes), np.int64)
+    idx[:, :ids.shape[1]] = ids
+    real = np.zeros((rows, lanes), bool)
+    real[:, :ids.shape[1]] = True
+    field = np.broadcast_to(np.arange(lanes), (rows, lanes))
+    keys = pair_hash(idx[:, :, None].astype(np.uint32),
+                     field[:, None, :].astype(np.uint32), dims)
+    pair = real[:, :, None] & real[:, None, :] & ~np.eye(lanes, dtype=bool)
+    return np.where(pair, keys, dims).astype(np.int32).reshape(-1)
+
+
+def rows_main(args) -> int:
+    """`--rows`: the row form's two paths (the module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from hivemall_tpu.kernels.run_write import add_rows_kernel
+    from hivemall_tpu.ops.scatter import sort_rows
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.allow_cpu:
+        print(f"no TPU here (platform {dev.platform!r}): this tool times "
+              "the chip", file=sys.stderr)
+        return 1
+    device = {"platform": dev.platform, "kind": dev.device_kind}
+    interpret = dev.platform != "tpu"
+    xla = jax.jit(lambda t, keys, d: t.at[keys].add(d, mode="drop"),
+                  donate_argnums=(0,))
+
+    def by_kernel(t, keys, d):
+        ids = jax.lax.sort(keys, is_stable=False)
+        return add_rows_kernel(t, ids, list(sort_rows(keys, d.T)),
+                               interpret=interpret)
+
+    kernel = jax.jit(by_kernel, donate_argnums=(0,))
+    gap_of = jax.jit(lambda got, want: jnp.max(jnp.abs(got - want))
+                     / jnp.max(jnp.abs(want)))   # fused: no third table
+
+    def timed(program, table, keys, deltas):
+        for _ in range(3):
+            table = program(table, keys, deltas)
+        float(table[0, 0])
+        t0 = time.perf_counter()
+        for _ in range(args.iters):
+            table = program(table, keys, deltas)
+        float(table[0, 0])
+        return (time.perf_counter() - t0) / args.iters, table
+
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    lines = []
+    with open(args.out, "w", encoding="utf-8") as f:
+        for log2 in args.log2:
+            dims = 1 << log2
+            for rows in args.blocks:
+                keys_np = pair_keys(rows, dims, args.seed + rows)
+                live = keys_np[keys_np < dims]
+                counts = {"real_lanes": int(live.size),
+                          "keys": int(np.unique(live).size),
+                          "tiles": int(np.unique(live // 128).size)}
+                keys = jnp.asarray(keys_np)
+                deltas = jax.random.normal(jax.random.PRNGKey(rows),
+                                           (keys.shape[0], 4)) * 1e-3
+                start = jax.random.normal(jax.random.PRNGKey(log2),
+                                          (dims, 4))
+                want = xla(start + 0, keys, deltas)
+                gap = float(gap_of(kernel(start, keys, deltas), want))
+                del start, want
+                table = jnp.zeros((dims, 4), jnp.float32)
+                for case, program in (("rows.xla", xla),
+                                      ("rows.kernel", kernel)):
+                    sec, table = timed(program, table, keys, deltas)
+                    line = {"case": case, "dtype": "f32", "k": 4,
+                            "log2_dims": log2, "lanes": int(keys.shape[0]),
+                            **counts, "ms": sec * 1e3,
+                            "ns_per_lane": sec * 1e9 / keys.shape[0],
+                            "gap": gap if case == "rows.kernel" else None,
+                            "device": device}
+                    lines.append(line)
+                    f.write(json.dumps(line) + "\n")
+                    f.flush()
+                del table
+    for ln in lines:
+        print(f"| {ln['case']} 2^{ln['log2_dims']} {ln['lanes']} | "
+              f"{ln['ms']:.3f} ms | {ln['ns_per_lane']:.2f} ns a lane | "
+              f"{ln['keys']} keys, {ln['tiles']} tiles | gap {ln['gap']}")
+    print(json.dumps({"device": device, "cases": len(lines),
+                      "largest_gap": max(ln["gap"] or 0 for ln in lines)}))
+    return 0
+
+
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--out", default="chiprun_out/scatter_cost.jsonl")
-    p.add_argument("--log2", type=int, nargs="+",
-                   default=[24, 25, 26, 27, 28, 29])
+    p.add_argument("--log2", type=int, nargs="+", default=None,
+                   help="table lengths (default 24-29; --rows: 20-28 by 2)")
     p.add_argument("--lanes", type=int, nargs="+", default=[16, 40, 64],
                    help="lanes a row; a block has 1,024 rows")
     p.add_argument("--dtypes", nargs="+", default=["s8", "bf16", "f32"])
@@ -96,7 +223,16 @@ def main() -> int:
     p.add_argument("--iters", type=int, default=30)
     p.add_argument("--seed", type=int, default=35)
     p.add_argument("--allow-cpu", action="store_true")
+    p.add_argument("--rows", action="store_true",
+                   help="the row form: rows.xla and rows.kernel")
+    p.add_argument("--blocks", type=int, nargs="+", default=[256, 512, 1024],
+                   help="rows of an FFM block, 1,600 pair lanes a row "
+                   "(--rows)")
     args = p.parse_args()
+    if args.rows:
+        args.log2 = args.log2 or [20, 22, 24, 26, 28]
+        return rows_main(args)
+    args.log2 = args.log2 or [24, 25, 26, 27, 28, 29]
 
     import jax
     import jax.numpy as jnp

@@ -7,6 +7,10 @@ compared step by step: a refactor that claims "the same programs" shows it.
     python3 scripts/step_module_text.py --root <other>    --out b.json
     python3 scripts/step_module_text.py --compare a.json b.json
 
+`--hashes` writes each module text's sha256 in place of the text: the form
+`tests/data/step_module_hashes.json` keeps, which
+`tests/test_minibatch_step_structure.py` holds every step of this tree to.
+
 Nothing runs and nothing is allocated: states are `jax.eval_shape` shapes, on
 the CPU backend with eight virtual devices (the mesh steps need four). Only
 arguments that every tree since PR 26 accepts are passed to the factories,
@@ -39,8 +43,11 @@ def _variants():
     from hivemall_tpu.models import regression as R
     from hivemall_tpu.models.ffm import FFMHyper, init_ffm_state, make_ffm_step
     from hivemall_tpu.models.fm import FMHyper, init_fm_state, make_fm_step
+    from hivemall_tpu.parallel.ffm_mix import FFMMixTrainer
+    from hivemall_tpu.parallel.mesh import make_mesh
     from hivemall_tpu.parallel.mix import MixedReplicas
-    from hivemall_tpu.parallel.sharded_train import (FMShardedTrainer,
+    from hivemall_tpu.parallel.sharded_train import (FFMShardedTrainer,
+                                                     FMShardedTrainer,
                                                      ShardedTrainer)
 
     S = jax.ShapeDtypeStruct
@@ -175,10 +182,24 @@ def _variants():
                                1 << 22, mesh=mesh)
         out[f"sharded.fm.k{k}"] = ftr._step.lower(
             jax.eval_shape(ftr._init_fn), *block(1024, 64), va)
+    # FFM's stripe step (scatter-adds a row group) and its -mix replicas
+    # (the mini-batch step inside shard_map)
+    fh = FFMHyper(factors=4, num_features=1 << 16, num_fields=16,
+                  v_dims=1 << 20)
+    fblk = (S((64, 8), jnp.int32), S((64, 8), jnp.float32),
+            S((64, 8), jnp.int32), S((64,), jnp.float32))
+    stripes = FFMShardedTrainer(fh, mesh=mesh)
+    out["sharded.ffm"] = stripes._step.lower(
+        jax.eval_shape(stripes._init_fn), *fblk)
+    mtr = FFMMixTrainer(fh, mesh=make_mesh(devices=devs))
+    four = lambda a, *lead: S((4,) + lead + a.shape, a.dtype)   # replicas
+    out["mix.ffm"] = mtr._step.lower(
+        jax.tree.map(four, jax.eval_shape(lambda: init_ffm_state(fh))),
+        *(four(a, 2) for a in fblk))   # two blocks a replica
     return out
 
 
-def dump(root: str, path: str) -> None:
+def dump(root: str, path: str, hashes: bool = False) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                " --xla_force_host_platform_device_count=8")
@@ -187,9 +208,15 @@ def dump(root: str, path: str) -> None:
     import hivemall_tpu
     print("lowered from", os.path.dirname(hivemall_tpu.__file__))
     with open(path, "w", encoding="utf-8") as f:
-        json.dump(texts, f)
+        json.dump({k: digest(t) for k, t in texts.items()} if hashes
+                  else texts, f, indent=1 if hashes else None,
+                  sort_keys=hashes)
     for k, t in texts.items():
-        print(hashlib.sha256(t.encode()).hexdigest()[:16], len(t), k)
+        print(digest(t)[:16], len(t), k)
+
+
+def digest(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def compare(a_path: str, b_path: str) -> int:
@@ -212,12 +239,14 @@ def main() -> int:
         os.path.abspath(__file__)), ".."))
     p.add_argument("--out")
     p.add_argument("--compare", nargs=2, metavar=("A", "B"))
+    p.add_argument("--hashes", action="store_true",
+                   help="write each text's sha256, not the text")
     args = p.parse_args()
     if args.compare:
         return compare(*args.compare)
     if not args.out:
         p.error("--out or --compare")
-    dump(args.root, args.out)
+    dump(args.root, args.out, args.hashes)
     return 0
 
 

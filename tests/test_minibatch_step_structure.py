@@ -15,9 +15,19 @@ run-write kernel where the backend is a TPU (`ops/scatter.write_path`); (b)
 also compiles two cells' steps with the kernels in them (the rule asked as
 on a TPU: `jax.default_backend()` is the CPU's here) and holds them to the
 same: every table aliased to its result, no copy of one, nothing as long
-as a table but the kernels' calls.
+as a table but the kernels' calls. FFM's step with V's rows added through
+the kernel's row form (`ops/scatter.row_write_path`) is held to the same
+at the FFM cell's shape.
+
+(c) every step `scripts/step_module_text.py` lowers (the linear engine's,
+FM's, FFM's mini-batch, scan, stripe and `-mix` steps) lowers to the module
+text recorded in `tests/data/step_module_hashes.json`: a change that claims
+"the same programs" is held to it, and one that changes a step on purpose
+records the new text (`step_module_text.py --hashes`).
 """
 
+import importlib.util
+import json
 import os
 import re
 
@@ -386,3 +396,104 @@ def test_compiled_emission_program_has_no_table_long_scratch(one_chip, name):
         assert m.output_size_in_bytes <= 1.07 * sum(
             emission.GATHER_CHUNK * t.dtype.itemsize
             * (t.shape[1] if t.ndim > 1 else 1) for t in args[0])
+
+
+# ---- (b) FFM's step with V's rows through the kernel, for a described v5e ----
+
+def _ffm_cell_args(one_chip, hyper, rows=1024, width=64):
+    from hivemall_tpu.models import ffm as F
+
+    on = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    state = jax.tree_util.tree_map(
+        on, jax.eval_shape(lambda: F.init_ffm_state(hyper)))
+    block = [jax.ShapeDtypeStruct((rows, width), dt, sharding=one_chip)
+             for dt in (jnp.int32, jnp.float32, jnp.int32)]
+    return state, block + [jax.ShapeDtypeStruct((rows,), jnp.float32,
+                                                sharding=one_chip)]
+
+
+def test_compiled_ffm_cell_step_adds_v_in_place_through_the_kernel(
+        monkeypatch, one_chip):
+    """The FFM cell's step (V `[2^28, 4]` f32, 1,024 rows of 39 fields on
+    40 lanes) as a TPU process traces it: the rule takes the kernel for V;
+    V goes into the kernel as the bitcast `[4, 2^28]` view of its own
+    `{0,1:T(4,128)}` buffer and comes back aliased, with no copy,
+    transpose or fusion of V's length beside the call (the table moved
+    each step); gg and the flag are XLA's in-place writes; temporaries
+    under the XLA step's bound. `init_ffm_state` makes V in the layout the
+    step takes, so the state needs no relayout between them."""
+    from hivemall_tpu.models import ffm as F
+    from hivemall_tpu.ops import scatter
+
+    by_rule = scatter.row_write_path
+    asked = []
+
+    def as_on_a_tpu(dtype, dims, k, lanes, _):
+        asked.append(by_rule(dtype, dims, k, lanes, "tpu"))
+        return asked[-1]
+
+    monkeypatch.setattr(scatter, "row_write_path", as_on_a_tpu)
+    hyper = F.FFMHyper(factors=4, num_features=1 << 23, num_fields=39,
+                       v_dims=1 << 28, eta0_v=0.01)
+    state, block = _ffm_cell_args(one_chip, hyper)
+    compiled = _compile_uncached(
+        F.make_ffm_step(hyper, "minibatch", pair_width=40).lower(
+            state, *block))
+    assert asked == ["kernel"]
+    m = compiled.memory_analysis()
+    v_bytes = hyper.v_dims * 4 * 4
+    assert m.alias_size_in_bytes >= v_bytes + hyper.v_dims * 5
+    assert m.temp_size_in_bytes <= (64 << 20) + 4 * (8 << 20)
+    dims = hyper.v_dims
+    text = compiled.as_text()
+    layout = re.search(rf"f32\[{dims},4\]\{{([^}}]*)\}}", text).group(1)
+    assert layout == "0,1:T(4,128)", layout
+    kernel, v_long = 0, []
+    for result, opcode, line in _instructions(text):
+        if str(dims) not in result or opcode in PASSES_ON:
+            continue
+        if opcode == "custom-call" and "%run_scatter_add_rows_float32" in line:
+            assert "tpu_custom_call" in line, line[:200]
+            assert f"f32[4,{dims}]{{1,0:T(4,128)}}" in result, result
+            kernel += 1
+        elif f"[{dims},4]" in result or f"[4,{dims}]" in result \
+                or f"[{dims * 4}]" in result:
+            v_long.append(line[:200])
+        else:   # gg's and the flag's in-place writes, alone
+            assert opcode == "scatter" or (
+                opcode == "fusion" and re.search(
+                    r'op_name="[^"]*/scatter(-add)?"', line)), line[:200]
+    assert kernel == 1 and not v_long, v_long
+    init = _compile_uncached(jax.jit(
+        lambda: F.init_ffm_state(hyper),
+        out_shardings=one_chip).lower()).as_text()
+    assert f"f32[{dims},4]{{0,1:T(4,128)}}" in init
+
+
+# ---- (c) every step's module text, as recorded ----
+
+HASHES_PATH = os.path.join(os.path.dirname(__file__), "data",
+                           "step_module_hashes.json")
+with open(HASHES_PATH, encoding="utf-8") as f:
+    RECORDED = json.load(f)
+
+
+@pytest.fixture(scope="module")
+def lowered_texts():
+    """sha256 of every module text `scripts/step_module_text.py` lowers,
+    lowered in this process (the CPU, the conftest's eight devices)."""
+    path = os.path.join(os.path.dirname(__file__), "..", "scripts",
+                        "step_module_text.py")
+    spec = importlib.util.spec_from_file_location("step_module_text", path)
+    smt = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smt)
+    return {k: smt.digest(low.as_text()) for k, low in smt._variants().items()}
+
+
+def test_the_recorded_steps_are_the_steps_lowered(lowered_texts):
+    assert sorted(lowered_texts) == sorted(RECORDED)
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_step_lowers_to_its_recorded_module_text(lowered_texts, name):
+    assert lowered_texts.get(name) == RECORDED[name]

@@ -423,3 +423,263 @@ def test_no_pallas_is_imported_where_no_step_takes_the_kernel():
         assert modules == [], (where, modules)
     assert report["writes"] == {name: "xla" for name in report["writes"]}
     assert len(report["writes"]) == 4
+
+
+# ---- the row form: a block's [4] row deltas added into a [D, 4] f32 table ----
+
+ROWS_DIMS = 1 << 14     # 128 tiles of ROW_TILE rows
+
+
+def _row_keys(kind, rng, dims):
+    """A block's pair keys as `sorted_pairs` hands them to the kernel:
+    ascending, every dropped lane `== dims` at the tail."""
+    from hivemall_tpu.kernels.run_write import ROW_TILE as T
+
+    if kind == "repeated_keys":             # 7 keys on 900 lanes
+        ids = rng.choice(rng.choice(dims, 7, replace=False), 900)
+    elif kind == "a_run_across_a_chunk":
+        # lanes CHUNK-50 ... CHUNK+49 are one key: two grid steps add it
+        ids = np.concatenate([rng.integers(0, 40 * T, CHUNK - 50),
+                              np.full(100, 41 * T + 5),
+                              rng.integers(42 * T, dims, 300)])
+    elif kind == "a_tile_shared_by_two_chunks":
+        # lanes CHUNK-60 ... CHUNK+59 are 120 rows of ONE tile, some twice
+        ids = np.concatenate([rng.integers(0, 50 * T, CHUNK - 60),
+                              50 * T + rng.integers(0, T, 120),
+                              rng.integers(51 * T, dims, 200)])
+    elif kind == "dropped_key_at_the_tail":
+        ids = np.concatenate([rng.integers(0, dims, 1000),
+                              np.full(700, dims)])
+    elif kind == "keys_in_the_first_and_last_tile":
+        ids = np.concatenate([[0, 0, 1, T - 1, dims - T, dims - 2, dims - 1,
+                               dims - 1],
+                              rng.integers(0, T, 50),
+                              rng.integers(dims - T, dims, 50)])
+    elif kind == "all_keys_differ":
+        ids = rng.choice(dims, 3000, replace=False)
+    elif kind == "as_the_cells_pairs":
+        # a third of the lanes on keys every row carries, the rest hashed
+        # ranks, 1/40 dropped
+        rank = np.exp(rng.random(2000) * np.log(dims)).astype(np.int64)
+        ids = np.concatenate([np.repeat(np.arange(13) * 7919 % dims, 80),
+                              rank * 2654435761 % dims, np.full(80, dims)])
+    return np.sort(np.asarray(ids, np.int64)).astype(np.int32)
+
+
+ROW_KINDS = ["repeated_keys", "a_run_across_a_chunk",
+             "a_tile_shared_by_two_chunks", "dropped_key_at_the_tail",
+             "keys_in_the_first_and_last_tile", "all_keys_differ",
+             "as_the_cells_pairs"]
+
+
+def _rounding_bound(table, ids, deltas):
+    """Per entry, what two float32 sums of the same terms in two orders may
+    differ by: the terms' magnitude times their count times float32's
+    epsilon."""
+    dims = table.shape[0]
+    live = ids < dims
+    mag = np.abs(table).astype(np.float64)
+    count = np.zeros(dims)
+    np.add.at(mag, ids[live], np.abs(deltas[live]))
+    np.add.at(count, ids[live], 1)
+    return mag * (count[:, None] + 1) * np.finfo(np.float32).eps
+
+
+@pytest.mark.parametrize("kind", ROW_KINDS)
+def test_row_kernel_adds_as_xla_to_float32_rounding(kind):
+    from hivemall_tpu.kernels.run_write import add_rows_kernel
+
+    rng = np.random.default_rng(len(kind))
+    ids = _row_keys(kind, rng, ROWS_DIMS)
+    table = rng.normal(size=(ROWS_DIMS, 4)).astype(np.float32)
+    deltas = (rng.normal(size=(ids.size, 4)) * 3).astype(np.float32)
+    got = np.asarray(add_rows_kernel(
+        jnp.asarray(table), jnp.asarray(ids),
+        [jnp.asarray(deltas[:, f]) for f in range(4)], interpret=True))
+    xla = np.asarray(jnp.asarray(table).at[ids].add(deltas, mode="drop"))
+    live = ids < ROWS_DIMS
+    want = table.astype(np.float64)
+    np.add.at(want, ids[live], deltas[live].astype(np.float64))
+    bound = _rounding_bound(table, ids, deltas)
+    assert np.all(np.abs(got - want) <= bound)
+    assert np.all(np.abs(xla - want) <= bound)
+    # a row no lane addresses keeps its bits; one that a single lane adds
+    # to is one float32 addition either way
+    count = np.bincount(ids[live], minlength=ROWS_DIMS)
+    assert _bits(got[count == 0]) == _bits(table[count == 0])
+    assert _bits(got[count == 1]) == _bits(xla[count == 1])
+    assert (count > 1).any() or kind == "all_keys_differ"
+
+
+def test_row_kernel_refuses_what_it_does_not_serve():
+    from hivemall_tpu.kernels.run_write import add_rows_kernel
+
+    ids = jnp.arange(64, dtype=jnp.int32)
+    cols = [jnp.ones(64)] * 4
+    for table in (jnp.zeros((1 << 12, 16)),           # FM's 16-wide V
+                  jnp.zeros((1 << 12, 4), jnp.bfloat16),
+                  jnp.zeros((1 << 12,)),              # a [D] table
+                  jnp.zeros(((1 << 12) + 64, 4))):    # a tile short
+        with pytest.raises(ValueError, match="no run-write kernel"):
+            add_rows_kernel(table, ids, cols[:table.shape[-1]],
+                            interpret=True)
+
+
+# The row rule as a table: (storage type, D, k, N, backend) -> path. The
+# FFM cell's V (1,024 rows of 40 x 40 pair lanes into [2^28, 4] f32), the
+# shapes between, and what stays on XLA whatever its length.
+ROW_WRITE_PATHS = [
+    ("float32", 1 << 28, 4, 1638400, "tpu", "kernel"),
+    ("float32", 1 << 28, 4, 409600, "tpu", "kernel"),
+    # every other backend
+    ("float32", 1 << 28, 4, 1638400, "cpu", "xla"),
+    ("float32", 1 << 28, 4, 1638400, "gpu", "xla"),
+    # FM's V, [2^23, 16] (its layout is `{0,1:T(8,128)}`), and k = 8
+    ("float32", 1 << 23, 16, 65536, "tpu", "xla"),
+    ("float32", 1 << 28, 8, 1638400, "tpu", "xla"),
+    # types the kernel does not add in, and a length short of a tile
+    ("bfloat16", 1 << 28, 4, 1638400, "tpu", "xla"),
+    ("float32", (1 << 28) + 64, 4, 1638400, "tpu", "xla"),
+]
+
+
+@pytest.mark.parametrize("dtype,dims,k,lanes,backend,want", ROW_WRITE_PATHS)
+def test_row_write_path_is_a_function_of_type_shapes_and_backend(
+        dtype, dims, k, lanes, backend, want):
+    assert scatter.row_write_path(jnp.dtype(dtype), dims, k, lanes,
+                                  backend) == want
+
+
+def test_the_row_rule_is_its_cost_model():
+    """The kernel is taken where its lanes and tiles cost less than XLA's
+    lanes and stream; a small block into a small table, where the launch
+    and the plan dominate, stays with XLA."""
+    cost = scatter.ROW_WRITE_COST[("float32", 4)]
+    for log2 in (20, 24, 28):
+        dims = 1 << log2
+        for lanes in (1 << 10, 409600, 1638400):
+            xla = lanes * cost["xla_ns"] * 1e-6 \
+                + cost["stream_ms"] * dims / 2 ** 28
+            tiles = min(lanes * cost["tiles_per_lane"], dims / 128)
+            kernel = scatter.KERNEL_FIXED_MS + 1e-6 * (
+                lanes * cost["kernel_ns"] + tiles * cost["tile_ns"])
+            want = "kernel" if xla > kernel else "xla"
+            assert scatter.row_write_path(jnp.float32, dims, 4, lanes,
+                                          "tpu") == want
+    assert scatter.row_write_path(jnp.float32, 1 << 12, 4, 64, "tpu") \
+        == "xla"
+
+
+def _ffm_block(rng, rows, width, dims, fields):
+    idx = rng.integers(0, dims, size=(rows, width)).astype(np.int32)
+    idx[:, :3] = np.arange(3)             # ids every row carries
+    val = np.ones((rows, width), np.float32)
+    val[:, width - 2:] = 0.0              # two padding lanes a row
+    idx[:, width - 2:] = dims
+    fld = np.broadcast_to(np.arange(width) % fields, (rows, width))
+    y = np.sign(rng.normal(size=rows)).astype(np.float32)
+    return idx, val, fld.astype(np.int32), y
+
+
+FFM_STEP_CASES = {
+    # name -> (FFMHyper arguments, row_chunk)
+    "ftrl": (dict(), None),
+    "sgd_w": (dict(use_ftrl=False), None),
+    "no_linear": (dict(linear_coeff=False), None),
+    "row_tiles": (dict(global_bias=True), 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FFM_STEP_CASES))
+def test_ffm_step_with_the_row_kernel_adds_as_xla(monkeypatch, case):
+    """FFM's `-mini_batch` step with V's rows added through the kernel
+    (`sorted_pairs`, the rule patched to answer "interpret") against the
+    same step on XLA's scatter-adds: V and gg to float32 rounding (the
+    same sums in another order), the flags and the linear term as XLA's,
+    the loss."""
+    from hivemall_tpu.models import ffm as F
+
+    args, row_chunk = FFM_STEP_CASES[case]
+    hyper = F.FFMHyper(factors=4, num_features=1 << 10, num_fields=6,
+                       v_dims=1 << 12, eta0_v=0.05, **args)
+    rng = np.random.default_rng(len(case))
+    block = _ffm_block(rng, 64, 8, hyper.num_features, hyper.num_fields)
+    step = lambda: jax.jit(F.make_ffm_step(hyper, "minibatch", row_chunk,
+                                           jit=False))
+    want, want_loss = step()(F.init_ffm_state(hyper), *block)
+    asked = []
+
+    def interpreted(dtype, dims, k, lanes, backend):
+        asked.append((jnp.dtype(dtype).name, dims, k, lanes))
+        return "interpret"
+
+    monkeypatch.setattr(scatter, "row_write_path", interpreted)
+    got, got_loss = step()(F.init_ffm_state(hyper), *block)
+    assert asked == [("float32", hyper.v_dims, 4, 64 * 8 * 8)]
+    start = np.asarray(F.init_ffm_state(hyper).v)
+    assert (np.asarray(want.v) != start).any()
+    for name in ("v", "v_gg", "w", "z", "n", "w0"):
+        np.testing.assert_allclose(np.asarray(getattr(got, name)),
+                                   np.asarray(getattr(want, name)),
+                                   rtol=2e-6, atol=1e-7, err_msg=name)
+    for name in ("v_touched", "touched", "step"):
+        assert _bits(getattr(got, name)) == _bits(getattr(want, name))
+    np.testing.assert_allclose(float(got_loss), float(want_loss), rtol=1e-6)
+
+
+def test_ffm_scan_and_stripe_steps_never_ask_the_row_rule(monkeypatch):
+    """The scan adds a row at a time and a stripe a row group: neither
+    writes a block's pair lanes, so neither asks."""
+    from hivemall_tpu.models import ffm as F
+    from hivemall_tpu.parallel.mesh import make_mesh
+    from hivemall_tpu.parallel.sharded_train import FFMShardedTrainer
+
+    def refused(*a):
+        raise AssertionError("the row rule was asked")
+
+    monkeypatch.setattr(scatter, "row_write_path", refused)
+    hyper = F.FFMHyper(factors=4, num_features=1 << 10, num_fields=6,
+                       v_dims=1 << 12, eta0_v=0.05)
+    block = _ffm_block(np.random.default_rng(3), 32, 8, hyper.num_features,
+                       hyper.num_fields)
+    _, loss = F.make_ffm_step(hyper, "scan")(F.init_ffm_state(hyper),
+                                             *block)
+    assert np.isfinite(float(loss))
+    tr = FFMShardedTrainer(hyper, make_mesh(2))
+    _, loss = tr.step(tr.init(), *block)
+    assert np.isfinite(float(loss))
+
+
+@pytest.mark.parametrize("rule,write,lanes_a_row", [
+    # on the CPU V is added by XLA's scatter-add
+    (None, "xla", 0),
+    # where the rule sends V to the kernel (here through the interpreter)
+    ("interpret", "kernel:v", 8 * 8),
+])
+def test_train_ffm_says_how_v_is_written(monkeypatch, rule, write,
+                                         lanes_a_row):
+    """`write` on `train_ffm`'s `train.call`, and `train.kernel_write_lanes`
+    beside `hivemall.ffm.examples`: rows x the pair lanes handed to the
+    kernel."""
+    from hivemall_tpu.runtime.metrics import REGISTRY
+    from hivemall_tpu.runtime.tracing import TRACER
+    from hivemall_tpu.sql.registry import get_function
+
+    if rule:
+        monkeypatch.setattr(scatter, "row_write_path", lambda *a: rule)
+    rng = np.random.default_rng(0)
+    rows = 96
+    idx = rng.integers(0, 4096, size=(rows, 6))
+    fields = np.broadcast_to(np.arange(6), idx.shape)
+    counter = REGISTRY.counter("train", "kernel_write_lanes")
+    before = counter.value
+    TRACER.clear()
+    get_function("train_ffm")(
+        (idx, np.ones(idx.shape, np.float32), fields),
+        np.sign(rng.normal(size=rows)),
+        "-feature_hashing 12 -num_fields 6 -factor 4 -v_bits 12 "
+        "-mini_batch 32 -eta0_V 0.01")
+    (call,) = [sp for sp in TRACER.traces()[-1]["spans"]
+               if sp["name"] == "train.call"]
+    assert call["args"]["write"] == write
+    assert counter.value - before == rows * lanes_a_row
