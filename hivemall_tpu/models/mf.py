@@ -25,16 +25,24 @@ replay re-runs staged arrays (replaces the 64KiB NIO disk spill, ref: :92,203).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from flax import struct
 
+from ..core.emission import select_rows, table_to_host
 from ..ops.convergence import ConversionState
 from ..ops.eta import EtaEstimator, get_eta
+from ..runtime.metrics import REGISTRY
+from ..runtime.tracing import (SCOPE_APPLY, SCOPE_GATHER, SCOPE_RULE,
+                               SCOPE_TOUCHED, SPAN_CALL, SPAN_DATA_PREP,
+                               SPAN_EMIT, SPAN_EPOCH, SPAN_STAGE, SPAN_SYNC,
+                               TRACER)
 from ..utils.options import Options
+from .base import dispatch_step, init_state_spanned
 
 
 @struct.dataclass
@@ -70,13 +78,22 @@ class MFHyper:
 
 
 def init_mf_state(num_users: int, num_items: int, hyper: MFHyper) -> MFState:
+    """A fresh state of `num_users` x `num_items`, made by one program. On a
+    TPU a `[N, k]` table is laid out with N minor (`{0,1:T(8,128)}`), so a
+    row costs k rounded up to 8 sublanes: 64 B at k = 10, not a 128-lane
+    tile."""
+    return _init_mf_state(int(num_users), int(num_items), hyper)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def _init_mf_state(num_users: int, num_items: int, hyper: MFHyper) -> MFState:
     k = hyper.factor
     key = jax.random.PRNGKey(hyper.seed)
     ku, ki = jax.random.split(key)
     if hyper.rankinit == "gaussian":
         P = jax.random.normal(ku, (num_users, k)) * hyper.min_init_stddev
         Q = jax.random.normal(ki, (num_items, k)) * hyper.min_init_stddev
-    else:  # 'random' uniform in [0, maxval/k-ish] (ref: Rating.rand init)
+    else:  # 'random': [0, maxval), where upstream's is [0, maxval / k) (ref: Rating.rand init)
         P = jax.random.uniform(ku, (num_users, k), maxval=hyper.maxval)
         Q = jax.random.uniform(ki, (num_items, k), maxval=hyper.maxval)
     gg = (jnp.zeros((num_users, k)), jnp.zeros((num_items, k))) if hyper.adagrad \
@@ -95,68 +112,97 @@ def init_mf_state(num_users: int, num_items: int, hyper: MFHyper) -> MFState:
 
 def make_mf_step(hyper: MFHyper, mode: str = "minibatch",
                  jit: bool = True):
-    """Rating-MF block update over (users [B], items [B], ratings [B])."""
+    """Rating-MF block update over (users [B], items [B], ratings [B]).
 
-    def row_deltas(st: MFState, u, i, r, t):
+    A row whose user id is the user table's length is padding: its reads
+    are zeros, its writes are dropped and the step counter does not count
+    it. A call pads its short last block with such rows, so that every
+    block of a call runs one program.
+
+    `minibatch` decides every row of a block against the block's starting
+    state and adds the rows' deltas at their ids: a row that repeats a
+    user or an item adds its own delta beside the others'. Each table is
+    read and written by one gather and one in-place scatter-add of the
+    block's rows (written as one `.at[ids]` over the block, not a vmap of
+    a row's read, which the TPU compiler serves from a row-major copy of
+    the whole table). `scan` is upstream's row at a time."""
+    lam = hyper.lambda_
+
+    def deltas(Pu, Qi, bu, bi, ggp, ggq, mu, r, t):
+        """The rule on gathered rows (one row, or a block's: `err` and `t`
+        then lead the rows' shape). `ggp` / `ggq` are the AdaGrad
+        accumulators before the row."""
         eta = hyper.eta.eta(t)
-        Pu = st.P[u]
-        Qi = st.Q[i]
-        bu = st.Bu[u] if hyper.use_bias else 0.0
-        bi = st.Bi[i] if hyper.use_bias else 0.0
-        pred = st.mu + bu + bi + jnp.dot(Pu, Qi)
-        err = r - pred
-        lam = hyper.lambda_
-        gq = err * Pu - lam * Qi
-        gp = err * Qi - lam * Pu
+        err = r - (mu + bu + bi + jnp.sum(Pu * Qi, axis=-1))
+        e = err[..., None]
+        gq = e * Pu - lam * Qi
+        gp = e * Qi - lam * Pu
         if hyper.adagrad:
             # scaled accumulator trick (ref: MatrixFactorizationAdaGradUDTF.java:111-123)
-            ggp = st.P_gg[u] + gp * (gp / hyper.scaling)
-            ggq = st.Q_gg[i] + gq * (gq / hyper.scaling)
-            eta_p = hyper.eta.eta0 / jnp.sqrt(hyper.eps + ggp * hyper.scaling)
-            eta_q = hyper.eta.eta0 / jnp.sqrt(hyper.eps + ggq * hyper.scaling)
-            dP, dQ = eta_p * gp, eta_q * gq
             dggp, dggq = gp * (gp / hyper.scaling), gq * (gq / hyper.scaling)
+            eta_p = hyper.eta.eta0 / jnp.sqrt(
+                hyper.eps + (ggp + dggp) * hyper.scaling)
+            eta_q = hyper.eta.eta0 / jnp.sqrt(
+                hyper.eps + (ggq + dggq) * hyper.scaling)
+            dP, dQ = eta_p * gp, eta_q * gq
         else:
-            dP, dQ = eta * gp, eta * gq
+            dP, dQ = eta[..., None] * gp, eta[..., None] * gq
             dggp = dggq = None
-        dbu = eta * (err - lam * bu) if hyper.use_bias else 0.0
-        dbi = eta * (err - lam * bi) if hyper.use_bias else 0.0
-        dmu = eta * err if (hyper.use_bias and hyper.update_mean) else 0.0
-        loss = err * err
-        return dP, dQ, dbu, dbi, dmu, dggp, dggq, loss
+        dbu = eta * (err - lam * bu) if hyper.use_bias else None
+        dbi = eta * (err - lam * bi) if hyper.use_bias else None
+        dmu = eta * err if (hyper.use_bias and hyper.update_mean) else None
+        return dP, dQ, dbu, dbi, dmu, dggp, dggq, err * err
 
-    def apply(st: MFState, u, i, dP, dQ, dbu, dbi, dmu, dggp, dggq, nb):
-        st = st.replace(
-            P=st.P.at[u].add(dP),
-            Q=st.Q.at[i].add(dQ),
-            touched_u=st.touched_u.at[u].set(1),
-            touched_i=st.touched_i.at[i].set(1),
-            step=st.step + nb,
-        )
-        if hyper.use_bias:
-            st = st.replace(Bu=st.Bu.at[u].add(dbu), Bi=st.Bi.at[i].add(dbi),
-                            mu=st.mu + jnp.sum(dmu))
-        if hyper.adagrad:
-            st = st.replace(P_gg=st.P_gg.at[u].add(dggp), Q_gg=st.Q_gg.at[i].add(dggq))
-        return st
+    def gather(st: MFState, u, i):
+        """The rows the rule reads; zeros at a padding row's ids."""
+        get = lambda table, ids: table.at[ids].get(mode="fill", fill_value=0)
+        with jax.named_scope(SCOPE_GATHER):
+            Pu, Qi = get(st.P, u), get(st.Q, i)
+            bu = get(st.Bu, u) if hyper.use_bias else 0.0
+            bi = get(st.Bi, i) if hyper.use_bias else 0.0
+            ggp = get(st.P_gg, u) if hyper.adagrad else None
+            ggq = get(st.Q_gg, i) if hyper.adagrad else None
+        return Pu, Qi, bu, bi, ggp, ggq
+
+    def apply(st: MFState, u, i, real, d):
+        """The deltas `d` added at the rows' ids in place; a padding row's
+        ids are past the tables' ends and its writes are dropped."""
+        dP, dQ, dbu, dbi, dmu, dggp, dggq = d
+        add = lambda table, ids, x: table.at[ids].add(x, mode="drop")
+        with jax.named_scope(SCOPE_APPLY):
+            st = st.replace(P=add(st.P, u, dP), Q=add(st.Q, i, dQ))
+            if hyper.use_bias:
+                st = st.replace(Bu=add(st.Bu, u, dbu), Bi=add(st.Bi, i, dbi))
+                if hyper.update_mean:
+                    st = st.replace(mu=st.mu + jnp.sum(jnp.where(real, dmu, 0.0)))
+            if hyper.adagrad:
+                st = st.replace(P_gg=add(st.P_gg, u, dggp),
+                                Q_gg=add(st.Q_gg, i, dggq))
+        with jax.named_scope(SCOPE_TOUCHED):
+            one = jnp.ones(jnp.shape(u), jnp.int8)
+            st = st.replace(touched_u=st.touched_u.at[u].set(one, mode="drop"),
+                            touched_i=st.touched_i.at[i].set(one, mode="drop"))
+        return st.replace(step=st.step + jnp.sum(real, dtype=jnp.int32))
 
     def scan_step(state: MFState, users, items, ratings):
         def body(st, row):
             u, i, r = row
+            real = u < st.P.shape[0]
             t = (st.step + 1).astype(jnp.float32)
-            dP, dQ, dbu, dbi, dmu, dggp, dggq, loss = row_deltas(st, u, i, r, t)
-            return apply(st, u, i, dP, dQ, dbu, dbi, dmu, dggp, dggq, 1), loss
+            *d, loss = deltas(*gather(st, u, i), st.mu, r, t)
+            return apply(st, u, i, real, d), jnp.where(real, loss, 0.0)
 
         state, losses = jax.lax.scan(body, state, (users, items, ratings))
         return state, jnp.sum(losses)
 
     def minibatch_step(state: MFState, users, items, ratings):
-        b = users.shape[0]
-        ts = (state.step + 1 + jnp.arange(b)).astype(jnp.float32)
-        dP, dQ, dbu, dbi, dmu, dggp, dggq, loss = jax.vmap(
-            lambda u, i, r, t: row_deltas(state, u, i, r, t))(users, items, ratings, ts)
-        return apply(state, users, items, dP, dQ, dbu, dbi, dmu, dggp, dggq, b), \
-            jnp.sum(loss)
+        real = users < state.P.shape[0]
+        ts = (state.step + 1 + jnp.arange(users.shape[0])).astype(jnp.float32)
+        rows = gather(state, users, items)
+        with jax.named_scope(SCOPE_RULE):
+            *d, loss = deltas(*rows, state.mu, ratings, ts)
+            loss = jnp.sum(jnp.where(real, loss, 0.0))
+        return apply(state, users, items, real, d), loss
 
     step = scan_step if mode == "scan" else minibatch_step
     # jit=False returns the raw traceable fn for embedding in an outer scan
@@ -281,14 +327,25 @@ class TrainedMFModel:
 
     def model_rows(self):
         """(idx, Pu, Qi, Bu, Bi, mu) — the reference's per-index emission
-        (ref: OnlineMatrixFactorizationUDTF close/forward)."""
-        tu = np.nonzero(np.asarray(self.state.touched_u))[0]
-        ti = np.nonzero(np.asarray(self.state.touched_i))[0]
-        return {
-            "users": (tu, np.asarray(self.state.P)[tu], np.asarray(self.state.Bu)[tu]),
-            "items": (ti, np.asarray(self.state.Q)[ti], np.asarray(self.state.Bi)[ti]),
-            "mu": float(self.state.mu),
-        }
+        (ref: OnlineMatrixFactorizationUDTF close/forward): the rows of the
+        users and of the items a trained rating touched, selected where the
+        tables are (`core/emission.select_rows`), so what crosses to the
+        host is each side's packed flags and its emitted rows."""
+        st = self.state
+        with TRACER.span(SPAN_EMIT, args={
+                "table_dtype": str(st.P.dtype)}) as emit:
+            users, (P, Bu), stats = select_rows(
+                st.touched_u, [("P", st.P), ("Bu", st.Bu)])
+            items, (Q, Bi), i_stats = select_rows(
+                st.touched_i, [("Q", st.Q), ("Bi", st.Bi)])
+            for key in ("chunks", "d2h_bytes", "h2d_bytes"):
+                stats[key] = stats.get(key, 0) + i_stats.get(key, 0)
+            mu = table_to_host(st.mu, "mu", stats)
+            emit.set(rows_out=len(users) + len(items),
+                     item_rows_out=len(items), **stats)
+        REGISTRY.counter("emit", "rows").increment(len(users) + len(items))
+        return {"users": (users, P, Bu), "items": (items, Q, Bi),
+                "mu": float(mu)}
 
 
 def _mf_options(bpr: bool = False) -> Options:
@@ -315,6 +372,13 @@ def _mf_options(bpr: bool = False) -> Options:
     o.add("seed", None, True, "Init seed", default=31, type=int)
     o.add("mini_batch", None, True, "Mini batch size [default 1 = exact scan]",
           default=1, type=int)
+    o.add("num_users", None, True,
+          "Rows of the user table: ids are 0 .. num_users - 1, and every "
+          "call of a deployment keeps one state shape [default: the "
+          "call's largest user id + 1]", type=int)
+    o.add("num_items", None, True,
+          "Rows of the item table, as -num_users [default: the call's "
+          "largest item id + 1]", type=int)
     if bpr:
         o.add("loss", "loss_function", True,
               "Loss [lnLogistic (default), logistic, sigmoid]", default="lnLogistic")
@@ -337,50 +401,112 @@ def _mf_options(bpr: bool = False) -> Options:
     return o
 
 
-def _dims_from(idx, given: Optional[int]) -> int:
-    return given if given is not None else int(np.max(idx)) + 1
+def _dims_from(idx, given: Optional[int], what: str = "id") -> int:
+    """The table's rows: `given`, which every id must be under, or else the
+    largest id + 1."""
+    if len(idx) and int(np.min(idx)) < 0:
+        raise ValueError(f"a {what} id is negative: {int(np.min(idx))}")
+    if given is None:
+        return int(np.max(idx)) + 1 if len(idx) else 1
+    if len(idx) and int(np.max(idx)) >= given:
+        raise ValueError(f"{what} id {int(np.max(idx))} is at or beyond the "
+                         f"table's {given} rows (-num_{what}s)")
+    return int(given)
+
+
+def _stage_ratings(users, items, ratings, num_users, num_items, block):
+    """The call's ratings as `[blocks, block]` arrays, the last block
+    padded with rows past the tables' ends (user `num_users`, item
+    `num_items`, rating 0), under a `train.stage` span. The span also counts
+    the item lanes whose item appears earlier in the same block
+    (`dup_item_lanes`, of `item_lanes`): what the block's sum adds to a row
+    it has already added to."""
+    with TRACER.span(SPAN_STAGE, args={"form": "arrays"}) as sp:
+        u = np.asarray(users, dtype=np.int32).reshape(-1)
+        i = np.asarray(items, dtype=np.int32).reshape(-1)
+        r = np.asarray(ratings, dtype=np.float32).reshape(-1)
+        n = len(u)
+        if n == 0 or len(i) != n or len(r) != n:
+            raise ValueError(f"{n} users, {len(i)} items and {len(r)} "
+                             "ratings: one of each a training row, at least one")
+        nu = _dims_from(u, num_users, "user")
+        ni = _dims_from(i, num_items, "item")
+        pad = -n % block
+        blocks = tuple(
+            np.concatenate([a, np.full(pad, fill, a.dtype)]).reshape(-1, block)
+            for a, fill in ((u, nu), (i, ni), (r, 0)))
+        ordered = np.sort(blocks[1], axis=1)
+        dup = int(np.count_nonzero((ordered[:, 1:] == ordered[:, :-1])
+                                   & (ordered[:, 1:] < ni)))
+        sp.set(rows=n, blocks=len(blocks[0]), dup_item_lanes=dup,
+               item_lanes=n)
+    return blocks, n, nu, ni
 
 
 def _train_rating_mf(users, items, ratings, options: Optional[str], adagrad: bool,
                      name: str, num_users=None, num_items=None) -> TrainedMFModel:
-    cl = _mf_options().parse(options, name)
-    default_eta0 = 1.0 if adagrad else 0.2
-    hyper = MFHyper(
-        factor=cl.get_int("k", 10),
-        lambda_=cl.get_float("r", 0.03),
-        mu=cl.get_float("mu", 0.0),
-        update_mean=cl.has("update_mean"),
-        use_bias=not cl.has("disable_bias"),
-        rankinit=cl.get("rankinit", "random"),
-        maxval=cl.get_float("maxval", 1.0),
-        min_init_stddev=cl.get_float("min_init_stddev", 0.1),
-        eta=get_eta(cl, default_eta0),
-        adagrad=adagrad,
-        eps=cl.get_float("eps", 1.0),
-        scaling=cl.get_float("scale", 100.0),
-        seed=cl.get_int("seed", 31),
-    )
-    u = np.asarray(users, dtype=np.int32)
-    i = np.asarray(items, dtype=np.int32)
-    r = np.asarray(ratings, dtype=np.float32)
-    state = init_mf_state(_dims_from(u, num_users), _dims_from(i, num_items), hyper)
-    mini_batch = cl.get_int("mini_batch", 1)
-    mode = "minibatch" if mini_batch > 1 else "scan"
-    step = make_mf_step(hyper, mode)
-    iters = cl.get_int("iter", 1)
-    conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
-    block = mini_batch if mode == "minibatch" else 8192
-    n = len(u)
-    for it in range(max(1, iters)):
-        epoch_loss = 0.0
-        for s in range(0, n, block):
-            e = min(s + block, n)
-            state, loss = step(state, u[s:e], i[s:e], r[s:e])
-            epoch_loss += float(loss)
-        conv.incr_loss(epoch_loss)
-        if iters > 1 and conv.is_converged(n):
-            break
-    return TrainedMFModel(state=state, use_bias=hyper.use_bias)
+    """The whole call is one `train.call` span, as `fit_linear`'s is."""
+    with TRACER.span(SPAN_CALL, args={
+            "entry": "mf", "rule": "adagrad" if adagrad else "sgd"}) as call:
+        cl = _mf_options().parse(options, name)
+        default_eta0 = 1.0 if adagrad else 0.2
+        hyper = MFHyper(
+            factor=cl.get_int("k", 10),
+            lambda_=cl.get_float("r", 0.03),
+            mu=cl.get_float("mu", 0.0),
+            update_mean=cl.has("update_mean"),
+            use_bias=not cl.has("disable_bias"),
+            rankinit=cl.get("rankinit", "random"),
+            maxval=cl.get_float("maxval", 1.0),
+            min_init_stddev=cl.get_float("min_init_stddev", 0.1),
+            eta=get_eta(cl, default_eta0),
+            adagrad=adagrad,
+            eps=cl.get_float("eps", 1.0),
+            scaling=cl.get_float("scale", 100.0),
+            seed=cl.get_int("seed", 31),
+        )
+        mini_batch = cl.get_int("mini_batch", 1)
+        mode = "minibatch" if mini_batch > 1 else "scan"
+        n_given = len(users)
+        # the scan's block is a dispatch's rows: a call of fewer takes its own
+        block = mini_batch if mode == "minibatch" else min(8192, max(n_given, 1))
+        (ub, ib, rb), n, nu, ni = _stage_ratings(
+            users, items, ratings,
+            cl.get_int("num_users") if cl.has("num_users") else num_users,
+            cl.get_int("num_items") if cl.has("num_items") else num_items, block)
+        call.set(rows=n, users=nu, items=ni, factor=hyper.factor,
+                 mini_batch=mini_batch, mode=mode, block=block)
+        state = init_state_spanned(init_mf_state, nu, ni, hyper)
+        call.set(table_dtype=str(state.P.dtype))
+        step = make_mf_step(hyper, mode)
+        iters = cl.get_int("iter", 1)
+        conv = ConversionState(not cl.has("disable_cv"), cl.get_float("cv_rate", 0.005))
+        real = np.minimum(block, n - block * np.arange(len(ub)))
+        h2d_counter = REGISTRY.counter("train", "h2d_bytes")
+        row_counter = REGISTRY.counter("hivemall", f"{name}.examples")
+        step_no = 0
+        for it in range(max(1, iters)):
+            with TRACER.span(SPAN_EPOCH, args={"epoch": it}) as epoch:
+                # losses stay on the device through the epoch, as fit_linear's do
+                epoch_losses = []
+                for j in range(len(ub)):
+                    with TRACER.span(SPAN_DATA_PREP) as sp:
+                        arrays = (ub[j], ib[j], rb[j])
+                        h2d = sum(a.nbytes for a in arrays)
+                        sp.set(rows=int(real[j]), width=1, h2d_bytes=h2d)
+                    h2d_counter.increment(h2d)
+                    state, loss = dispatch_step(step, step_no, state, *arrays)
+                    step_no += 1
+                    epoch_losses.append(loss)
+                    row_counter.increment(int(real[j]))
+                with TRACER.span(SPAN_SYNC, args={"fetches": len(epoch_losses)}):
+                    epoch_loss = float(np.sum(jax.device_get(epoch_losses)))
+                epoch.set(steps=len(epoch_losses))
+                call.set(epochs=it + 1)
+                conv.incr_loss(epoch_loss)
+                if iters > 1 and conv.is_converged(n):
+                    break
+        return TrainedMFModel(state=state, use_bias=hyper.use_bias)
 
 
 def train_mf_sgd(users, items, ratings, options: Optional[str] = None, **kw):
@@ -413,8 +539,10 @@ def train_bprmf(users, pos_items, neg_items, options: Optional[str] = None,
     u = np.asarray(users, dtype=np.int32)
     i = np.asarray(pos_items, dtype=np.int32)
     j = np.asarray(neg_items, dtype=np.int32)
-    nu = _dims_from(u, num_users)
-    ni = _dims_from(np.concatenate([i, j]), num_items)
+    nu = _dims_from(u, cl.get_int("num_users") if cl.has("num_users")
+                    else num_users, "user")
+    ni = _dims_from(np.concatenate([i, j]), cl.get_int("num_items")
+                    if cl.has("num_items") else num_items, "item")
     mf_hyper = MFHyper(factor=hyper.factor, rankinit=hyper.rankinit,
                        maxval=hyper.maxval, min_init_stddev=hyper.min_init_stddev,
                        seed=hyper.seed)
